@@ -50,7 +50,7 @@ def main(argv=None):
 
     shape = tuple(int(x) for x in args.image_shape.split(","))
     net = getattr(vision, args.network)(classes=args.num_classes)
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.current_context()
     with ctx:
         net.initialize(ctx=ctx)
         net(mx.nd.zeros((1,) + shape, ctx=ctx))

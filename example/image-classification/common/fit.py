@@ -92,7 +92,7 @@ def fit(args, network, data_loader, **kwargs):
 
 
 def _contexts():
-    return [mx.tpu()] if mx.context.num_tpus() else [mx.cpu()]
+    return [mx.current_context()]
 
 
 def get_network(name, num_classes, image_shape):

@@ -35,10 +35,7 @@ def _maybe_init_distributed():
         return
     import jax
 
-    # feature-detect is_initialized: some jax builds ship
-    # jax.distributed without it
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None and is_init():
+    if jax.distributed.is_initialized():
         return  # user script already joined the group
     jax.distributed.initialize(
         coordinator_address="%s:%s" % (

@@ -255,6 +255,8 @@ class CompiledStep:
         """Materialize updater state for every trainable index — what
         ``Updater.__call__`` does lazily on the eager path, done
         eagerly here so the state tree exists before tracing."""
+        import jax
+
         opt = self.trainer._optimizer
         upd = self._updater()
         for p in self.trainable:
@@ -263,6 +265,15 @@ class CompiledStep:
                 upd.states[i] = opt.create_state_multi_precision(
                     i, p.data())
                 upd.states_synced[i] = True
+                # commit the fresh state to the weight's device: the
+                # program hands it back committed, and an input that
+                # flips from uncommitted to committed recompiles the
+                # whole step on its second call
+                (dev,) = p.data()._data.devices()
+                fresh = []
+                _state_leaves(upd.states[i], fresh)
+                for leaf in fresh:
+                    leaf._assign(jax.device_put(leaf._data, dev))
 
     def _collect_state(self):
         """``(leaf NDArrays, values)`` for every trainable index, in

@@ -100,14 +100,18 @@ class Context:
             except RuntimeError:
                 # no host platform registered (rare); fall back to default
                 return jax.local_devices()[self.device_id]
-        # tpu / gpu → whatever accelerator platform is present
+        # tpu / gpu → whatever accelerator platform is present.  No
+        # fallback: a context that names a chip the process does not
+        # have is an error, never a quiet host (or chip 0) placement.
         devs = _accelerator_devices()
-        if not devs:
-            # CPU-only process (tests): accelerator contexts fall back to the
-            # host platform so models still run; this mirrors reference
-            # behaviour of failing only on explicit device features.
-            devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            from .base import MXNetError
+
+            raise MXNetError(
+                "%s: this process has %d accelerator device(s) (jax "
+                "platform %r); use mx.cpu() or current_context()"
+                % (self, len(devs), jax.default_backend()))
+        return devs[self.device_id]
 
     def empty_cache(self):
         """Parity with reference Context.empty_cache (gpu mem pool flush).

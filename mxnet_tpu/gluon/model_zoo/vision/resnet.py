@@ -179,7 +179,8 @@ class BottleneckV2(HybridBlock):
 class _S2DStem(HybridBlock):
     """The 7×7/s2 stem conv, computed via space-to-depth (TPU MXU
     optimization, opt-in): the C=3 input leaves MXU lanes ~empty, so
-    the stem's backward-filter runs at <10% MXU (BENCH_ROOFLINE.md).
+    the stem's backward-filter runs at <10% MXU (r4 trace, other
+    toolchain, not re-measured).
     Rearranging 2×2 input blocks into channels (C: 3→12, spatial /2)
     and the 7×7 kernel into an equivalent 4×4 one computes the SAME
     function with 4× the lane occupancy.

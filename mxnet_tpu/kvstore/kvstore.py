@@ -257,10 +257,7 @@ class DistKVStore(KVStore):
 
         # normally already joined at import (mxnet_tpu._maybe_init_distributed
         # reads the same DMLC_* contract); handle direct construction too.
-        # Feature-detect is_initialized: some jax builds ship
-        # jax.distributed without it
-        is_init = getattr(jax.distributed, "is_initialized", None)
-        if is_init is not None and is_init():
+        if jax.distributed.is_initialized():
             self._group = True
             return
         coord = os.environ.get("DMLC_PS_ROOT_URI", "127.0.0.1")

@@ -32,7 +32,7 @@ from .. import device_memory as _dm
 from .. import profiler as _prof
 from .. import runtime_stats as _rts
 from ..base import MXNetError, np_dtype, numeric_types
-from ..context import Context, current_context
+from ..context import Context, _accelerator_devices, current_context
 from ..ops import registry as _reg
 
 # dict read on every dispatch: cheapest possible "is the profiler on"
@@ -107,7 +107,9 @@ class NDArray:
             return current_context()
         if platform == "cpu":
             return Context("cpu", dev.id)
-        return Context("tpu", dev.id)
+        # index among THIS process's chips: a device id is global, and
+        # worker r of a multi-process job holds chip id r as its chip 0
+        return Context("tpu", _accelerator_devices().index(dev))
 
     ctx = context
 

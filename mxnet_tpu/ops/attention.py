@@ -24,7 +24,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..base import MXNetError
+from ..util import pallas_interpret
 from .registry import register
 
 # Measured on v5e (tools/bench_attention.py, r3): 256/512 blocks run
@@ -37,7 +41,7 @@ _NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
-# reference (unfused) implementation — also the CPU / odd-shape fallback
+# reference (unfused) implementation — the oracle, and the CPU platform's path
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, causal=False, sm_scale=None):
@@ -317,18 +321,13 @@ def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
 
 
 # ---------------------------------------------------------------------------
-# public fused op (custom_vjp) with automatic fallback
+# public fused op (custom_vjp)
 # ---------------------------------------------------------------------------
 
-def _use_pallas(q, k, v, block_q, block_k, interpret):
-    # interpret mode bypasses only the backend check: the kernel's grid
-    # still assumes the blocks tile the sequence exactly, so a ragged
-    # seq (e.g. 300 with 256-blocks) would leave trailing rows unwritten
-    # in interpret mode just as on hardware
-    if not interpret and jax.default_backend() != "tpu":
-        return False
-    sq, sk = q.shape[2], k.shape[2]
-    return sq % block_q == 0 and sk % block_k == 0
+def _tiles(q, k, block_q, block_k):
+    """The kernel's grid assumes the blocks tile both sequences exactly
+    (a ragged seq would leave trailing rows unwritten)."""
+    return q.shape[2] % block_q == 0 and k.shape[2] % block_k == 0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -358,29 +357,37 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
                     interpret=False):
     """Fused attention over (batch, heads, seq, head_dim) arrays.
 
-    Pallas flash kernel on TPU (or with interpret=True anywhere);
-    falls back to the XLA-fused reference off-TPU or for ragged shapes.
+    On an accelerator this is always the Pallas flash kernel: a sequence
+    the blocks cannot tile raises instead of materialising seq x seq
+    scores.  The CPU platform computes the XLA reference;
+    ``interpret=True`` runs the kernel through the Pallas interpreter
+    there (the test suite's path).
+
+    Inside a program GSPMD partitions over several chips (a step over a
+    dp/tp mesh) XLA refuses the bare kernel — "Mosaic kernels cannot be
+    automatically partitioned" — and jax 0.9.0 / libtpu 0.0.34 cannot
+    take a ``custom_partitioning`` rule either ("Custom emitter for
+    CustomSPMDPartitioning not found"): there the call must sit inside
+    a ``shard_map``, as parallel/ring_attention.py's does.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    # prefer the fast measured blocks, but step down to 128/128 for
-    # sequences they don't divide before abandoning the fused path
-    for cq, ck in ((block_q, block_k), (128, 128)):
-        bq = min(cq, q.shape[2])
-        bk = min(ck, k.shape[2])
-        if _use_pallas(q, k, v, bq, bk, interpret):
-            return _flash(q, k, v, sm_scale, causal, bq, bk, interpret)
+    on_cpu = pallas_interpret()
+    if not on_cpu or interpret:
+        # prefer the fast measured blocks, but step down to 128/128 for
+        # sequences they don't divide
+        for cq, ck in ((block_q, block_k), (128, 128)):
+            bq = min(cq, q.shape[2])
+            bk = min(ck, k.shape[2])
+            if _tiles(q, k, bq, bk):
+                return _flash(q, k, v, sm_scale, causal, bq, bk, on_cpu)
+        if not on_cpu:
+            raise MXNetError(
+                "flash_attention: sequence lengths (q %d, k %d) are not "
+                "tiled by blocks (%d, %d) or (128, 128); pad the "
+                "sequence to a multiple of 128"
+                % (q.shape[2], k.shape[2], block_q, block_k))
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-
-
-# pallas imports are deferred so that `import mxnet_tpu` works on builds
-# without pallas; resolved at first use
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pl = None
-    pltpu = None
 
 
 # ---------------------------------------------------------------------------

@@ -57,8 +57,7 @@ def convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(), pad=()
     NCHW/NCDHW, weight OI+spatial) and the channel-last forms (NWC/
     NHWC/NDHWC) with the reference's OHWI weight convention
     (num_filter, *kernel, in_c/groups — conv-inl.h WeightShape for
-    NHWC).  Measured ~+7% on TPU conv trunks (BENCH_NOTES "layout
-    experiment").  cudnn_*/workspace attrs are accepted for API parity
+    NHWC).  cudnn_*/workspace attrs are accepted for API parity
     and ignored — XLA picks the TPU algorithm.
     """
     nd = len(kernel)
@@ -103,46 +102,6 @@ def _pallas_dw_enabled():
     return os.environ.get("MXTPU_PALLAS_CONV_DW", "0") == "1"
 
 
-def _pallas_pool_bwd_enabled():
-    import os
-
-    return os.environ.get("MXTPU_PALLAS_POOL_BWD", "0") == "1"
-
-
-@functools.lru_cache(maxsize=None)
-def _nhwc_maxpool2d_pallas_bwd(kernel, stride, pad):
-    """NHWC 2-D max pool whose input-gradient routes to the Pallas
-    gather-style kernel (MXTPU_PALLAS_POOL_BWD=1); forward stays XLA's
-    reduce_window."""
-    from . import pallas_pool
-
-    window = (1,) + kernel + (1,)
-    strides = (1,) + stride + (1,)
-    padding = [(0, 0), (pad[0], pad[0]), (pad[1], pad[1]), (0, 0)]
-
-    def raw(x):
-        return lax.reduce_window(x, -jnp.inf, lax.max, window, strides,
-                                 padding)
-
-    @jax.custom_vjp
-    def pool(x):
-        return raw(x)
-
-    def fwd(x):
-        return raw(x), x
-
-    def bwd(x, dy):
-        if pallas_pool.supported(x.shape, dy.shape, kernel, stride, pad,
-                                 ebytes=x.dtype.itemsize):
-            return (pallas_pool.maxpool_bwd_nhwc(
-                x, dy, kernel, stride, pad).astype(x.dtype),)
-        _, vjp = jax.vjp(raw, x)
-        return vjp(dy)
-
-    pool.defvjp(fwd, bwd)
-    return pool
-
-
 @functools.lru_cache(maxsize=None)
 def _nhwc_conv2d_pallas_dw(stride, pad, groups):
     """NHWC 2-D conv whose weight-gradient routes to the Pallas dW
@@ -177,7 +136,7 @@ def _nhwc_conv2d_pallas_dw(stride, pad, groups):
         if pallas_conv.supported(x.shape, dy.shape, kernel, stride, pad,
                                  (1, 1), groups,
                                  ebytes=x.dtype.itemsize):
-            dw = pallas_conv.conv_dw_nhwc(x, dy, kernel, stride,
+            dw = pallas_conv.conv_dw_nhwc(x, dy, kernel,
                                           pad).astype(w.dtype)
         else:
             _, vjp_w = jax.vjp(lambda ww: raw(x, ww), w)
@@ -613,15 +572,6 @@ def pooling(data, kernel=(), pool_type="max", stride=(), pad=(), global_pool=Fal
         strides = (1, 1) + stride
 
     if pool_type == "max":
-        if (channel_last and nd == 2 and not global_pool
-                and _pallas_pool_bwd_enabled()
-                and all(lo == hi for lo, hi in spatial_padding)):
-            # backward via the Pallas gather-style kernel
-            # (pallas_pool.py) where supported; forward keeps XLA's
-            # reduce_window bit-for-bit
-            return _nhwc_maxpool2d_pallas_bwd(
-                kernel, stride,
-                tuple(lo for lo, _hi in spatial_padding))(data)
         init = -jnp.inf
         out = lax.reduce_window(data, init, lax.max, window, strides, padding)
         return out

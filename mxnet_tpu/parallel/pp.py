@@ -26,22 +26,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map as _shard_map_raw
-    _REP_KWARG = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-    _REP_KWARG = "check_rep"
-
-
-def _shard_map(fn, **kw):
-    """Version shim: the replication-check kwarg was renamed check_rep →
-    check_vma when shard_map moved out of jax.experimental."""
-    kw[_REP_KWARG] = False
-    return _shard_map_raw(fn, **kw)
-
+from jax import lax, shard_map
 
 __all__ = ["GPipe", "stack_stage_params"]
 
@@ -103,8 +88,8 @@ class GPipe:
         # params when stage weights also shard over other axes (e.g.
         # P('pp', None, 'tp') for Megatron column-parallel stages); the
         # default P(axis) shards the stage dim only.
-        self._fn = _shard_map(
-            self._device_program, mesh=mesh,
+        self._fn = shard_map(
+            self._device_program, mesh=mesh, check_vma=False,
             in_specs=(P(axis) if param_specs is None else param_specs,
                       P() if batch_spec is None else batch_spec,
                       P(axis)),

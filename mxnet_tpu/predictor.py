@@ -53,23 +53,26 @@ class Predictor:
     input_shapes : dict of str to tuple
         Shapes of the input variables.
     dev_type : str, optional
-        "cpu" or "tpu" ("gpu" accepted as an alias of "tpu").
+        "cpu" or "tpu" ("gpu" accepted as an alias of "tpu").  Default:
+        ``current_context()`` — the accelerator when the process has
+        one, like ``Parameter.initialize`` and ``nd.array``.
     dev_id : int, optional
     type_dict : dict of str to dtype, optional
         Input dtypes (default float32).
     """
 
     def __init__(self, symbol_json_str, param_raw_bytes, input_shapes,
-                 dev_type="cpu", dev_id=0, type_dict=None):
+                 dev_type=None, dev_id=0, type_dict=None):
         from . import context as _context
         from . import ndarray as _nd
         from . import symbol as _symbol
 
         self._symbol = _symbol.load_json(symbol_json_str)
         self._symbol_json = symbol_json_str
-        self._dev_type, self._dev_id = dev_type, dev_id
         self._type_dict = dict(type_dict or {})
-        if dev_type in ("tpu", "gpu"):
+        if dev_type is None:
+            self._ctx = _context.current_context()
+        elif dev_type in ("tpu", "gpu"):
             self._ctx = _context.tpu(dev_id)
         else:
             self._ctx = _context.cpu(dev_id)
@@ -182,7 +185,6 @@ class Predictor:
         new = Predictor.__new__(Predictor)
         new._symbol = self._symbol
         new._symbol_json = self._symbol_json
-        new._dev_type, new._dev_id = self._dev_type, self._dev_id
         new._type_dict = dict(self._type_dict)
         new._ctx = self._ctx
         new._arg_params = self._arg_params

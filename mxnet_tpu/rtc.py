@@ -13,6 +13,7 @@ from __future__ import annotations
 
 from .base import MXNetError
 from .ndarray import NDArray
+from .util import pallas_interpret
 
 __all__ = ["CudaModule", "PallasModule"]
 
@@ -41,8 +42,6 @@ class PallasModule:
         self._grid = grid
 
     def get_kernel(self, name=None, signature=None):
-        import jax
-
         kernel_fn = self._kernel_fn
         out_shape_fn = self._out_shape_fn
         grid = self._grid
@@ -60,11 +59,8 @@ class PallasModule:
                     # gridless kernels must OMIT the arg: pallas_call
                     # rejects an explicit grid=None
                     kw["grid"] = grid_dims if grid_dims is not None else grid
-                if jax.default_backend() != "tpu":
-                    # Mosaic compiles only on TPU; CPU (tests, local
-                    # dev) runs the same kernel through the interpreter
-                    kw["interpret"] = True
-                fn = pl.pallas_call(kernel_fn, out_shape=out_shape, **kw)
+                fn = pl.pallas_call(kernel_fn, out_shape=out_shape,
+                                    interpret=pallas_interpret(), **kw)
                 res = fn(*arrays)
                 return NDArray(res)
 

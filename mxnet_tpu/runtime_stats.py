@@ -27,7 +27,7 @@ Memory & cost analytics (PR 3): ``snapshot()`` additionally carries a
 a ``costs`` section (per-op XLA cost/memory analysis captured at
 compile time by ``ops/registry.py``), and :func:`roofline` derives
 achieved GB/s / GFLOP/s per op from profiled dispatch wall-time — the
-in-production analog of the offline ``BENCH_ROOFLINE.md`` audit.
+in-production analog of the offline ``tools/profile_step.py`` audit.
 :func:`dump_diag` writes the whole picture atomically to a JSON file;
 ``MXNET_TPU_DIAG=<file>`` arms a ``SIGUSR1`` handler (plus an atexit
 dump) so a live training job can be asked for it at any time, and
@@ -59,9 +59,6 @@ Environment variables
 ``MXNET_TPU_DIAG``  diagnostic-dump destination; arms SIGUSR1 + atexit
     dump, and turns on the device-memory tracker and compile-time cost
     capture so the dump is populated.
-``MXNET_TPU_HBM_PEAK_GBPS`` / ``MXNET_TPU_PEAK_TFLOPS``  roofline peaks
-    used for the headroom columns (defaults: v5e — 819 GB/s, 394
-    bf16 TFLOP/s).
 """
 
 from __future__ import annotations
@@ -97,12 +94,32 @@ STORM_WARN_INTERVAL = float(os.environ.get(
 # but no rates.  Import-time, like the rest of the DIAG arming.
 DIAG_TIMING = bool(os.environ.get("MXNET_TPU_DIAG"))
 
-# roofline peaks for the derived headroom columns (defaults: TPU v5e
-# public numbers, the same constants tools/profile_step.py audits with)
-ROOFLINE_BW_PEAK = float(os.environ.get(
-    "MXNET_TPU_HBM_PEAK_GBPS", "819")) * 1e9
-ROOFLINE_FLOP_PEAK = float(os.environ.get(
-    "MXNET_TPU_PEAK_TFLOPS", "394")) * 1e12
+# THE table of published per-chip peaks, keyed by jax's ``device_kind``
+# (tools/profile_step.py imports it).  A device that is not here has no
+# roofline: ``device_peaks`` raises and the headroom columns are left
+# out — never a default.  Source: Google Cloud documentation, "TPU v5e"
+# (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16 (394 is the int8
+# figure), 16 GB HBM2e at 819 GB/s per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device_kind=None):
+    """Published ``{"bf16_flops", "hbm_bytes_per_s"}`` of one chip of
+    ``device_kind`` (default: the first jax device's).  Raises
+    ``KeyError`` for a kind the table does not list."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            "no published peaks for device_kind %r (known: %s) — a "
+            "roofline needs a chip from the table in runtime_stats.py"
+            % (device_kind, sorted(DEVICE_PEAKS))) from None
 
 # recent cache keys kept per op for churn diagnosis
 _STORM_KEY_WINDOW = 8
@@ -412,14 +429,20 @@ def snapshot():
             "identity": process_identity()}
 
 
-def roofline(snap=None, top=None):
+def roofline(snap=None, top=None, device_kind=None):
     """Per-op achieved GB/s and GFLOP/s vs the chip roofline, derived by
     dividing each op's cost-model bytes/flops per call by its profiled
     mean dispatch wall-time; rows sorted by headroom (µs above the
-    roofline bound) descending — the in-production analog of
-    ``BENCH_ROOFLINE.md``.  Ops never profiled get cost columns only.
-    Works on a live :func:`snapshot` or a loaded diag dump."""
+    roofline bound) descending.  Ops never profiled get cost columns
+    only, and so does every op when ``device_kind`` (default: this
+    process's device) is not in :data:`DEVICE_PEAKS` — an unknown chip
+    has no bound.  Works on a live :func:`snapshot` or a loaded diag
+    dump."""
     snap = snap or snapshot()
+    try:
+        peaks = device_peaks(device_kind)
+    except KeyError:
+        peaks = None
     rows = []
     for name, cost in sorted(snap.get("costs", {}).items()):
         row = {"op": name,
@@ -441,9 +464,10 @@ def roofline(snap=None, top=None):
                 row["achieved_gbps"] = bpc / per_call / 1e9
             if fpc:
                 row["achieved_gflops"] = fpc / per_call / 1e9
-            bound = max((bpc or 0.0) / ROOFLINE_BW_PEAK,
-                        (fpc or 0.0) / ROOFLINE_FLOP_PEAK)
-            if bound > 0:
+            bound = peaks and max(
+                (bpc or 0.0) / peaks["hbm_bytes_per_s"],
+                (fpc or 0.0) / peaks["bf16_flops"])
+            if bound:
                 row["bound_us"] = bound * 1e6
                 row["headroom_us"] = (per_call - bound) * 1e6
         rows.append(row)

@@ -1,8 +1,8 @@
 """Pass manager over the Symbol DAG — verified rewrites by construction.
 
-Relay-style (arxiv 1810.00952) composable IR -> IR transforms: a
-:class:`Pass` wraps one graph rewrite, and the manager re-runs the
-graph verifier (:mod:`.verify`) on the rewrite's output before anyone
+Composable IR -> IR transforms in the style of TVM's IR (arxiv
+1810.00952): a :class:`Pass` wraps one graph rewrite, and the manager
+re-runs the graph verifier (:mod:`.verify`) on the rewrite's output before anyone
 downstream can bind it.  A pass that produces an invalid graph fails
 loudly with the pass *and* the finding named — it never hands a broken
 DAG to the executor, where the same fault would surface as an opaque

@@ -122,6 +122,18 @@ def test_flash_interpret_ragged_seq_falls_back_correctly():
     out = att.flash_attention(q, k, v, interpret=True)  # default blocks
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
-    # and _use_pallas itself refuses ragged shapes in interpret mode
-    assert not att._use_pallas(q, k, v, 256, 512, True)
-    assert not att._use_pallas(q, k, v, 128, 128, True)
+    # and the tiling check itself refuses ragged shapes
+    assert not att._tiles(q, k, 256, 512)
+    assert not att._tiles(q, k, 128, 128)
+
+
+def test_flash_ragged_seq_raises_off_cpu(monkeypatch):
+    """On an accelerator a sequence the blocks cannot tile is an error,
+    never a silent drop to materialised seq x seq scores."""
+    from mxnet_tpu.base import MXNetError
+
+    monkeypatch.setattr(att, "pallas_interpret", lambda: False)
+    q, k, v = (_rand((1, 1, 300, 32), seed=i) for i in range(3))
+    with pytest.raises(MXNetError, match="not tiled"):
+        att.flash_attention(q, k, v)
+

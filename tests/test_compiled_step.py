@@ -340,11 +340,17 @@ def test_shape_change_new_entry_not_recompile_storm():
     xs, ys = _data(n=4)
     net = _make_mlp()
     tr = gluon.Trainer(net.collect_params(), "sgd",
-                       {"learning_rate": 0.1})
+                       {"learning_rate": 0.1, "momentum": 0.9})
     cs = tr.compile(net, loss_fn)
     for x, y in zip(xs, ys):
         cs.step(mx.nd.array(x), mx.nd.array(y))
     assert len(cs._cache) == 1  # steady shape: ONE program
+    # ... that XLA compiled ONCE: the fresh momentum state goes in
+    # committed, like the state the program hands back, so the second
+    # step reuses the first step's executable (on the chip a second
+    # ResNet-50 compile cost ~35 s)
+    (entry,) = cs._cache.values()
+    assert entry.fn._cache_size() == 1
     cs.step(mx.nd.array(xs[0][:4]), mx.nd.array(ys[0][:4]))
     cs.step(mx.nd.array(xs[1][:4]), mx.nd.array(ys[1][:4]))
     assert len(cs._cache) == 2  # new batch shape: one NEW entry
@@ -591,10 +597,12 @@ def test_make_chained_donates_carry_and_writes_back():
 # ------------------------------------------------------------- bench
 
 
-def test_bench_compiled_compare_smoke():
+def test_bench_compiled_compare_smoke(capfd):
     """bench.py --compiled-step end to end on a small model: losses
-    match, warm dispatches collapse to ~1/step, wall improves, dumps
-    + verdict record emitted."""
+    match, warm dispatches collapse to ~1/step, dumps + record emitted.
+    This is a CPU run (asked for explicitly), so the record names the
+    CPU and carries no verdict word and no wall-time comparison — a CPU
+    timing is not a speed."""
     import importlib.util
     import tempfile
 
@@ -620,8 +628,12 @@ def test_bench_compiled_compare_smoke():
             data_shape=(16, 16), num_classes=10)
         assert rc == 0
         assert rec["losses_match"]
-        assert rec["verdict"] == "improvement"
         assert rec["warm_dispatches_per_step"]["fused"] <= 2.0
-        assert rec["step_wall_ms"]["fused"] < rec["step_wall_ms"]["eager"]
+        assert rec["platform"] == "cpu" and rec["device_kind"]
+        assert "verdict" not in rec and "compare_verdict" not in rec
+        assert rec["step_wall_ms"] == "not measured"
+        out, err = capfd.readouterr()
+        assert "improvement" not in out + err
+        assert "regression" not in out + err
         for p in rec["dumps"]:
             assert os.path.exists(p)

@@ -133,8 +133,8 @@ def test_resnet_s2d_stem_matches_standard():
     """stem_s2d=True (space-to-depth stem, TPU MXU option) computes
     the SAME function as the 7x7/s2 conv with identical param shapes,
     so checkpoints swap between stems freely.  Measured perf-neutral
-    at model scale on v5e (BENCH_NOTES r4: the stem dW is byte-bound,
-    not lane-bound) — kept as the standard TPU option with the
+    at model scale on v5e (r4, other toolchain, not re-measured: the
+    stem dW is byte-bound, not lane-bound) — kept as the standard TPU option with the
     equivalence pinned here."""
     rng = np.random.RandomState(0)
     a = vision.resnet18_v1(classes=5, layout="NHWC")

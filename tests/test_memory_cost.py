@@ -232,10 +232,19 @@ def test_cost_capture_and_roofline_with_profiler_on():
     assert s["timed_calls"] == s["hits"] >= 3
     assert s["dispatch_seconds"] > 0
 
-    rows = runtime_stats.roofline(snap)
+    # this process runs on "cpu", which the peaks table does not list:
+    # rates yes, but no bound and no headroom — never a default chip
+    row = next(r for r in runtime_stats.roofline(snap)
+               if r["op"] == "linalg_gemm2")
+    assert row["achieved_gbps"] > 0 and row["achieved_gflops"] > 0
+    assert "bound_us" not in row and "headroom_us" not in row
+    with pytest.raises(KeyError, match="no published peaks"):
+        runtime_stats.device_peaks()
+    # the same dump read against a chip from the table
+    assert runtime_stats.device_peaks("TPU v5 lite") == {
+        "bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    rows = runtime_stats.roofline(snap, device_kind="TPU v5 lite")
     row = next(r for r in rows if r["op"] == "linalg_gemm2")
-    assert row["achieved_gbps"] > 0
-    assert row["achieved_gflops"] > 0
     assert row["headroom_us"] == pytest.approx(
         row["us_per_call"] - row["bound_us"])
     # rows come sorted by headroom descending

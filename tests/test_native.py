@@ -456,8 +456,8 @@ def test_raw_records_warns_on_dropped_augmentation(tmp_path):
 def test_native_jpeg_pipeline_matches_python(tmp_path):
     """The in-worker C++ JPEG decoder (pipeline.cc DecodeJpeg) produces
     the same batches as the Python-callback path — labels exactly,
-    pixels within decoder rounding (r3; closes the GIL-bet in
-    BENCH_NOTES' multi-core scaling story)."""
+    pixels within decoder rounding (r3; decoding in the C++ workers is
+    what lets the pipeline scale over cores without the GIL)."""
     pytest.importorskip("PIL")
     from mxnet_tpu.io.io import ImageRecordIter, _native_has_jpeg
     from mxnet_tpu.recordio import IRHeader, MXIndexedRecordIO, pack_img

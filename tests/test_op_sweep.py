@@ -1127,8 +1127,7 @@ def test_registry_fully_covered():
 
 def test_conv_nhwc_layout_matches_nchw():
     """layout='NHWC' (channel-last data, OHWI weight — the reference's
-    NHWC weight convention) must equal the NCHW result transposed
-    (BENCH_NOTES layout experiment: ~+7% on the conv trunk on TPU)."""
+    NHWC weight convention) must equal the NCHW result transposed."""
     x = _f32(2, 3, 6, 6)
     w = _f32(4, 3, 3, 3, seed=1)
     b = _f32(4, seed=2)

@@ -566,7 +566,12 @@ def test_profile_step_summary_uses_shared_anatomy(tmp_path):
     try:
         import profile_step
         summary, _rows = profile_step.main(
-            ["--parse-only", str(path), "--steps", "1", "--top", "5"])
+            ["--parse-only", str(path), "--steps", "1", "--top", "5",
+             "--device-kind", "TPU v5 lite"])
+        # the trace's chip must be named: this process's device ("cpu")
+        # has no published peaks, and that is an error, not a default
+        with pytest.raises(KeyError, match="no published peaks"):
+            profile_step.main(["--parse-only", str(path)])
     finally:
         sys.path.remove(os.path.join(REPO, "tools"))
     anat = summary["step_anatomy"]

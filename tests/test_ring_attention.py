@@ -10,23 +10,17 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mxnet_tpu.ops.attention import mha_reference
 from mxnet_tpu.parallel.mesh import create_mesh
 from mxnet_tpu.parallel.ring_attention import ring_attention, ulysses_attention
 
-try:
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs)
 
 
 def _rand(shape, seed):

@@ -1,8 +1,8 @@
 """Continuous-batching inference server (mxnet_tpu/serving.py).
 
-Pins the subsystem's contracts: bucket padding is bit-exact vs the
-unbatched Predictor (padded rows never leak into results), concurrent
-clients get exactly their own answers, the NaN sentinel rejects (one
+Pins the subsystem's contracts: bucketed outputs stay within tolerance
+of the unbatched Predictor and padding never bleeds into results,
+concurrent clients get their own answers, the NaN sentinel rejects (one
 rate-limited warning, never a silent bad payload), shutdown drains,
 the serve:* telemetry reaches histograms / Prometheus / diag dumps /
 --compare / the perf doctor, and the open-loop loadgen smoke holds a
@@ -69,19 +69,37 @@ def _reference(pred, x):
 
 # ------------------------------------------------------------ exactness
 
+# a bucketed batch and the unbatched predictor are differently shaped,
+# differently fused programs: every backend keeps them within a few
+# float32 ulps of each other, none keeps them bit-identical
+SERVE_RTOL, SERVE_ATOL = 1e-5, 1e-6
 
-def test_bucket_padding_bit_exact(tmp_path):
-    """Every bucket size: a request padded up to the bucket must
-    bit-match the unbatched Predictor on its valid rows — padding can
-    never bleed into results."""
+
+def test_bucket_padding_within_tolerance_and_never_bleeds(tmp_path):
+    """Every bucket size: a request padded up to the bucket matches the
+    unbatched Predictor on its valid rows within tolerance, and padding
+    never bleeds into results — the same bucket executable fed two
+    different paddings returns identical valid rows."""
     pred = _export_predictor(tmp_path)
     with InferenceServer(pred, buckets=(1, 2, 4, 8)) as srv:
         for n in (1, 2, 3, 5, 8):
             x = np.random.uniform(size=(n, 5)).astype(np.float32)
             out = srv.infer(x)
             assert len(out) == 1 and out[0].shape == (n, 3)
-            assert np.array_equal(out[0], _reference(pred, x)), \
-                "bucketed output for n=%d differs from unbatched" % n
+            np.testing.assert_allclose(
+                out[0], _reference(pred, x), rtol=SERVE_RTOL,
+                atol=SERVE_ATOL,
+                err_msg="bucketed output for n=%d differs from "
+                        "unbatched" % n)
+        x = np.random.uniform(size=(5, 5)).astype(np.float32)
+        rows = []
+        for pad in (0.0, 1e6):
+            buf = np.full((8, 5), pad, np.float32)
+            buf[:5] = x
+            (o,) = srv._bucket_fn(8)({"data": serving._device_put(buf)})
+            rows.append(np.asarray(o)[:5])
+        assert np.array_equal(rows[0], rows[1]), \
+            "valid rows depend on what the pad rows hold"
     snap = srv.snapshot()
     assert snap["requests"] == 5
     assert snap["samples"] == 1 + 2 + 3 + 5 + 8
@@ -91,9 +109,11 @@ def test_bucket_padding_bit_exact(tmp_path):
     assert snap["bucket_compiles"] == len(snap["per_bucket"])
 
 
-def test_concurrent_clients_bit_exact(tmp_path):
-    """Threaded clients with distinct inputs each get exactly their own
-    rows back, bit-exact, while the batcher packs them arbitrarily."""
+def test_concurrent_clients_get_their_own_rows(tmp_path):
+    """Threaded clients with distinct inputs each get their own rows
+    back (within tolerance of the unbatched predictor; another
+    client's rows would be off by orders of magnitude more) while the
+    batcher packs them arbitrarily."""
     pred = _export_predictor(tmp_path)
     rng = np.random.RandomState(3)
     per_client = 8
@@ -122,8 +142,10 @@ def test_concurrent_clients_bit_exact(tmp_path):
     assert not errors, errors
     assert len(results) == clients * per_client
     for (cid, i), (x, got) in results.items():
-        assert np.array_equal(got, _reference(pred, x)), \
-            "client %d request %d got someone else's rows" % (cid, i)
+        np.testing.assert_allclose(
+            got, _reference(pred, x), rtol=SERVE_RTOL, atol=SERVE_ATOL,
+            err_msg="client %d request %d got someone else's rows"
+                    % (cid, i))
 
 
 def test_shape_and_queue_rejections(tmp_path):
@@ -564,8 +586,7 @@ def test_loadgen_open_loop_smoke(tmp_path):
     """Open-loop loadgen end-to-end: the server sustains more than the
     serial rate, and at that same offered load its p99 beats the
     one-at-a-time serial replay (the continuous-batching claim).  Kept
-    small — the real sweep is ``python bench.py --serve``
-    (BENCH_NOTES)."""
+    small — the real sweep is ``python bench.py --serve``."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(

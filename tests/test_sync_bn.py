@@ -26,11 +26,8 @@ def test_sync_bn_global_stats_under_shard_map():
     barrier semantics)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     from mxnet_tpu.ops.contrib import sync_batch_norm
 

@@ -42,7 +42,7 @@ def build_train_step(network, batch, hw=None, dtype="bfloat16",
             raise
         net = ctor(classes=classes)
         layout = "NCHW"
-    ctx = mx.tpu() if mx.context.num_tpus() else mx.cpu()
+    ctx = mx.current_context()
     # probe at FULL size: flatten-tailed nets (alexnet, vgg) resolve
     # their Dense in_units from the probe's spatial dims, and
     # inception_v3's fixed AvgPool2D(8) rejects small inputs — only

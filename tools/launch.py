@@ -13,6 +13,11 @@ their ports are handed to workers via MXTPU_PS_PORTS.  Only the local
 launcher is implemented; ssh/mpi cluster modes are host-scheduling
 concerns outside this container.
 
+On a TPU host a chip belongs to one process, so worker `r` is bound to
+chip `r` (libtpu's TPU_VISIBLE_CHIPS / TPU_PROCESS_* variables) and the
+N one-chip processes join one jax.distributed group; `-n` must then
+equal the host's chip count.  Under JAX_PLATFORMS=cpu nothing is bound.
+
 Supervisor mode (`MXNET_TPU_SUPERVISE=N`): while workers are still
 running, a parameter-server process that exits NONZERO (crash, fault
 drill, signal) is relaunched on the same port, up to N times per
@@ -38,6 +43,7 @@ budget.  The relaunched worker resumes through the normal
 """
 
 import argparse
+import glob
 import json
 import os
 import pickle
@@ -56,6 +62,33 @@ def free_port():
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+# libtpu's process grid over the chips of one host, by chip count (v5e
+# hosts hold 1, 4 (2x2) or 8 (2x4) chips)
+_TPU_PROCESS_BOUNDS = {4: "2,2,1", 8: "2,4,1"}
+
+
+def local_tpu_chips():
+    """Chips on this host, counted from the device files (a v5e host
+    shows one /dev/vfio/<n> per chip): the launcher never imports jax,
+    so it cannot take a chip its workers need."""
+    return len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def tpu_worker_env(rank, ports):
+    """libtpu environment that gives worker ``rank`` chip ``rank`` alone
+    while keeping all ``len(ports)`` one-chip processes in one slice
+    (so collectives between them ride ICI)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_BOUNDS[len(ports)],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            "localhost:%d" % p for p in ports),
+        "TPU_PROCESS_PORT": str(ports[rank]),
+        "CLOUD_TPU_TASK_ID": str(rank),
+    }
 
 
 def _poll_restart_requests(port, timeout=1.0):
@@ -132,6 +165,17 @@ def main(argv=None):
     args = parser.parse_args(argv)
     if not args.command:
         parser.error("no command given")
+    tpu_ports = None
+    chips = 0 if os.environ.get("JAX_PLATFORMS") == "cpu" \
+        else local_tpu_chips()
+    if chips and args.num_workers > 1:
+        if args.num_workers != chips or chips not in _TPU_PROCESS_BOUNDS:
+            parser.error(
+                "this host has %d TPU chip(s) and a chip belongs to one "
+                "process: -n must be %s (one worker per chip), or set "
+                "JAX_PLATFORMS=cpu to run the workers on the CPU"
+                % (chips, chips if chips in _TPU_PROCESS_BOUNDS else 1))
+        tpu_ports = [free_port() for _ in range(chips)]
 
     port = free_port()
     default_ckpt_dir = None
@@ -189,6 +233,8 @@ def main(argv=None):
         env = dict(os.environ)
         env.update(common)
         env.update({"DMLC_ROLE": "worker", "DMLC_WORKER_ID": str(rank)})
+        if tpu_ports:
+            env.update(tpu_worker_env(rank, tpu_ports))
         rank_suffix_observability(env, "worker", rank)
         return subprocess.Popen(args.command, env=env)
 

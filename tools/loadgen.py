@@ -73,6 +73,16 @@ def build_demo_predictor(in_dim=64, hidden=64, out_dim=8, seed=7):
     return pred, (in_dim,)
 
 
+def output_device(pred):
+    """Where the model computes: the device the predictor's *output
+    array* lives on after one forward (jax's default device says
+    nothing about where a model's parameters were placed)."""
+    pred.forward(data=np.zeros(pred._exec.arg_dict["data"].shape,
+                               np.float32))
+    (dev,) = pred._outputs[0].data_jax.devices()
+    return {"platform": dev.platform, "device_kind": dev.device_kind}
+
+
 def _latency_summary(lat_s):
     if not lat_s:
         return {"p50_ms": None, "p99_ms": None, "p999_ms": None,
@@ -334,6 +344,7 @@ def sweep(qps_levels=None, duration=2.0, sizes=DEFAULT_SIZES,
         pred, sample_shape = build_demo_predictor()
     else:
         pred, sample_shape = model
+    device = output_device(pred)
     serial = serial_baseline(pred, sample_shape, sizes=sizes,
                              n_requests=serial_requests, seed=seed)
     if not qps_levels:
@@ -380,6 +391,7 @@ def sweep(qps_levels=None, duration=2.0, sizes=DEFAULT_SIZES,
         "metric": "serving open-loop sweep (Poisson arrivals, request "
                   "sizes %s, buckets %s, %.1fs/level)"
                   % (list(sizes), list(buckets), duration),
+        **device,
         "serial": serial,
         "levels": levels,
         "soak": soak,
@@ -433,6 +445,9 @@ def main(argv=None):
                    help="also write the JSON report here")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    from mxnet_tpu.util import enable_compile_cache
+
+    enable_compile_cache()
     qps_levels = [float(q) for q in args.qps.split(",")] \
         if args.qps else None
     report = sweep(
