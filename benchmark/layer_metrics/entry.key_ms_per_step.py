@@ -1,0 +1,10 @@
+"""Layer ``entry``: host time per step inside the program's span
+``mxtpu.step.key`` (``GluonTrainStep.__call__`` drawing the step's PRNG key:
+four tiny device programs), summed over the traced window (profiler's
+clock; ``harness/program_spans.py``)."""
+
+from benchmark.harness import program_spans
+
+
+def read(obs):
+    return program_spans.host_span_ms_per_step(obs, "mxtpu.step.key")
