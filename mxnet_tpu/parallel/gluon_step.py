@@ -41,6 +41,7 @@ import numpy as _np
 
 from .. import autograd
 from .. import health as _health
+from .. import profiler as _profiler
 from .. import random as _random
 from .. import runtime_stats as _rts
 from .. import xray as _xray
@@ -294,6 +295,8 @@ class GluonTrainStep:
             self._update = sgd_momentum_update(lr, momentum, wd)
         self._compute_dtype = compute_dtype
         self.last_grad_norm = None
+        self._calls = 0        # step_num of the next mxtpu.step span
+        self._leaves = None    # array arguments of one launch
         pure_loss = _pure_loss_builder(block, loss_block, self.trainable,
                                        self.aux,
                                        aux_loss_weight=aux_loss_weight)
@@ -635,25 +638,35 @@ class GluonTrainStep:
         """One training step on device arrays/numpy; returns loss (async)."""
         import jax
 
-        if not isinstance(x, jax.Array):
-            x, y = self.put_batch(x, y)
-        key = _random.next_key()
-        args = [self.train_vals, self.opt_state, self.aux_vals, x, y, key]
-        if self._opt_update is not None:
-            args.append(self._opt_update.host_scalars())
-        (loss, self.train_vals, self.opt_state, self.aux_vals,
-         gnorm) = self._step(*args)
-        self.last_grad_norm = gnorm
-        if self._zero:
-            zl = self.zero_layout
-            _rts.inc("zero_steps")
-            _rts.inc("zero_allgather_bytes",
-                     zl["per_step_allgather_bytes"])
-            _rts.inc("zero_reduce_bytes", zl["per_step_reduce_bytes"])
-        if _health._state["on"]:
-            hm = _health.monitor()
-            if hm is not None:
-                hm.observe_scalar("grad_norm", gnorm)
+        span = _profiler.boundary_span
+        with span("mxtpu.step", step_num=self._calls):
+            self._calls += 1
+            if not isinstance(x, jax.Array):
+                with span("mxtpu.step.put_batch"):
+                    x, y = self.put_batch(x, y)
+            with span("mxtpu.step.key"):
+                key = _random.next_key()
+            args = [self.train_vals, self.opt_state, self.aux_vals, x, y,
+                    key]
+            if self._opt_update is not None:
+                with span("mxtpu.step.scalars"):
+                    args.append(self._opt_update.host_scalars())
+            if self._leaves is None:    # fixed from the first call on
+                self._leaves = len(jax.tree_util.tree_leaves(args))
+            with span("mxtpu.step.launch", leaves=self._leaves):
+                (loss, self.train_vals, self.opt_state, self.aux_vals,
+                 gnorm) = self._step(*args)
+            self.last_grad_norm = gnorm
+            if self._zero:
+                zl = self.zero_layout
+                _rts.inc("zero_steps")
+                _rts.inc("zero_allgather_bytes",
+                         zl["per_step_allgather_bytes"])
+                _rts.inc("zero_reduce_bytes", zl["per_step_reduce_bytes"])
+            if _health._state["on"]:
+                hm = _health.monitor()
+                if hm is not None:
+                    hm.observe_scalar("grad_norm", gnorm)
         return loss
 
     def sync_to_params(self):

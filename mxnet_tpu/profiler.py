@@ -11,7 +11,13 @@ producing chrome-tracing JSON — this traces the *framework* (op
 dispatch, iterator, kvstore). (2) ``start_xla_trace``/``stop_xla_trace``
 wrap ``jax.profiler`` for device-side traces viewable in TensorBoard /
 Perfetto — the analog of the reference's device-level opr profiling,
-since XLA owns kernel timing on TPU.
+since XLA owns kernel timing on TPU.  The two meet in
+:func:`boundary_span`: a layer-boundary span (once per step or rarer)
+is a ``jax.profiler.TraceAnnotation``, so it lies on the device
+trace's own clock beside the XLA ops of whatever jax profiler session
+is on, and while recorder (1) runs it is also an "X" event under the
+same name.  :func:`span` stays the guard-first tool of per-op loops and
+reaches recorder (1) only.
 
 Distributed telemetry (PR 7): under a ``tools/launch.py`` job every
 event carries a rank-tagged pid (worker rank, or 10000 + shard id for
@@ -189,6 +195,55 @@ def span(name, cat="framework", args=None):
     if not _state["running"]:
         return _NULL_SPAN
     return scope(name, cat, args)
+
+
+class _BothClocks:
+    """A jax annotation and a chrome-trace ``scope`` entered as one."""
+
+    __slots__ = ("annotation", "recorded")
+
+    def __init__(self, annotation, recorded):
+        self.annotation = annotation
+        self.recorded = recorded
+
+    def __enter__(self):
+        self.recorded.__enter__()
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.annotation.__exit__(*exc)
+        return self.recorded.__exit__(*exc)
+
+
+def boundary_span(name, step_num=None, **stats):
+    """Span at a layer boundary (once per step or rarer), on the jax
+    profiler's clock.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **stats)`` — with
+    ``step_num`` a ``StepTraceAnnotation``, which the profiler's viewers
+    group steps by — so whoever has a jax profiler session on (the
+    benchmark's traced tail, :func:`start_xla_trace`, a TensorBoard
+    capture) finds it in plane ``/host:CPU`` on the calling thread's
+    line, its keyword arguments as stats, on the clock of the device
+    planes' ``XLA Ops``.  A span's parent is the span that encloses it
+    on that line.  No switch: with no session on, entering and leaving
+    costs about half a microsecond.  While the chrome-trace recorder is
+    running the span is also recorded there as the usual "X" event
+    under the same name (category ``boundary``).
+
+    Not for per-op loops: those keep the guard-first :func:`span`."""
+    import jax
+
+    if step_num is None:
+        annotation = jax.profiler.TraceAnnotation(name, **stats)
+    else:
+        annotation = jax.profiler.StepTraceAnnotation(
+            name, step_num=step_num, **stats)
+        stats = dict(stats, step_num=step_num)
+    if not _state["running"]:
+        return annotation
+    return _BothClocks(annotation, scope(name, "boundary", stats or None))
 
 
 def counter(name, values, cat="framework"):
