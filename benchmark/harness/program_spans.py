@@ -28,6 +28,7 @@ from . import trace
 
 PHASES = ("forward", "backward", "optimizer", "other")
 _IN_GRAD = re.compile(r"(^|[/(])grad/")     # xray.GRAD_MARKER as a scope
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
 PREFIX = "mxtpu."
 OUTSIDE = "outside_step"
 
@@ -53,18 +54,35 @@ def phase_of(op_name):
     return "other"
 
 
+def _instruction_phase(op, modules):
+    """The phase of one executed instruction from the first module that
+    knows it.  A fusion is backward if any instruction inside it is, else
+    optimizer, else forward: the compiler duplicates cheap forward
+    instructions into the backward fusions that consume them, and fuses the
+    update into the weight gradient, and such a fusion runs in the backward
+    pass.  (``hlo_cost.Module.instructions`` alone names a fusion without
+    convolutions by its first named instruction, which read 6.1 ms of a
+    ResNet-50 step's backward fusions as forward.)"""
+    for module in modules:
+        if op.name in module.instructions:
+            inside = {phase_of(module.instructions[op.name][1])}
+            for called in _CALLS.findall(op.text):
+                inside.update(phase_of(module.instructions[name][1])
+                              for name in module._comps.get(called, ()))
+            return next(p for p in ("backward", "optimizer", "forward",
+                                    "other") if p in inside)
+    return "other"
+
+
 def device_phase_s(recorded, modules):
     """{phase: seconds} of the first chip's busy time inside the traced
     window.  ``trace.leaf_ops`` (containers excluded) go to the phase of
-    their ``op_name``, taken from the first module that knows the
-    instruction, as in ``trace.stable_label``; a fusion goes whole to the
-    phase of its heaviest inner instruction (``hlo_cost.Module
-    .instructions``), so an optimizer update fused into a weight gradient
-    counts as backward.  Busy time that no such instruction covers goes to
-    other: the waits for overlapped slices and copies (``async-done``,
-    which ``leaf_ops`` leaves out as a container, 0.98 ms of a ResNet-50
-    step).  So the four sum to that chip's ``trace.busy``: one core runs
-    one instruction at a time."""
+    their ``op_name`` scope, a fusion whole to the latest phase found inside
+    it (``_instruction_phase``).  Busy time that no such instruction covers
+    goes to other: the waits for overlapped slices and copies
+    (``async-done``, which ``leaf_ops`` leaves out as a container, 0.98 ms
+    of a ResNet-50 step).  So the four sum to that chip's ``trace.busy``:
+    one core runs one instruction at a time."""
     seconds = dict.fromkeys(PHASES, 0.0)
     if not recorded.devices:
         return seconds
@@ -72,9 +90,7 @@ def device_phase_s(recorded, modules):
     leaves = trace.leaf_ops(recorded)
     for op in leaves:
         if op.name not in phases:
-            known = (m.instructions[op.name] for m in modules
-                     if op.name in m.instructions)
-            phases[op.name] = phase_of(next(known, (0, ""))[1])
+            phases[op.name] = _instruction_phase(op, modules)
         seconds[phases[op.name]] += (op.end - op.start) / 1e9
     first = trace.busy(recorded)[min(recorded.devices)]
     uncovered = trace.subtract(

@@ -228,7 +228,7 @@ def boundary_span(name, step_num=None, **stats):
     line, its keyword arguments as stats, on the clock of the device
     planes' ``XLA Ops``.  A span's parent is the span that encloses it
     on that line.  No switch: with no session on, entering and leaving
-    costs about half a microsecond.  While the chrome-trace recorder is
+    costs about a microsecond.  While the chrome-trace recorder is
     running the span is also recorded there as the usual "X" event
     under the same name (category ``boundary``).
 
@@ -238,9 +238,8 @@ def boundary_span(name, step_num=None, **stats):
     if step_num is None:
         annotation = jax.profiler.TraceAnnotation(name, **stats)
     else:
-        annotation = jax.profiler.StepTraceAnnotation(
-            name, step_num=step_num, **stats)
-        stats = dict(stats, step_num=step_num)
+        stats["step_num"] = step_num
+        annotation = jax.profiler.StepTraceAnnotation(name, **stats)
     if not _state["running"]:
         return annotation
     return _BothClocks(annotation, scope(name, "boundary", stats or None))

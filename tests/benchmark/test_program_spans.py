@@ -77,7 +77,8 @@ STEP_MODULE = """HloModule jit_step, is_scheduled=true
 %fc.bwd (p0: f32[256]) -> f32[256] {
   %p0 = f32[256]{0} parameter(0)
   %r.1 = f32[256]{0} maximum(%p0, %p0), metadata={op_name="jit(step)/grad/jvp(net)/relu0/max"}
-  ROOT %d.1 = f32[256,256]{1,0} dot(%r.1, %p0), lhs_contracting_dims={}, rhs_contracting_dims={}, metadata={op_name="jit(step)/grad/transpose(jvp(net))/dense0/dot_general"}
+  %g.1 = f32[256]{0} multiply(%r.1, %p0), metadata={op_name="jit(step)/grad/transpose(jvp(net))/relu0/mul"}
+  ROOT %u.1 = f32[256]{0} subtract(%p0, %g.1), metadata={op_name="jit(step)/optimizer/sub"}
 }
 
 %fc.opt (p0: f32[256]) -> f32[256] {
@@ -88,7 +89,7 @@ STEP_MODULE = """HloModule jit_step, is_scheduled=true
 ENTRY %main (a: f32[256]) -> f32[256] {
   %a = f32[256]{0} parameter(0)
   %fusion.fwd = f32[256]{0} fusion(%a), kind=kLoop, calls=%fc.fwd
-  %fusion.bwd = f32[256,256]{1,0} fusion(%fusion.fwd), kind=kOutput, calls=%fc.bwd
+  %fusion.bwd = f32[256]{0} fusion(%fusion.fwd), kind=kLoop, calls=%fc.bwd
   %copy.1 = f32[256]{0} copy(%a), metadata={op_name="train_vals[0]"}
   ROOT %fusion.opt = f32[256]{0} fusion(%a), kind=kLoop, calls=%fc.opt
 }
@@ -108,7 +109,8 @@ def _trace(ops, spans=()):
 
 def _step_trace():
     """Two steps of 100 ns: forward 20, backward 40 (a fusion that starts
-    with a duplicated forward instruction; its dot is the heaviest), a copy
+    with a forward instruction the compiler duplicated into it and ends in
+    the update, as on the chip: ``hlo_cost`` names it by the first), a copy
     of 5, a 7 ns wait in an ``async-done`` (a container to ``leaf_ops``),
     optimizer 10, and 18 idle."""
     ops = []
@@ -123,6 +125,8 @@ def _step_trace():
 
 def test_device_phases_conserve_the_first_chips_busy_time():
     t, modules = _step_trace(), [hlo_cost.Module(STEP_MODULE)]
+    assert program_spans.phase_of(
+        modules[0].instructions["fusion.bwd"][1]) == "forward"
     seconds = program_spans.device_phase_s(t, modules)
     assert seconds == {
         "forward": pytest.approx(40e-9), "backward": pytest.approx(80e-9),
@@ -285,3 +289,119 @@ def test_the_new_metrics_resolve_and_list_the_three_cells(metric):
     for cell in CELLS:
         assert entry in m.cell(cell).per_layer
         assert callable(m.cell(cell).reader(metric).read)
+
+
+# ----------------------------------------------------------- recorded trace
+
+FIXTURE = "resnet50_train_bs128_spans_2steps"
+
+
+@pytest.fixture()
+def recorded(tmp_path):
+    """(path, Trace, modules): two steps of resnet50_train_bs128 on the v5e
+    (PR 25), the last of one group and the first of the next, with the
+    optimized ``jit_step`` module of the same run."""
+    path = str(tmp_path / (FIXTURE + ".xplane.pb"))
+    with gzip.open(os.path.join(HERE, "fixtures",
+                                FIXTURE + ".xplane.pb.gz")) as src, \
+            open(path, "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    with gzip.open(os.path.join(HERE, "fixtures",
+                                FIXTURE + ".jit_step.hlo.txt.gz"),
+                   "rt") as f:
+        modules = [hlo_cost.Module(f.read())]
+    spans = {"dispatch", "loss_fetch", trace.WINDOW_SPAN}
+    return path, trace.Trace.from_xplane(path, spans), modules
+
+
+def test_recorded_device_phases_and_their_conservation(recorded):
+    _, t, modules = recorded
+    seconds = program_spans.device_phase_s(t, modules)
+    # per step: forward 13.75, backward 29.76, optimizer 0.05 (the update
+    # is fused into the weight gradients), other 4.40 ms
+    assert seconds == {
+        "forward": pytest.approx(0.027509799),
+        "backward": pytest.approx(0.059518428),
+        "optimizer": pytest.approx(0.000095147),
+        "other": pytest.approx(0.008797383)}
+    busy_s, window_s = trace.busy_and_window_s(t)
+    assert busy_s == pytest.approx(0.095920757)
+    assert sum(seconds.values()) == pytest.approx(busy_s)
+    # the instructions of their own alone fall 2 % short: the waits in
+    # ``async-done`` are containers to ``leaf_ops``
+    leaf_s = sum(op.end - op.start for op in trace.leaf_ops(t)) / 1e9
+    assert leaf_s == pytest.approx(0.093957032)
+    assert busy_s - leaf_s > 0.02 * busy_s
+    obs = {"trace": t, "modules": modules, "tail": {"steps": 2}}
+    assert program_spans.device_phase_ms_per_step(obs, "backward") == \
+        pytest.approx(29.759214)
+
+
+def test_recorded_heaviest_instructions_are_backward(recorded):
+    """What the ledger's cut names could not say: the three heaviest are
+    backward convolutions; the stage-1 batch-norm fusions after them run in
+    the backward pass too, though ``hlo_cost`` names them by the forward
+    instruction duplicated into them."""
+    _, t, modules = recorded
+    by_name = {}
+    for op in trace.leaf_ops(t):
+        entry = by_name.setdefault(op.name, [0, op])
+        entry[0] += op.end - op.start
+    ranked = sorted(by_name.values(), key=lambda e: -e[0])[:6]
+    named = [(op.name, program_spans._instruction_phase(op, modules),
+              program_spans.phase_of(modules[0].instructions[op.name][1]))
+             for _, op in ranked]
+    assert named == [
+        ("fusion.1677", "backward", "backward"),
+        ("fusion.1675", "backward", "backward"),
+        ("fusion.1676", "backward", "backward"),
+        ("fusion.1911", "backward", "forward"),
+        ("fusion.1921", "backward", "forward"),
+        ("fusion.1934", "backward", "forward")]
+    assert [round(ns / 2e6, 2) for ns, _ in ranked] == \
+        [1.45, 1.21, 1.17, 0.93, 0.91, 0.91]
+    for _, op in ranked[:3]:
+        assert modules[0].instructions[op.name][1].endswith(
+            "conv_general_dilated")
+
+
+def test_recorded_host_spans_run_ahead_of_the_chip(recorded):
+    """While the chip runs these two steps the host dispatches eight: it
+    is seven steps ahead, and the eighth blocks inside its key."""
+    path, t, _ = recorded
+    spans = program_spans.host_spans(path)
+    steps = [s for s in spans if s[0] == "mxtpu.step"]
+    assert [s[4]["step_num"] for s in steps] == list(range(231, 239))
+    assert {s[3] for s in spans} == {steps[0][3]}          # one line
+    launches = [s for s in spans if s[0] == "mxtpu.step.launch"]
+    assert {s[4]["leaves"] for s in launches} == {495}
+    parent = program_spans.parents(spans)
+    for i, span in enumerate(spans):
+        assert (parent[i] is None) == (span[0] == "mxtpu.step")
+        if parent[i] is not None:
+            assert spans[parent[i]][0] == "mxtpu.step"
+    # the step's own time: 39 us of the first step's 6.05 ms
+    assert program_spans.self_ns(spans)[0] == pytest.approx(39081)
+    keys = [round((s[2] - s[1]) / 1e6, 1) for s in spans
+            if s[0] == "mxtpu.step.key"]
+    assert keys == [2.4, 2.0, 1.8, 1.7, 1.6, 1.6, 1.5, 23.0]
+    # each key and launch lies inside one of the benchmark's dispatch spans
+    dispatches = [s for s in t.spans if s[0] == "dispatch"]
+    for _, start, end, _, _ in spans:
+        assert any(d[1] <= start and end <= d[2] for d in dispatches)
+    # inside the (cut) window: seven launches, eight keys of which the
+    # blocked one ends after it
+    window = t.window()
+    assert program_spans.span_ms_per_step(
+        spans, "mxtpu.step.launch", window, 2) == pytest.approx(7.2132195)
+    assert program_spans.span_ms_per_step(
+        spans, "mxtpu.step.key", window, 2) == pytest.approx(6.306623)
+    # the chip idles 3.4 ms while the host draws the next group's first key
+    # and 2.4 ms before that, while the benchmark fetches the loss
+    assert program_spans.idle_by_span(t, spans) == [
+        ["mxtpu.step.key", pytest.approx(0.003367175)],
+        ["outside_step", pytest.approx(0.002422794)],
+        ["mxtpu.step.launch", pytest.approx(2.965e-06)],
+        ["mxtpu.step", pytest.approx(1.8e-08)]]
+    assert sum(s for _, s in program_spans.idle_by_span(t, spans)) == \
+        pytest.approx(sum(s for _, s in trace.idle_gaps(t)))

@@ -5,8 +5,16 @@ nowhere otherwise.  A live CPU profile (python tracer off, as the benchmark
 traces) of three steps of a two-layer network, in both layouts and with
 both kinds of optimizer."""
 
+import os
+import sys
+
 import numpy as np
 import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+
+from benchmark.harness import program_spans, trace  # noqa: E402
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, optimizer as opt_mod, profiler
@@ -51,12 +59,9 @@ def _batch():
 
 
 def _profile(tmp_path, body):
-    """The ``mxtpu.*`` events of plane /host:CPU while ``body`` ran:
-    [(name, start, end, line, stats)] by start."""
-    import glob
-
+    """The ``mxtpu.*`` events of plane /host:CPU while ``body`` ran, as the
+    benchmark reads them: [(name, start, end, line, stats)] by start."""
     import jax
-    from jax.profiler import ProfileData
 
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -65,19 +70,7 @@ def _profile(tmp_path, body):
         body()
     finally:
         jax.profiler.stop_trace()
-    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
-                          "*.xplane.pb"))
-    spans = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name != "/host:CPU":
-            continue
-        for index, line in enumerate(plane.lines):
-            for e in line.events:
-                if e.name.startswith("mxtpu."):
-                    spans.append((e.name, e.start_ns,
-                                  e.start_ns + e.duration_ns, index,
-                                  dict(e.stats)))
-    return sorted(spans, key=lambda s: s[1])
+    return program_spans.host_spans(trace.newest_xplane(str(tmp_path)))
 
 
 @pytest.mark.parametrize("with_optimizer", [False, True],
