@@ -35,6 +35,9 @@ Used by bench.py, __graft_entry__.py and the multi-chip Trainer path.
 
 from __future__ import annotations
 
+import functools
+import hashlib
+import json
 import os
 
 import numpy as _np
@@ -240,12 +243,109 @@ def _put(vals, shard):
     return tuple(jax.device_put(v, shard) for v in vals)
 
 
+def _is_models(order):
+    return order == tuple(range(len(order)))
+
+
+def _in_order(v, order):
+    """``v`` with its dimensions in ``order``, major first."""
+    import jax.numpy as jnp
+
+    return v if _is_models(order) else jnp.transpose(v, order)
+
+
+def _in_model_order(stored, order):
+    """Inverse of :func:`_in_order`."""
+    return _in_order(stored, tuple(int(i) for i in _np.argsort(order)))
+
+
+def _shard_in_order(shard, order):
+    """``shard`` of a leaf for the same leaf held in ``order``."""
+    from jax.sharding import NamedSharding, PartitionSpec as _P
+
+    spec = tuple(shard.spec) + (None,) * (len(order) - len(shard.spec))
+    return NamedSharding(shard.mesh, _P(*(spec[i] for i in order)))
+
+
+def _orders_dir():
+    """Where the orders a step learned are kept: jax's persistent compile
+    cache directory, None if there is none."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir
+
+
+def _read_orders(path, held):
+    """The orders kept at ``path`` if they fit the trees ``held``."""
+    try:
+        with open(path) as f:
+            orders = tuple(tuple(tuple(o) for o in tree)
+                           for tree in json.load(f))
+    except (OSError, ValueError, TypeError):
+        return None
+    fits = len(orders) == len(held) and all(
+        len(tree) == len(vals) and all(
+            sorted(o) == list(range(v.ndim)) for o, v in zip(tree, vals))
+        for tree, vals in zip(orders, held))
+    return orders if fits else None
+
+
+def _write_orders(path, orders):
+    try:
+        with open(path + ".part", "w") as f:
+            json.dump(orders, f)
+        os.replace(path + ".part", path)
+    except OSError:     # a cache that cannot be written to: learn again
+        pass
+
+
+def _state_tree(i, doc):
+    """One of the three state trees of a ``GluonTrainStep``: read and
+    assigned in the model's shapes, kept as the step holds it."""
+
+    def get(self):
+        held = self._held[i]
+        if self._orders is None:
+            return held
+        return tuple(_in_model_order(s, o)
+                     for s, o in zip(held, self._orders[i]))
+
+    def put(self, vals):
+        vals = tuple(vals)
+        if self._orders is not None:
+            vals = tuple(_in_order(v, o)
+                         for v, o in zip(vals, self._orders[i]))
+        self._held[i] = vals
+
+    return property(get, put, doc=doc)
+
+
 class GluonTrainStep:
     """Compile a Gluon block + loss + optimizer into one sharded step.
 
     Parameters live as jax arrays in this object (functional style); call
     ``sync_to_params()`` to write them back into the block's Parameters
     for checkpointing with the normal Gluon API.
+
+    Where the state lives, and in which order: ``train_vals``,
+    ``opt_state`` and ``aux_vals`` are donated to every step and rebound
+    to its results.  On the replicated/dp path each leaf is *held* with
+    its dimensions in the order the step program's compiler would lay it
+    out in (a convolution weight's update is fused into the fusion that
+    computes its gradient, which writes another layout than the runtime's
+    default for the model's shape): at the first call the step is
+    compiled once ahead of time with ``Layout.AUTO`` on the state, only
+    to read that order per leaf (the answer is kept beside jax's
+    persistent compile cache, where there is one, and read from there
+    by later processes); the state is transposed into it, once; and the
+    program that runs takes and returns the state in that order, in the
+    runtime's default layout, with free transposes at its edges.
+    So no step copies a weight or its momentum between layouts, and
+    nothing depends on a non-default layout surviving the persistent
+    compile cache (an executable loaded from it does not keep one).  The
+    three attributes read in the model's shapes whatever the order held
+    (a leaf held in another order is transposed back on reading).  The
+    ZeRO path holds flat 1-D shards, for which there is nothing to choose.
 
     compute_dtype: 'bfloat16' casts activations/weights for the matmul/
     conv path while keeping master weights and the update fp32 — the
@@ -262,6 +362,11 @@ class GluonTrainStep:
     the step (the real fused-kernel update); None keeps the fused
     sgd-momentum closure built from ``lr/momentum/wd``.
     """
+
+    train_vals = _state_tree(0, "The trainable parameters' values.")
+    opt_state = _state_tree(1, "The optimizer's state leaves.")
+    aux_vals = _state_tree(2, "The values of the parameters that take no "
+                              "gradient (batch-norm statistics).")
 
     def __init__(self, block, loss_block, mesh=None, lr=0.1, momentum=0.9,
                  wd=0.0, compute_dtype=None, param_spec_fn=None,
@@ -284,19 +389,22 @@ class GluonTrainStep:
         params = list(block.collect_params().values())
         self.trainable = [p for p in params if p.grad_req != "null"]
         self.aux = [p for p in params if p.grad_req == "null"]
-        self.train_vals = tuple(p.data().data_jax for p in self.trainable)
-        self.aux_vals = tuple(p.data().data_jax for p in self.aux)
+        self._held = [tuple(p.data().data_jax for p in self.trainable), (),
+                      tuple(p.data().data_jax for p in self.aux)]
         if optimizer is not None:
             self._opt_update = _OptimizerUpdate(
-                optimizer, [v.dtype for v in self.train_vals])
+                optimizer, [v.dtype for v in self._held[0]])
             self._update = None
         else:
             self._opt_update = None
             self._update = sgd_momentum_update(lr, momentum, wd)
         self._compute_dtype = compute_dtype
         self.last_grad_norm = None
+        self._step = None
         self._calls = 0        # step_num of the next mxtpu.step span
         self._leaves = None    # array arguments of one launch
+        self._orders = None    # per state leaf, the order it is held in
+        self._relaid = 0       # leaves held in another order than the model's
         pure_loss = _pure_loss_builder(block, loss_block, self.trainable,
                                        self.aux,
                                        aux_loss_weight=aux_loss_weight)
@@ -407,14 +515,138 @@ class GluonTrainStep:
         self.opt_state = _put(self.opt_state, state_shard)
         self.aux_vals = _put(self.aux_vals, aux_shard)
 
-        self._step_py = step  # un-jitted; composed by make_chained()
-        self._step = jax.jit(
-            step,
-            in_shardings=sig_in,
+        def per_leaf(shard, vals):
+            return shard if isinstance(shard, tuple) else (shard,) * len(vals)
+
+        self._state_shard = tuple(
+            per_leaf(shard, vals) for shard, vals in zip(
+                (tv_shard, state_shard, aux_shard), self._held))
+        self._rest_in = sig_in[3:]
+        # un-jitted; composed by make_chained().  self._step is built by
+        # _adopt_orders() at the first call: the order the state is held
+        # in (class docstring) needs the batch's shape to be learned
+        self._step_py = step
+
+    def _compilers_orders(self, x, y, rest):
+        """Per state leaf, its dimensions in the order (major first) the
+        compiler lays it out in when the choice is its own: the step
+        compiled ahead of time with ``Layout.AUTO`` on the state, and
+        dropped once read."""
+        import jax
+        from jax.experimental.layout import Format, Layout
+
+        auto = jax.tree.map(lambda shard: Format(Layout.AUTO, shard),
+                            self._state_shard)
+
+        def spec(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        def asked(*args):
+            # a function of its own: jax keeps what it compiles for as
+            # long as the function lives, and this program is only read
+            return self._step_py(*args)
+
+        compiled = jax.jit(
+            asked, in_shardings=(*auto, *self._rest_in),
+            out_shardings=(self._repl, *auto, self._repl),
+            donate_argnums=(0, 1, 2)).lower(
+            *jax.tree.map(spec, tuple(self._held)), spec(x), spec(y),
+            *rest).compile()
+        # the results': the layout the fused update writes
+        return tuple(
+            tuple(tuple(f.layout.major_to_minor) for f in tree)
+            for tree in compiled.output_formats[1:4])
+
+    def _orders_path(self, x, y):
+        """The file that keeps what ``_compilers_orders`` answers for a
+        batch like (x, y), named after what the answer can depend on;
+        None where no compile cache is kept.  (With the answer kept, a
+        process whose programs all come from the cache loads the step
+        once, as before, and not the program that is only read too.)"""
+        import jax
+
+        cache = _orders_dir()
+        if not cache:
+            return None
+        chip = self.mesh.devices.flat[0]
+        asked = repr((
+            jax.__version__, chip.client.platform_version, chip.device_kind,
+            tuple(self.mesh.shape.items()), type(self.block).__name__,
+            type(getattr(self._opt_update, "opt", None)).__name__,
+            str(self._compute_dtype),
+            [p.name for p in self.trainable + self.aux],
+            [(v.shape, str(v.dtype), str(shard.spec))
+             for tree, shards in zip(self._held, self._state_shard)
+             for v, shard in zip(tree, shards)],
+            x.shape, str(x.dtype), y.shape, str(y.dtype)))
+        return os.path.join(cache, "mxtpu-step-orders-%s.json"
+                            % hashlib.sha256(asked.encode()).hexdigest()[:32])
+
+    def _adopt_orders(self, x, y, rest):
+        """Once, at the first call: learn the compiler's order of every
+        state leaf (or read what an earlier process learned), transpose
+        the state into it and build the jitted step that takes and
+        returns it so."""
+        import jax
+
+        path = self._orders_path(x, y)
+        orders = path and _read_orders(path, self._held)
+        if not orders:
+            orders = self._compilers_orders(x, y, rest)
+            if path:
+                _write_orders(path, orders)
+        self._orders = orders
+        # one program moves the leaves whose order is not the model's
+        moving = [(i, j) for i, tree in enumerate(orders)
+                  for j, order in enumerate(tree) if not _is_models(order)]
+        if moving:
+            moved = dict(zip(moving, jax.jit(
+                lambda *leaves: tuple(
+                    _in_order(v, orders[i][j])
+                    for v, (i, j) in zip(leaves, moving)),
+                out_shardings=tuple(
+                    _shard_in_order(self._state_shard[i][j], orders[i][j])
+                    for i, j in moving))(
+                *(self._held[i][j] for i, j in moving))))
+            self._held = [
+                tuple(moved.get((i, j), v) for j, v in enumerate(tree))
+                for i, tree in enumerate(self._held)]
+        self._relaid = len(moving)
+        _rts.inc("step_state_relayouts")
+        self._step = self._jit(self._step_py, 1)
+
+    def _jit(self, fn, n_tail):
+        """jit ``fn(train_vals, opt_state, aux_vals, x, y, key[, scalars])
+        -> (loss, train_vals, opt_state, aux_vals, *tail)`` on the state
+        as it is held: donated, each leaf transposed to the model's order
+        on the way in and back on the way out (free: the held order is
+        the order the compiler lays the leaf out in)."""
+        import jax
+
+        orders = self._orders
+        shards = tuple(
+            tuple(_shard_in_order(s, o) for s, o in zip(tree, tree_orders))
+            for tree, tree_orders in zip(self._state_shard, orders))
+
+        def reorder(one, state):
+            return tuple(
+                tuple(one(v, o) for v, o in zip(tree, tree_orders))
+                for tree, tree_orders in zip(state, orders))
+
+        @functools.wraps(fn)
+        def on_held(train_vals, opt_state, aux_vals, *rest):
+            loss, *out = fn(*reorder(_in_model_order,
+                                     (train_vals, opt_state, aux_vals)),
+                            *rest)
+            return (loss, *reorder(_in_order, out[:3]), *out[3:])
+
+        return jax.jit(
+            on_held,
+            in_shardings=(*shards, *self._rest_in),
             # pin outputs to the input layouts: the functional state must
             # keep its sharding across steps (otherwise the compiler may
             # re-shard e.g. a bias, and step 2's in_shardings reject it)
-            out_shardings=(repl, tv_shard, state_shard, aux_shard, repl),
+            out_shardings=(self._repl, *shards) + (self._repl,) * n_tail,
             donate_argnums=(0, 1, 2),
         )
 
@@ -617,14 +849,20 @@ class GluonTrainStep:
             tv, os_, av, loss = lax.fori_loop(0, n_steps, body, init)
             return loss, tv, os_, av
 
-        jitted = jax.jit(chained, donate_argnums=(0, 1, 2))
-
         def run(x, y, key):
-            loss, self.train_vals, self.opt_state, self.aux_vals = jitted(
-                self.train_vals, self.opt_state, self.aux_vals, x, y, key)
+            if run._jitted is None:
+                # ZeRO: flat shards, a plain jit.  Else the carry is the
+                # state as it is held (class docstring)
+                if self._zero:
+                    run._jitted = jax.jit(chained, donate_argnums=(0, 1, 2))
+                else:
+                    if self._orders is None:
+                        self._adopt_orders(x, y, (key,))
+                    run._jitted = self._jit(chained, 0)
+            loss, *self._held = run._jitted(*self._held, x, y, key)
             return loss
 
-        run._jitted = jitted  # donation introspection (tests)
+        run._jitted = None  # from the first run on; donation introspection
         return run
 
     def put_batch(self, x, y):
@@ -646,16 +884,18 @@ class GluonTrainStep:
                     x, y = self.put_batch(x, y)
             with span("mxtpu.step.key"):
                 key = _random.next_key()
-            args = [self.train_vals, self.opt_state, self.aux_vals, x, y,
-                    key]
+            rest = [key]
             if self._opt_update is not None:
                 with span("mxtpu.step.scalars"):
-                    args.append(self._opt_update.host_scalars())
+                    rest.append(self._opt_update.host_scalars())
+            if self._step is None:
+                self._adopt_orders(x, y, rest)
+            args = [*self._held, x, y, *rest]
             if self._leaves is None:    # fixed from the first call on
                 self._leaves = len(jax.tree_util.tree_leaves(args))
-            with span("mxtpu.step.launch", leaves=self._leaves):
-                (loss, self.train_vals, self.opt_state, self.aux_vals,
-                 gnorm) = self._step(*args)
+            with span("mxtpu.step.launch", leaves=self._leaves,
+                      relaid_leaves=self._relaid):
+                loss, *self._held, gnorm = self._step(*args)
             # the last references to the donated arrays: released inside
             # the span (0.7-2.4 ms a step on the chip), not after it
             del args
@@ -671,6 +911,19 @@ class GluonTrainStep:
                 if hm is not None:
                     hm.observe_scalar("grad_norm", gnorm)
         return loss
+
+    def program_for(self, x, y):
+        """The step compiled ahead of time for a batch like (x, y), for
+        ``as_text()``, ``cost_analysis()`` and ``memory_analysis()``: the
+        program ``__call__`` runs for it, compiled once more."""
+        import jax
+
+        rest = [jax.random.PRNGKey(0)]  # shape/dtype stand-in only
+        if self._opt_update is not None:
+            rest.append(tuple(0.0 for _ in self._opt_update.slots))
+        if self._step is None:
+            self._adopt_orders(x, y, rest)
+        return self._step.lower(*self._held, x, y, *rest).compile()
 
     def sync_to_params(self):
         """Write functional values back into the Gluon Parameters.

@@ -33,11 +33,7 @@ D = 16
 
 def _step_hlo(step, x, y):
     """Optimized (post-SPMD-partitioning) HLO of the compiled step."""
-    import mxnet_tpu.random as mxrandom
-
-    key = mxrandom.next_key()
-    return step._step.lower(step.train_vals, step.opt_state,
-                            step.aux_vals, x, y, key).compile().as_text()
+    return step.program_for(x, y).as_text()
 
 
 def _dense_net():

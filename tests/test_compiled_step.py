@@ -569,11 +569,6 @@ def test_make_chained_donates_carry_and_writes_back():
     key = jax.random.PRNGKey(7)
 
     run = step.make_chained(3)
-    # donation is declared in the lowered program (buffer_donor /
-    # aliasing annotations on the carry arguments)
-    txt = run._jitted.lower(step.train_vals, step.opt_state,
-                            step.aux_vals, x, y, key).as_text()
-    assert ("jax.buffer_donor" in txt) or ("tf.aliasing_output" in txt)
 
     # reference trajectory: 3 sequential un-jitted steps, same keys
     tv, os_, av = step.train_vals, step.opt_state, step.aux_vals
@@ -590,6 +585,10 @@ def test_make_chained_donates_carry_and_writes_back():
     for new, ref in zip(step.train_vals, tv):
         np.testing.assert_allclose(np.asarray(new), np.asarray(ref),
                                    rtol=2e-5, atol=2e-6)
+    # donation is declared in the lowered program (buffer_donor /
+    # aliasing annotations on the carry arguments)
+    txt = run._jitted.lower(*step._held, x, y, key).as_text()
+    assert ("jax.buffer_donor" in txt) or ("tf.aliasing_output" in txt)
     # a second call works on the rebound state (no deleted-buffer use)
     run(x, y, key)
 

@@ -1,0 +1,398 @@
+"""PR 27: ``GluonTrainStep`` (replicated/dp path) holds each leaf of its
+functional state with its dimensions in the order the step program's
+compiler lays it out in: learned once from an ahead-of-time compile with
+``Layout.AUTO``, moved there once, read back in the model's shapes.
+
+The CPU compiler chooses the default order for every leaf, so the cases
+that need another order put one in place of the compiler's answer."""
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, optimizer as opt_mod, profiler, runtime_stats
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.parallel.gluon_step import GluonTrainStep
+from mxnet_tpu.parallel.mesh import create_mesh
+
+
+def _net(prefix):
+    mx.random.seed(7)
+    net = nn.HybridSequential(prefix=prefix)
+    with net.name_scope():
+        net.add(nn.Conv2D(8, 3, padding=1), nn.BatchNorm(),
+                nn.Activation("relu"), nn.Flatten(), nn.Dense(4))
+    net.initialize(ctx=mx.cpu())
+    net(mx.nd.zeros((2, 3, 6, 6), ctx=mx.cpu()))
+    return net
+
+
+def _step(net, with_optimizer=False, mesh=None, **kwargs):
+    import jax
+
+    if with_optimizer:
+        kwargs["optimizer"] = opt_mod.create("adam", learning_rate=0.01)
+    else:
+        kwargs.update(lr=0.1, momentum=0.9, wd=1e-4)
+    mesh = mesh or {"dp": 2}
+    n = int(np.prod(list(mesh.values())))
+    return GluonTrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        mesh=create_mesh(mesh, devices=jax.devices()[:n]), **kwargs)
+
+
+def _batch(n=8):
+    rs = np.random.RandomState(0)
+    return (rs.rand(n, 3, 6, 6).astype(np.float32),
+            rs.randint(0, 4, (n,)).astype(np.int32))
+
+
+def _relayouts():
+    return runtime_stats.snapshot()["counters"].get(
+        "step_state_relayouts", 0)
+
+
+@pytest.fixture
+def minor_first(monkeypatch):
+    """The compiler's answer replaced: every leaf minor dimension first,
+    as a TPU holds a convolution's weight (HWIO for the model's OIHW)."""
+    real = GluonTrainStep._compilers_orders
+
+    def reversed_orders(self, x, y, rest):
+        return tuple(tuple(tuple(reversed(order)) for order in tree)
+                     for tree in real(self, x, y, rest))
+
+    monkeypatch.setattr(GluonTrainStep, "_compilers_orders",
+                        reversed_orders)
+
+
+def _three_steps(step, x, y):
+    mx.random.seed(11)
+    return [np.asarray(step(x, y)) for _ in range(3)]
+
+
+def _default_layout_steps(plain, x, y, with_optimizer):
+    """Three steps of ``_step_py`` under a plain ``jax.jit``, the state
+    as the model has it: -> (losses, final state)."""
+    import jax
+
+    from mxnet_tpu import random as mxrandom
+
+    mx.random.seed(11)
+    default = jax.jit(plain._step_py)
+    state = (plain.train_vals, plain.opt_state, plain.aux_vals)
+    losses = []
+    for _ in range(3):
+        rest = [mxrandom.next_key()]
+        if with_optimizer:
+            rest.append(plain._opt_update.host_scalars())
+        loss, *state, _gnorm = default(*state, x, y, *rest)
+        losses.append(np.asarray(loss))
+    return losses, jax.tree.leaves(state)
+
+
+def _read(step):
+    return list(step.train_vals + step.opt_state + step.aux_vals)
+
+
+@pytest.mark.parametrize("with_optimizer", [False, True],
+                         ids=["fused_sgd", "optimizer"])
+def test_the_compilers_orders_and_bit_for_bit_the_default_step(
+        with_optimizer):
+    """The ahead-of-time compile with ``Layout.AUTO`` answers for every
+    leaf; on the CPU the answer is the model's own order, and the step
+    that runs is, bit for bit, ``_step_py`` under a plain ``jax.jit``."""
+    net = _net("bit%d_" % with_optimizer)
+    step = _step(net, with_optimizer)
+    plain = _step(net, with_optimizer)
+    x, y = step.put_batch(*_batch())
+    before = _relayouts()
+    got = _three_steps(step, x, y)
+    assert _relayouts() - before == 1
+    for tree, orders in zip(step._held, step._orders):
+        assert [sorted(o) for o in orders] \
+            == [list(range(v.ndim)) for v in tree]
+    assert step._relaid == sum(
+        o != tuple(range(len(o))) for tree in step._orders for o in tree)
+    want, state = _default_layout_steps(plain, x, y, with_optimizer)
+    if step._relaid == 0:
+        assert all(np.array_equal(a, b) for a, b in zip(got, want))
+        assert all(np.array_equal(np.asarray(a), np.asarray(b))
+                   for a, b in zip(_read(step), state))
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("with_optimizer", [False, True],
+                         ids=["fused_sgd", "optimizer"])
+def test_the_state_moves_once_and_reads_in_the_models_shapes(
+        minor_first, with_optimizer):
+    net = _net("lay%d_" % with_optimizer)
+    step = _step(net, with_optimizer)
+    plain = _step(net, with_optimizer)
+    shapes = [v.shape for v in _read(step)]
+    x, y = step.put_batch(*_batch())
+    before = _relayouts()
+    got = _three_steps(step, x, y)
+    assert _relayouts() - before == 1
+    # held minor first, read as the model has them
+    assert [v.shape for tree in step._held for v in tree] \
+        == [s[::-1] for s in shapes]
+    assert [v.shape for v in _read(step)] == shapes
+    assert step._relaid == sum(len(s) > 1 for s in shapes) > 0
+    want, state = _default_layout_steps(plain, x, y, with_optimizer)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    if not with_optimizer:
+        # (Adam turns the rounding noise of a gradient that is zero, the
+        # bias ahead of a batch norm, into steps of the size of lr)
+        for a, b in zip(_read(step), state):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-6)
+
+    for _ in range(10):
+        loss = step(x, y)
+    assert np.isfinite(float(np.asarray(loss)))
+    # a short last batch: one more trace of the same jit, nothing moves
+    traces = step._step._cache_size()
+    step(*_batch(4))
+    step(x, y)
+    assert step._step._cache_size() == traces + 1
+    assert _relayouts() - before == 1
+    assert [v.shape for tree in step._held for v in tree] \
+        == [s[::-1] for s in shapes]
+
+
+def test_a_sharded_leaf_keeps_its_axes_in_the_held_order(minor_first):
+    """``param_spec_fn``: the partition follows the dimension it names."""
+    from jax.sharding import PartitionSpec as P
+
+    def spec(name, shape):
+        return P("tp") if name.endswith("dense0_weight") else P()
+
+    net = _net("tp_")
+    step = _step(net, mesh={"dp": 2, "tp": 2}, param_spec_fn=spec)
+    plain = _step(net, mesh={"dp": 2, "tp": 2}, param_spec_fn=spec)
+    x, y = step.put_batch(*_batch())
+    got = _three_steps(step, x, y)
+    want, _ = _default_layout_steps(plain, x, y, False)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for tree in step._held[:2]:
+        dense = [v for p, v in zip(step.trainable, tree)
+                 if p.name.endswith("dense0_weight")]
+        assert [(v.shape, v.sharding.spec) for v in dense] \
+            == [((288, 4), P(None, "tp"))]
+
+
+def test_what_was_learned_is_kept_beside_the_compile_cache(
+        minor_first, monkeypatch, tmp_path):
+    """With a persistent compile cache, the next process reads the orders
+    and does not compile the program that is only read."""
+    from mxnet_tpu.parallel import gluon_step
+
+    net = _net("kept_")
+    x, y = _batch()
+    first = _step(net)
+    first(x, y)                         # no cache directory: nothing kept
+    assert list(tmp_path.iterdir()) == []
+
+    monkeypatch.setattr(gluon_step, "_orders_dir", lambda: str(tmp_path))
+    second = _step(net)
+    loss = float(np.asarray(second(x, y)))
+    kept, = tmp_path.iterdir()
+    assert kept.name.startswith("mxtpu-step-orders-")
+
+    def never(self, x, y, rest):
+        raise AssertionError("the orders were kept: nothing to compile")
+
+    monkeypatch.setattr(GluonTrainStep, "_compilers_orders", never)
+    third = _step(net)
+    assert float(np.asarray(third(x, y))) == loss
+    assert third._orders == second._orders == first._orders
+    # another batch, another answer to ask for; a file that does not fit
+    # the state is not believed
+    with pytest.raises(AssertionError, match="nothing to compile"):
+        _step(net)(*_batch(4))
+    kept.write_text("[[[0]], [], []]")
+    with pytest.raises(AssertionError, match="nothing to compile"):
+        _step(net)(x, y)
+
+
+def test_sync_to_params_and_the_chain_round_trip(minor_first):
+    import jax
+
+    net = _net("rt_")
+    step = _step(net, mesh={"dp": 1})
+    x, y = step.put_batch(*_batch())
+    key = jax.random.PRNGKey(3)
+    before = _relayouts()
+
+    # the chain is the first to run: it learns the orders and moves the
+    # state, the step's own program takes the state as the chain left it
+    run = step.make_chained(2)
+    tv, os_, av = step.train_vals, step.opt_state, step.aux_vals
+    for i in range(2):
+        want, tv, os_, av, _gn = step._step_py(
+            tv, os_, av, x, y, jax.random.fold_in(key, i))
+    got = run(x, y, key)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    for new, ref in zip(step.train_vals, tv):
+        np.testing.assert_allclose(np.asarray(new), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-6)
+    held = step._held[0][0]
+    assert held.shape == (3, 3, 3, 8)
+    step(x, y)
+    assert held.is_deleted()        # donated by the step, as it was held
+    run(x, y, key)
+    step(x, y)
+    assert _relayouts() - before == 1
+
+    step.sync_to_params()
+    for p, v in zip(step.trainable + step.aux,
+                    step.train_vals + step.aux_vals):
+        assert p.data().shape == v.shape
+        assert np.array_equal(p.data().asnumpy(), np.asarray(v))
+    # and the parameters feed the eager API as ever
+    assert net(mx.nd.array(np.asarray(x))).shape == (8, 4)
+
+    # assigned in the model's shapes, kept as held
+    step.train_vals = [np.asarray(v) * 0 for v in step.train_vals]
+    assert step._held[0][0].shape == (3, 3, 3, 8)
+    assert not np.asarray(step.train_vals[0]).any()
+
+
+def test_the_launch_span_carries_relaid_leaves(minor_first):
+    step = _step(_net("span_"))
+    x, y = _batch()
+    step(x, y)
+    profiler.set_state("run")
+    try:
+        float(np.asarray(step(x, y)))
+    finally:
+        profiler.set_state("stop")
+    launch, = [e for e in profiler._state["events"]
+               if e["name"] == "mxtpu.step.launch"]
+    profiler.dumps(reset=True)
+    assert launch["args"] == {"leaves": step._leaves, "relaid_leaves": 4}
+
+
+def test_zero_holds_flat_shards_and_nothing_moves(minor_first):
+    net = _net("zl_")
+    before = _relayouts()
+    step = _step(net, zero=True)
+    x, y = _batch()
+    step(x, y)
+    step(x, y)
+    assert step._orders is None and step._relaid == 0
+    assert _relayouts() == before
+    assert all(v.ndim == 1 for v in step.train_vals + step.opt_state)
+    assert "input_output_alias" in step.program_for(
+        *step.put_batch(x, y)).as_text()
+
+
+# --------------------------------------------------- the v5e's compiler
+# On the CPU the compiler's order is the model's.  The TPU's compiler is
+# installed here and compiles for a chip that is described, not attached
+# (PERF.md §3): the same small network, its shardings swapped for the
+# described chip's.
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mx.random.seed(7)
+    net = nn.HybridSequential(prefix="v5e_")
+    with net.name_scope():
+        for width in (64, 128):
+            net.add(nn.Conv2D(width, 3, padding=1, layout="NHWC",
+                              use_bias=False),
+                    nn.BatchNorm(axis=3), nn.Activation("relu"))
+        net.add(nn.GlobalAvgPool2D(layout="NHWC"), nn.Dense(10))
+    net.initialize(ctx=mx.cpu())
+    net(mx.nd.zeros((1, 16, 16, 64), ctx=mx.cpu()))
+    step = GluonTrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(),
+        mesh=create_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        lr=0.1, momentum=0.9, wd=1e-4, compute_dtype="bfloat16")
+    mesh = Mesh(np.array(v5e.devices[:1]), ("dp",))
+    repl, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    step._repl, step._rest_in = repl, (batch, batch, repl)
+    step._state_shard = jax.tree.map(lambda _s: repl, step._state_shard)
+    x = jax.ShapeDtypeStruct((32, 16, 16, 64), jnp.float32)
+    y = jax.ShapeDtypeStruct((32,), jnp.int32)
+    rest = [jax.ShapeDtypeStruct((2,), jnp.uint32)]
+
+    def copies_at_the_entry(orders):
+        step._orders = orders
+        held = [tuple(jax.ShapeDtypeStruct(
+            tuple(v.shape[i] for i in order), v.dtype)
+            for v, order in zip(tree, tree_orders))
+            for tree, tree_orders in zip(step._held, orders)]
+        text = step._jit(step._step_py, 1).lower(
+            *held, x, y, *rest).compile().as_text()
+        entry = text[text.index("\nENTRY "):]
+        return len(re.findall(r" copy\(", entry[:entry.index("\n}")]))
+
+    as_the_model = tuple(tuple(tuple(range(v.ndim)) for v in tree)
+                         for tree in step._held)
+    # each OHWI weight and its momentum: into the weight-gradient
+    # fusion's layout (the second's on the way in too) and back out
+    assert copies_at_the_entry(as_the_model) >= 4
+    orders = step._compilers_orders(x, y, rest)
+    assert [o for tree in orders for o in tree if len(o) == 4] \
+        == [(1, 2, 3, 0)] * 4          # HWIO, weights and momenta
+    assert copies_at_the_entry(orders) == 0
+
+
+# ------------------------------------ why orders, and not layouts, are held
+
+_ASKS_FOR_A_LAYOUT = """
+import jax, jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+wanted = Format(Layout(major_to_minor=(1, 2, 3, 0)),
+                SingleDeviceSharding(jax.devices()[0]))
+out = jax.jit(lambda v: v * 2, out_shardings=wanted)(jnp.ones((4, 3, 3, 8)))
+print(out.format.layout.major_to_minor)
+"""
+
+
+def test_a_program_from_the_compile_cache_forgets_its_result_layout(
+        tmp_path):
+    """The toolchain's behaviour this design stands on (jax 0.9.0; the same
+    on the v5e with libtpu 0.0.34, PERF.md Findings PR 27): compiled in the
+    process, a program gives its result in the layout it was compiled for;
+    loaded from the persistent compile cache, in the default one.  If the
+    second line ever reads (1, 2, 3, 0) too, the state could hold the
+    compiler's layouts themselves (ISSUE 27's first design)."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    runs = [subprocess.run([sys.executable, "-c", _ASKS_FOR_A_LAYOUT],
+                           env=env, capture_output=True, text=True,
+                           timeout=120) for _ in range(2)]
+    assert [r.returncode for r in runs] == [0, 0], runs[1].stderr[-2000:]
+    assert [r.stdout.strip().splitlines()[-1] for r in runs] \
+        == ["(1, 2, 3, 0)", "(0, 1, 2, 3)"]

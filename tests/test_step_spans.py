@@ -99,7 +99,8 @@ def test_three_steps_on_the_profilers_clock(tmp_path, zero, with_optimizer):
         assert {c[3] for c in children} == {line}
         assert all(a[2] <= b[1] for a, b in zip(children, children[1:]))
         launch = children[-1]
-        assert launch[4] == {"leaves": step._leaves}
+        assert launch[4] == {"leaves": step._leaves,
+                             "relaid_leaves": step._relaid}
     assert len(spans) == 3 * (1 + len(expected)) + 2
     # every array the launch flattens: parameters, optimizer state,
     # statistics, batch, labels, key and the optimizer's host scalars

@@ -110,13 +110,9 @@ def step_cost_model(step, x, y):
     summary carries the cost-model columns next to the measured ones.
     Backends without the analyses just yield no columns."""
     try:
-        from mxnet_tpu import random as mxrandom
         from mxnet_tpu.ops.registry import compiled_cost
 
-        compiled = step._step.lower(
-            step.train_vals, step.opt_state, step.aux_vals, x, y,
-            mxrandom.next_key()).compile()
-        return compiled_cost(compiled) or {}
+        return compiled_cost(step.program_for(x, y)) or {}
     except Exception:
         return {}
 

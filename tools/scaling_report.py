@@ -143,8 +143,7 @@ def _child(n):
     x, y = step.put_batch(
         np.zeros((2 * n, 3, 32, 32), np.float32),
         np.zeros((2 * n,), np.int32))
-    hlo = step._step.lower(step.train_vals, step.opt_state, step.aux_vals,
-                           x, y, mxrandom.next_key()).compile().as_text()
+    hlo = step.program_for(x, y).as_text()
     out["dp"] = {
         "param_bytes_per_dev": _sharded_bytes(step.train_vals),
         "opt_bytes_per_dev": _sharded_bytes(
