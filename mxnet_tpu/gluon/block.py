@@ -42,7 +42,7 @@ from .parameter import (DeferredInitializationError, Parameter, ParameterDict,
                         param_override)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "is_staging",
-           "staged_call"]
+           "staged_call", "recomputed"]
 
 
 class _BlockScope:
@@ -414,6 +414,35 @@ def staged_call(block, override, seed, args, train=True):
             mode:
         out = block(*args)
     return out, scope
+
+
+def recomputed(fn, x):
+    """``fn(x)`` (NDArray -> NDArray) with its forward recomputed in the
+    backward pass (``jax.checkpoint``) while a step is being staged; plain
+    ``fn(x)`` otherwise.  Of the activations inside, only the flash
+    attention kernel's results are kept (``ops/attention.py`` names them:
+    a small part of what a block holds, and the kernel then runs once).
+    The auxiliary-state updates made inside leave the recomputed region as
+    results and are posted to the staging scope outside it."""
+    import jax
+
+    from ..ops.attention import FLASH_RESIDUALS
+
+    outer = _StagingScope.current()
+    if outer is None:
+        return fn(x)
+    keys = []
+
+    def pure(value):
+        with _StagingScope() as inner:
+            out = fn(NDArray(value))
+        keys[:] = list(inner.aux_updates)
+        return out._data, tuple(inner.aux_updates[k] for k in keys)
+
+    policy = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+    out, updates = jax.checkpoint(pure, policy=policy)(x._data)
+    outer.aux_updates.update(zip(keys, updates))
+    return NDArray(out)
 
 
 def update_aux_state(param, new_value):

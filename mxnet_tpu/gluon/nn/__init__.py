@@ -5,3 +5,4 @@ from .basic_layers import Activation  # noqa: F401
 from .conv_layers import *  # noqa: F401,F403
 from .activations import *  # noqa: F401,F403
 from .transformer import *  # noqa: F401,F403
+from .mla_moe import *  # noqa: F401,F403

@@ -1,0 +1,380 @@
+"""Decoder language model with latent attention and a mixture of experts
+(DeepSeek-V3, arXiv:2412.19437; the block of JoyAI-LLM-Flash and its
+kin): Gluon HybridBlocks over the operators of ``ops/llm.py`` and the
+Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
+
+- :class:`RMSNorm`, :class:`GatedFFN`: the norm and the gated-SiLU
+  feed-forward, no biases.
+- :class:`MLAttention`: multi-head latent attention in its training form
+  (no weight absorption): low-rank query and key/value projections with a
+  norm on each latent, rotary embedding on a part of each head that all
+  heads share on the key side, scores over ``nope + rope`` and values of
+  another head size.
+- :class:`RoutedExperts`: sigmoid-scored top-k routing with a selection
+  bias, the held experts' grouped feed-forward and one shared expert.  The
+  layer is told which experts it holds (``held_experts=(first, count)``):
+  the router keeps the model's width, selection and normalisation run over
+  all experts, and the layer computes its own experts' part of the sum:
+  one chip's share of an expert-parallel layer, without the exchange.
+- :class:`MLAMoEBlock`: ``h += MLA(norm(h)); h += FFN(norm(h))``, its
+  forward recomputed in the backward pass while a step is staged.
+- :class:`MLAMoELM`: embedding, blocks, final norm, and one multi-token
+  prediction module that shares embedding and head.  It returns the two
+  streams' normed hidden states; ``net.head`` makes logits of them, and
+  :class:`MultiTokenLoss` fuses the head with the loss of both terms over
+  token chunks.
+
+Named scopes (``xray.scope``): ``mla.proj``, ``mla.attention``,
+``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
+``moe.shared``, ``mtp``, ``lm_head``.  Counters: every :class:`RoutedExperts`
+keeps ``held_pairs`` (pairs routed to its held experts in the last step)
+and ``max_load`` (the largest held expert's pairs over their mean) as
+parameters that take no gradient, updated by the step like batch-norm
+statistics, so they leave the step program with its state.
+"""
+
+from __future__ import annotations
+
+from ... import initializer as _init
+from ... import xray as _xray
+from ..block import Block, HybridBlock, recomputed, update_aux_state
+from .basic_layers import Dense, Embedding
+
+__all__ = ["RMSNorm", "GatedFFN", "MLAttention", "RoutedExperts",
+           "MLAMoEBlock", "MLAMoELM", "MultiTokenLoss"]
+
+# at a quarter of the scores' spread (0.05) the bias alone made the busiest
+# expert 4-7 times the mean at random weights (PERF.md, PR 28)
+ROUTER_BIAS_STD = 0.01
+
+
+class RMSNorm(HybridBlock):
+    """``x * rsqrt(mean(x^2) + eps) * weight`` over the last axis."""
+
+    def __init__(self, units, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.weight = self.params.get("weight", shape=(units,),
+                                          init="ones")
+
+    def hybrid_forward(self, F, x, weight):
+        return F.contrib.rms_norm(x, weight, eps=self._epsilon)
+
+
+class GatedFFN(HybridBlock):
+    """``W_down(silu(W_gate x) * W_up x)``, no biases."""
+
+    def __init__(self, units, hidden_size, weight_std=0.02, **kwargs):
+        super().__init__(**kwargs)
+        init = _init.Normal(weight_std)
+        with self.name_scope():
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(hidden_size, units), init=init)
+            self.up_weight = self.params.get(
+                "up_weight", shape=(hidden_size, units), init=init)
+            self.down_weight = self.params.get(
+                "down_weight", shape=(units, hidden_size), init=init)
+
+    def hybrid_forward(self, F, x, gate_weight, up_weight, down_weight):
+        return F.contrib.gated_silu(x, gate_weight, up_weight, down_weight)
+
+
+class MLAttention(HybridBlock):
+    """Multi-head latent attention, causal, in its training form."""
+
+    def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, epsilon=1e-6, weight_std=0.02,
+                 **kwargs):
+        super().__init__(**kwargs)
+        self._heads = num_heads
+        self._nope, self._rope = qk_nope_head_dim, qk_rope_head_dim
+        self._v, self._rank = v_head_dim, kv_lora_rank
+        self._theta, self._epsilon = rope_theta, epsilon
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        init = _init.Normal(weight_std)
+        shapes = {
+            "qa_weight": (q_lora_rank, units),
+            "qb_weight": (num_heads * qk, q_lora_rank),
+            "kva_weight": (kv_lora_rank + qk_rope_head_dim, units),
+            "kvb_weight": (num_heads * (qk_nope_head_dim + v_head_dim),
+                           kv_lora_rank),
+            "o_weight": (units, num_heads * v_head_dim),
+        }
+        with self.name_scope():
+            for name, shape in shapes.items():
+                setattr(self, name, self.params.get(name, shape=shape,
+                                                    init=init))
+            self.qnorm_weight = self.params.get(
+                "qnorm_weight", shape=(q_lora_rank,), init="ones")
+            self.kvnorm_weight = self.params.get(
+                "kvnorm_weight", shape=(kv_lora_rank,), init="ones")
+
+    def hybrid_forward(self, F, x, qa_weight, qb_weight, kva_weight,
+                       kvb_weight, o_weight, qnorm_weight, kvnorm_weight):
+        heads, nope, rot, vd = self._heads, self._nope, self._rope, self._v
+
+        def dense(data, weight):
+            return F.FullyConnected(data, weight, no_bias=True,
+                                    flatten=False,
+                                    num_hidden=weight.shape[0])
+
+        def by_head(data, size):        # (B, S, heads * size) -> (B, h, S, .)
+            return F.transpose(F.reshape(data, shape=(0, 0, heads, size)),
+                               axes=(0, 2, 1, 3))
+
+        with _xray.scope("mla.proj"):
+            c_q = F.contrib.rms_norm(dense(x, qa_weight), qnorm_weight,
+                                     eps=self._epsilon)
+            q = by_head(dense(c_q, qb_weight), nope + rot)
+            kva = dense(x, kva_weight)
+            c_kv = F.contrib.rms_norm(
+                F.slice_axis(kva, axis=-1, begin=0, end=self._rank),
+                kvnorm_weight, eps=self._epsilon)
+            k_r = F.contrib.rope(
+                F.slice_axis(kva, axis=-1, begin=self._rank, end=None),
+                theta=self._theta)                          # (B, S, rot)
+            kv = by_head(dense(c_kv, kvb_weight), nope + vd)
+            q = F.concat(
+                F.slice_axis(q, axis=-1, begin=0, end=nope),
+                F.contrib.rope(F.slice_axis(q, axis=-1, begin=nope, end=None),
+                               theta=self._theta), dim=-1)
+            k = F.concat(
+                F.slice_axis(kv, axis=-1, begin=0, end=nope),
+                F.broadcast_axis(F.expand_dims(k_r, axis=1), axis=1,
+                                 size=heads), dim=-1)
+            v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+        with _xray.scope("mla.attention"):
+            o = F.contrib.flash_attention(q, k, v, causal=True,
+                                          sm_scale=(nope + rot) ** -0.5)
+        with _xray.scope("mla.proj"):
+            o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)),
+                          shape=(0, 0, heads * vd))
+            return dense(o, o_weight)
+
+
+class RoutedExperts(HybridBlock):
+    """Routed experts with one shared expert; see the module docstring.
+
+    ``num_experts``: the router's width (the model's experts);
+    ``held_experts`` ``(first, count)``: the consecutive expert ids held
+    here, all of them by default.  ``router_bias`` takes no gradient (the
+    training loop that balances the load owns it); it is drawn N(0,
+    ``ROUTER_BIAS_STD``), non-zero so that it takes part in the selection,
+    small against the scores' spread (~0.2) so that it does not decide
+    it."""
+
+    def __init__(self, units, hidden_size, num_experts, experts_per_token,
+                 held_experts=None, routed_scaling_factor=1.0,
+                 weight_std=0.02, **kwargs):
+        super().__init__(**kwargs)
+        first, held = held_experts or (0, num_experts)
+        if first < 0 or held < 1 or first + held > num_experts:
+            raise ValueError("held_experts %r do not lie in 0..%d"
+                             % ((first, held), num_experts))
+        self._first, self._held = int(first), int(held)
+        self._k, self._scale = experts_per_token, routed_scaling_factor
+        init = _init.Normal(weight_std)
+        with self.name_scope():
+            self.router_weight = self.params.get(
+                "router_weight", shape=(num_experts, units), init=init)
+            self.router_bias = self.params.get(
+                "router_bias", shape=(num_experts,), grad_req="null",
+                init=_init.Normal(ROUTER_BIAS_STD))
+            self.experts_gate_weight = self.params.get(
+                "experts_gate_weight", shape=(held, units, hidden_size),
+                init=init)
+            self.experts_up_weight = self.params.get(
+                "experts_up_weight", shape=(held, units, hidden_size),
+                init=init)
+            self.experts_down_weight = self.params.get(
+                "experts_down_weight", shape=(held, hidden_size, units),
+                init=init)
+            self.held_pairs = self.params.get(
+                "held_pairs", shape=(1,), grad_req="null", init="zeros")
+            self.max_load = self.params.get(
+                "max_load", shape=(1,), grad_req="null", init="zeros")
+            self.shared = GatedFFN(units, hidden_size, weight_std=weight_std,
+                                   prefix="shared_")
+
+    def hybrid_forward(self, F, x, router_weight, router_bias,
+                       experts_gate_weight, experts_up_weight,
+                       experts_down_weight, held_pairs, max_load):
+        from ... import autograd
+
+        rows = F.reshape(x, shape=(-1, x.shape[-1]))
+        ids, weights = F.contrib.moe_route(
+            rows, router_weight, router_bias, k=self._k, scale=self._scale)
+        y, pairs, load = F.contrib.moe_experts(
+            rows, ids, weights, experts_gate_weight, experts_up_weight,
+            experts_down_weight, first_expert=self._first)
+        if autograd.is_training():
+            update_aux_state(self.held_pairs, F.reshape(pairs, shape=(1,)))
+            update_aux_state(self.max_load, F.reshape(load, shape=(1,)))
+        with _xray.scope("moe.shared"):
+            y = y + self.shared(rows)
+        return F.reshape(y, shape=x.shape)
+
+
+class MLAMoEBlock(HybridBlock):
+    """One decoder block: ``h += MLA(norm(h)); h += FFN(norm(h))``, the
+    feed-forward dense (``dense_size``) or routed (``moe``: keyword
+    arguments of :class:`RoutedExperts`).  While a step is staged only the
+    block's input and its attention kernel's results are kept for the
+    backward pass, which runs the rest of the forward again
+    (``gluon.block.recomputed``): a block's activations are thirty times
+    its input, and a model of this kind fills a chip with its state."""
+
+    def __init__(self, units, attention, dense_size=None, moe=None,
+                 epsilon=1e-6, weight_std=0.02, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.ln1 = RMSNorm(units, epsilon, prefix="ln1_")
+            self.attn = MLAttention(units, epsilon=epsilon,
+                                    weight_std=weight_std, prefix="attn_",
+                                    **attention)
+            self.ln2 = RMSNorm(units, epsilon, prefix="ln2_")
+            if moe is None:
+                self.ffn = GatedFFN(units, dense_size, weight_std=weight_std,
+                                    prefix="ffn_")
+            else:
+                self.ffn = RoutedExperts(units, weight_std=weight_std,
+                                         prefix="moe_", **moe)
+
+    def _body(self, h):
+        h = h + self.attn(self.ln1(h))
+        return h + self.ffn(self.ln2(h))
+
+    def hybrid_forward(self, F, h):
+        return recomputed(self._body, h)
+
+
+class MLAMoELM(HybridBlock):
+    """Decoder-only language model of :class:`MLAMoEBlock`s with one
+    multi-token prediction module (DeepSeek-V3 section 2.2).
+
+    Input ``(batch, seq)`` integer token ids.  Result: the normed hidden
+    states ``(main, mtp)``, each ``(batch, seq, units)``: ``head(main)[i]``
+    are the logits for token ``i + 1``, ``head(mtp)[i]`` for token ``i +
+    2``.  The MTP module: ``u_i = W_eh [norm(embed(t_{i+1})); norm(h_i)]``
+    (embedding first, as the released weights have it), one routed block,
+    its own final norm; embedding and head are the main model's.  It runs
+    on all positions; its last input wraps to the row's first token, which
+    causal attention keeps from every other position and the loss leaves
+    out.
+
+    Weights are drawn N(0, ``weight_std``), the projections that write to
+    the residual stream (attention's ``o_weight``, every ``down_weight``)
+    N(0, ``weight_std / sqrt(2 x blocks)``) as in GPT-2 and Megatron-LM:
+    unscaled, attention's running mean of the values dominates the stream
+    and the tokens of a row route alike.  The keyword arguments carry the
+    names of the model's ``config.json``.
+    ``held_experts`` ``(first, count)`` gives this chip's share of every
+    routed layer."""
+
+    def __init__(self, vocab_size, hidden_size, num_hidden_layers,
+                 first_k_dense_replace, intermediate_size,
+                 moe_intermediate_size, router_outputs, num_experts_per_tok,
+                 num_attention_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 held_experts=None, routed_scaling_factor=1.0,
+                 rope_theta=10000.0, rms_norm_eps=1e-6, weight_std=0.02,
+                 **kwargs):
+        super().__init__(**kwargs)
+        attention = dict(
+            num_heads=num_attention_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta)
+        moe = dict(
+            hidden_size=moe_intermediate_size, num_experts=router_outputs,
+            experts_per_token=num_experts_per_tok,
+            held_experts=held_experts and tuple(held_experts),
+            routed_scaling_factor=routed_scaling_factor)
+        block = dict(units=hidden_size, attention=attention,
+                     epsilon=rms_norm_eps, weight_std=weight_std)
+        init = _init.Normal(weight_std)
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, hidden_size, prefix="embed_",
+                                   weight_initializer=init)
+            self.blocks = []
+            for i in range(num_hidden_layers):
+                dense = i < first_k_dense_replace
+                blk = MLAMoEBlock(
+                    dense_size=intermediate_size if dense else None,
+                    moe=None if dense else moe, prefix="l%d_" % i, **block)
+                self.register_child(blk, "l%d" % i)
+                self.blocks.append(blk)
+            self.norm = RMSNorm(hidden_size, rms_norm_eps, prefix="norm_")
+            self.head = Dense(vocab_size, use_bias=False, flatten=False,
+                              in_units=hidden_size, prefix="head_",
+                              weight_initializer=init)
+            self.mtp_enorm = RMSNorm(hidden_size, rms_norm_eps,
+                                     prefix="mtp_enorm_")
+            self.mtp_hnorm = RMSNorm(hidden_size, rms_norm_eps,
+                                     prefix="mtp_hnorm_")
+            self.mtp_proj = Dense(hidden_size, use_bias=False, flatten=False,
+                                  in_units=2 * hidden_size,
+                                  prefix="mtp_proj_",
+                                  weight_initializer=init)
+            self.mtp_blk = MLAMoEBlock(moe=moe, prefix="mtp_blk_", **block)
+            self.mtp_norm = RMSNorm(hidden_size, rms_norm_eps,
+                                    prefix="mtp_norm_")
+        blocks = num_hidden_layers + 1          # the MTP module's too
+        to_the_stream = _init.Normal(weight_std / (2 * blocks) ** 0.5)
+        for name, param in self.collect_params().items():
+            if name.endswith(("o_weight", "down_weight")):
+                param.init = to_the_stream
+
+    def hybrid_forward(self, F, tokens):
+        h = self.embed(tokens)
+        for blk in self.blocks:
+            h = blk(h)
+        with _xray.scope("mtp"):
+            shifted = F.concat(
+                F.slice_axis(tokens, axis=1, begin=1, end=None),
+                F.slice_axis(tokens, axis=1, begin=0, end=1), dim=1)
+            u = self.mtp_proj(F.concat(self.mtp_enorm(self.embed(shifted)),
+                                       self.mtp_hnorm(h), dim=-1))
+            u = self.mtp_norm(self.mtp_blk(u))
+        return self.norm(h), u
+
+
+class MultiTokenLoss(Block):
+    """``CE(head(main_i), t_{i+1}) + weight * CE(head(mtp_i), t_{i+2})``,
+    each a mean over its valid positions of a row; one value per row.
+
+    ``head``: the model's output projection (``MLAMoELM.head``), applied
+    here, fused with the loss over chunks of tokens, so that the float32
+    logits of neither head stand whole
+    (``ops/llm.py::linear_cross_entropy``).  The labels are the input's
+    own rows of token ids."""
+
+    def __init__(self, head, weight=0.3, **kwargs):
+        super().__init__(**kwargs)
+        self.__dict__["_head"] = head       # the model's block, not a child
+        self._weight = weight
+
+    def forward(self, streams, tokens):
+        from ... import ndarray as F
+
+        main, mtp = streams
+        batch, seq = tokens.shape
+        weight = self._head.weight.data()
+        total = None
+        with _xray.scope("lm_head"):
+            for hidden, ahead, scale in ((main, 1, 1.0),
+                                         (mtp, 2, self._weight)):
+                # token i + ahead labels position i; the row's last
+                # ``ahead`` positions have no label
+                labels = F.concat(
+                    F.slice_axis(tokens, axis=1, begin=ahead, end=None),
+                    F.full((batch, ahead), -1, dtype=tokens.dtype), dim=1)
+                rows = F.contrib.linear_cross_entropy(
+                    F.reshape(hidden, shape=(-1, hidden.shape[-1])), weight,
+                    F.reshape(labels, shape=(-1,)))
+                term = F.sum(F.reshape(rows, shape=(batch, seq)), axis=1) \
+                    * (scale / (seq - ahead))
+                total = term if total is None else total + term
+        return total

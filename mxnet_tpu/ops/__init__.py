@@ -17,6 +17,7 @@ from . import optimizer_ops  # noqa: F401
 from . import rnn  # noqa: F401
 from . import contrib  # noqa: F401
 from . import attention  # noqa: F401
+from . import llm  # noqa: F401
 from . import custom  # noqa: F401
 from . import quantization  # noqa: F401
 from . import linalg  # noqa: F401

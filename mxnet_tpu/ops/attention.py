@@ -123,11 +123,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     bh = b * h
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
+    vr = v.reshape(bh, sk, dv)
     num_q = sq // block_q
     num_k = sk // block_k
 
@@ -140,24 +140,24 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda z, i, j: (z, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
+    return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
 # ---------------------------------------------------------------------------
@@ -261,10 +261,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
                 block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     bh = b * h
-    qr, kr, vr = (x.reshape(bh, -1, d) for x in (q, k, v))
-    dor = do.reshape(bh, sq, d)
+    qr, kr = (x.reshape(bh, -1, d) for x in (q, k))
+    vr = v.reshape(bh, sk, dv)
+    dor = do.reshape(bh, sq, dv)
     lser = lse.reshape(bh, sq, 1)
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -279,8 +280,8 @@ def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda z, i, j: (z, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
         ],
@@ -297,22 +298,22 @@ def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, j, i: (z, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda z, j, i: (z, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda z, j, i: (z, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda z, j, i: (z, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda z, j, i: (z, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda z, j, i: (z, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda z, j, i: (z, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, delta)
@@ -337,9 +338,19 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return out
 
 
+# names under which a block that recomputes its forward in the backward
+# pass (``jax.checkpoint``) may keep the forward kernel's results, so that
+# the kernel runs once: ``save_only_these_names(*FLASH_RESIDUALS)``
+FLASH_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
+
+
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+
     out, lse = _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k,
                            interpret)
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
@@ -355,7 +366,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     interpret=False):
-    """Fused attention over (batch, heads, seq, head_dim) arrays.
+    """Fused attention over (batch, heads, seq, head_dim) arrays.  ``v``
+    (and so the output) may have another head size than ``q`` and ``k``
+    (latent attention trains with 192 for the scores and 128 for the
+    values); ``sm_scale`` defaults to the scores' head size.
 
     On an accelerator this is always the Pallas flash kernel: a sequence
     the blocks cannot tile raises instead of materialising seq x seq

@@ -1,0 +1,321 @@
+"""Operators of current decoder language models (docs/LLM_OPS.md).
+
+RMS norm, rotary position embedding, the gated-SiLU feed-forward, the
+router and the held-experts layer of a sigmoid-scored mixture of experts
+(DeepSeek-V3, arXiv:2412.19437), and a linear head fused with its
+cross-entropy over token chunks.  The reference framework has none of
+them (its transformer helpers are ``src/operator/contrib/transformer.cc``).
+
+The expert layer is told which experts it holds: the router scores all
+of the model's experts, selection and normalisation run over all of them,
+and the layer computes the part of the routed sum that its own experts
+give (what expert parallelism asks of one chip; on one chip without the
+exchange).  No pair routed to a held expert is dropped at any imbalance,
+and the work follows the rows actually routed: the pairs are sorted by
+expert into row tiles, and a loop whose trip count is the number of tiles
+in use gathers a tile's rows, runs its expert and scatters the result.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as _np
+from jax import lax
+
+from .. import xray as _xray
+from .registry import OP_INPUT_NAMES, register
+
+__all__ = ["rms_norm", "rope", "gated_silu", "moe_route", "moe_experts",
+           "linear_cross_entropy", "expert_tiles"]
+
+# rows of one expert tile.  A tile costs its expert's three weights read
+# (twice in the backward pass) and their three float32 gradients read and
+# written, whatever its rows: at 256 rows that fixed part was two thirds of
+# a tile's time on a v5e, and the step time followed the routed pairs at
+# 0.72 us a pair (PERF.md, PR 28); 512 halves it.
+DEFAULT_EXPERT_TILE = 512
+DEFAULT_LOSS_CHUNK = 1024
+
+
+@register("_contrib_rms_norm", aliases=("rms_norm",))
+def rms_norm(data, gamma, eps=1e-6, **_):
+    """Root-mean-square normalisation over the last axis with a learned
+    scale: ``x * rsqrt(mean(x^2) + eps) * gamma`` (Zhang & Sennrich 2019,
+    arXiv:1910.07467); statistics in float32 whatever the input's type."""
+    x = data.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * scale * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("_contrib_rope", aliases=("rope",))
+def rope(data, theta=10000.0, **_):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) over
+    ``(..., seq, dim)``: position ``p`` rotates the adjacent pair ``(2i,
+    2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``).  The angles
+    are constants computed in float64 when the op is traced."""
+    seq, dim = data.shape[-2], data.shape[-1]
+    # a host-side table: float32 angles at position 4096 and theta 3.2e7
+    # are wrong in the fourth digit
+    f64 = _np.float64  # mxlint: disable=dtype-default -- host table, cast below
+    inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
+    angle = _np.arange(seq, dtype=f64)[:, None] * inv[None, :]
+    cos = jnp.asarray(_np.cos(angle), jnp.float32)
+    sin = jnp.asarray(_np.sin(angle), jnp.float32)
+    x = data.astype(jnp.float32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(data.shape).astype(data.dtype)
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+@register("_contrib_gated_silu", aliases=("gated_silu",))
+def gated_silu(data, gate_weight, up_weight, down_weight, **_):
+    """Gated-SiLU feed-forward ``W_down(silu(W_gate x) * W_up x)`` (Shazeer
+    2020, arXiv:2002.05202), no biases; weights ``(out, in)`` like
+    ``FullyConnected``'s, float32 accumulation."""
+    last = (data.ndim - 1,)
+    g = _dot(data, gate_weight, (last, (1,)))
+    u = _dot(data, up_weight, (last, (1,)))
+    h = (jax.nn.silu(g) * u).astype(data.dtype)
+    return _dot(h, down_weight, (last, (1,))).astype(data.dtype)
+
+
+@register("_contrib_moe_route", num_outputs=2, aliases=("moe_route",))
+def moe_route(data, router_weight, router_bias, k=8, scale=1.0, **_):
+    """Sigmoid-scored top-``k`` routing with a selection-only bias
+    (``noaux_tc`` with one group): ``s = sigmoid(x W_g)`` in float32; the
+    ``k`` largest of ``s + bias`` are selected; a selected expert weighs
+    ``s / (sum of the selected s + 1e-20) * scale``: the bias selects, it
+    does not weigh.  -> (expert ids ``(..., k)`` int32, weights ``(..., k)``
+    float32).  ``router_weight``: ``(experts, in)``."""
+    with _xray.scope("moe.route"):
+        s = jax.nn.sigmoid(_dot(data.astype(jnp.float32),
+                                router_weight.astype(jnp.float32),
+                                ((data.ndim - 1,), (1,))))
+        _, ids = lax.top_k(s + lax.stop_gradient(
+            router_bias.astype(jnp.float32)), int(k))
+        picked = jnp.take_along_axis(s, ids, axis=-1)
+        weights = picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                            + 1e-20) * scale
+        return ids.astype(jnp.int32), weights
+
+
+# ------------------------------------------------------ the held experts
+
+
+def expert_tiles(ids, first, held, tile):
+    """Sort the (token, choice) pairs that chose a held expert into row
+    tiles, each tile one expert's: every held expert's rows start at a
+    multiple of ``tile``.  ``ids``: (tokens, k) expert ids over all of the
+    model's experts; held are ``first .. first + held - 1``.
+
+    -> (``row_pair`` (capacity,) int32: the flat pair index of every row,
+    ``tokens * k`` where the row is padding; ``tile_expert`` (capacity /
+    tile,) int32; ``n_tiles`` int32 scalar: the tiles in use; ``counts``
+    (held,) int32: pairs per held expert).  The capacity covers every
+    pair on held experts (nothing is dropped); only index arrays have
+    that size."""
+    pairs = ids.size
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    padded = (counts + tile - 1) // tile * tile
+    ends = jnp.cumsum(padded)
+    start_sorted = jnp.cumsum(counts) - counts
+    start_tiled = ends - padded
+    n_slots = -(-(pairs + held * (tile - 1)) // tile)
+    capacity = n_slots * tile
+    sorted_key = key[order]
+    clipped = jnp.minimum(sorted_key, held - 1)
+    dest = jnp.where(
+        sorted_key < held,
+        start_tiled[clipped] + jnp.arange(pairs) - start_sorted[clipped],
+        capacity)
+    row_pair = jnp.full((capacity,), pairs, jnp.int32).at[dest].set(
+        order, mode="drop")
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(n_slots) * tile, side="right"), held - 1)
+    return (row_pair, tile_expert.astype(jnp.int32),
+            (ends[-1] // tile).astype(jnp.int32), counts)
+
+
+def _tile_rows(t, row_pair, weights_flat, k, tile):
+    """Tokens and routing weights of tile ``t``'s rows (padding: token 0,
+    weight 0) and the rows' pair indices."""
+    with _xray.scope("moe.dispatch"):
+        pairs = lax.dynamic_slice(row_pair, (t * tile,), (tile,))
+        n = weights_flat.shape[0]
+        real = pairs < n
+        safe = jnp.where(real, pairs, 0)
+        gate = jnp.where(real, jnp.take(weights_flat, safe), 0.0)
+        return safe // k, gate, pairs
+
+
+def _expert_forward(xt, wg, wu, wd):
+    g = _dot(xt, wg, ((1,), (0,)))
+    u = _dot(xt, wu, ((1,), (0,)))
+    h = (jax.nn.silu(g) * u).astype(xt.dtype)
+    return g, u, h, _dot(h, wd, ((1,), (0,)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _routed_sum(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles, k,
+                tile):
+    return _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert,
+                           n_tiles, k, tile)[0]
+
+
+def _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles,
+                    k, tile):
+    flat = weights.reshape(-1)
+
+    def body(carry):
+        t, out = carry
+        tok, gate, _ = _tile_rows(t, row_pair, flat, k, tile)
+        e = tile_expert[t]
+        with _xray.scope("moe.dispatch"):
+            xt = jnp.take(x, tok, axis=0)
+        with _xray.scope("moe.experts"):
+            yt = _expert_forward(xt, wg[e], wu[e], wd[e])[3]
+        with _xray.scope("moe.combine"):
+            out = out.at[tok].add(gate[:, None] * yt)
+        return t + 1, out
+
+    _, out = lax.while_loop(lambda c: c[0] < n_tiles, body,
+                            (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    return out.astype(x.dtype), (x, weights, wg, wu, wd, row_pair,
+                                 tile_expert, n_tiles)
+
+
+def _routed_sum_bwd(k, tile, res, dy):
+    """The backward pass tile by tile, the tile's forward recomputed:
+    nothing but the layer's input is kept between the passes."""
+    x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles = res
+    flat = weights.reshape(-1)
+    f32 = jnp.float32
+
+    def body(carry):
+        t, dx, dflat, dwg, dwu, dwd = carry
+        tok, gate, pairs = _tile_rows(t, row_pair, flat, k, tile)
+        e = tile_expert[t]
+        with _xray.scope("moe.dispatch"):
+            xt = jnp.take(x, tok, axis=0)
+            dyt = jnp.take(dy, tok, axis=0)
+        with _xray.scope("moe.experts"):
+            g, u, h, yt = _expert_forward(xt, wg[e], wu[e], wd[e])
+            dgate = jnp.sum(dyt.astype(f32) * yt, axis=-1)
+            dyt = (gate[:, None] * dyt.astype(f32)).astype(x.dtype)
+            dh = _dot(dyt, wd[e], ((1,), (1,)))
+            sig = jax.nn.sigmoid(g)
+            dg = (dh * u * sig * (1 + g * (1 - sig))).astype(x.dtype)
+            du = (dh * g * sig).astype(x.dtype)
+            dxt = _dot(dg, wg[e], ((1,), (1,))) + _dot(du, wu[e],
+                                                       ((1,), (1,)))
+            dwg = dwg.at[e].add(_dot(xt, dg, ((0,), (0,))))
+            dwu = dwu.at[e].add(_dot(xt, du, ((0,), (0,))))
+            dwd = dwd.at[e].add(_dot(h, dyt, ((0,), (0,))))
+        with _xray.scope("moe.combine"):
+            dx = dx.at[tok].add(dxt)
+            dflat = dflat.at[pairs].add(dgate, mode="drop")
+        return t + 1, dx, dflat, dwg, dwu, dwd
+
+    init = (jnp.int32(0), jnp.zeros(x.shape, f32), jnp.zeros(flat.shape, f32),
+            jnp.zeros(wg.shape, f32), jnp.zeros(wu.shape, f32),
+            jnp.zeros(wd.shape, f32))
+    _, dx, dflat, dwg, dwu, dwd = lax.while_loop(
+        lambda c: c[0] < n_tiles, body, init)
+
+    def no_gradient(a):
+        return _np.zeros(a.shape, dtype=jax.dtypes.float0)
+
+    return (dx.astype(x.dtype), dflat.reshape(weights.shape).astype(
+        weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+        dwd.astype(wd.dtype), no_gradient(row_pair),
+        no_gradient(tile_expert), no_gradient(n_tiles))
+
+
+_routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
+
+
+@register("_contrib_moe_experts", num_outputs=3, aliases=("moe_experts",))
+def moe_experts(data, expert_ids, expert_weights, gate_weight, up_weight,
+                down_weight, first_expert=0, tile=DEFAULT_EXPERT_TILE, **_):
+    """The held experts' part of a routed sum: ``y[n] = sum over the
+    choices j of token n whose expert is held of weights[n, j] *
+    E_ids[n, j](x[n])``, every expert a gated-SiLU feed-forward.
+
+    ``data`` (tokens, in); ``expert_ids`` / ``expert_weights`` (tokens, k)
+    over all of the model's experts (``moe_route``'s results); the held
+    experts' weights stacked ``(held, in, width)``, ``(held, in, width)``,
+    ``(held, width, in)`` for expert ids ``first_expert ..``.  Choices of
+    absent experts add nothing.  No pair is dropped at any imbalance; the
+    loop runs over the row tiles in use (``expert_tiles``), so the work
+    follows the pairs actually routed here.  -> (y, pairs routed to held
+    experts, largest held expert's pairs over their mean), the two
+    counters float32 scalars."""
+    held = gate_weight.shape[0]
+    k = expert_ids.shape[-1]
+    expert_ids = expert_ids.astype(jnp.int32)   # ids may arrive as floats
+    with _xray.scope("moe.dispatch"):
+        row_pair, tile_expert, n_tiles, counts = expert_tiles(
+            expert_ids, int(first_expert), held, int(tile))
+        routed = jnp.sum(counts).astype(jnp.float32)
+        load = jnp.max(counts).astype(jnp.float32) * held \
+            / jnp.maximum(routed, 1.0)
+    y = _routed_sum(data, expert_weights, gate_weight, up_weight,
+                    down_weight, row_pair, tile_expert, n_tiles, int(k),
+                    int(tile))
+    return y, routed, load
+
+
+# ------------------------------------------------- head fused with its loss
+
+
+@register("_contrib_linear_cross_entropy",
+          aliases=("linear_cross_entropy",))
+def linear_cross_entropy(data, weight, label, chunk=DEFAULT_LOSS_CHUNK, **_):
+    """``-log softmax(data @ weight.T)[label]`` per row, the head's product
+    fused with its loss over chunks of rows so that the float32 logits
+    never stand whole: a chunk's logits are recomputed in the backward
+    pass.  ``data`` (rows, in), ``weight`` (classes, in), ``label`` (rows,)
+    integer; a negative label gives 0 (the row is left out).  Fewer rows
+    than ``chunk`` take one chunk; otherwise the chunk is the largest
+    common divisor of the two.  -> (rows,) float32."""
+    rows = data.shape[0]
+    chunk = rows if rows <= int(chunk) else math.gcd(rows, int(chunk))
+    label = label.astype(jnp.int32)
+
+    @jax.checkpoint
+    def one(args):
+        h, y = args
+        logits = _dot(h, weight, ((1,), (1,)))
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(y, 0)[:, None], axis=-1)[:, 0]
+        return jnp.where(y >= 0, logz - picked, 0.0)
+
+    out = lax.map(one, (data.reshape(rows // chunk, chunk, -1),
+                        label.reshape(rows // chunk, chunk)))
+    return out.reshape(rows)
+
+
+OP_INPUT_NAMES.update({
+    "_contrib_rms_norm": ("data", "gamma"),
+    "_contrib_rope": ("data",),
+    "_contrib_gated_silu": ("data", "gate_weight", "up_weight",
+                            "down_weight"),
+    "_contrib_moe_route": ("data", "router_weight", "router_bias"),
+    "_contrib_moe_experts": ("data", "expert_ids", "expert_weights",
+                             "gate_weight", "up_weight", "down_weight"),
+    "_contrib_linear_cross_entropy": ("data", "weight", "label"),
+})

@@ -243,6 +243,14 @@ def _put(vals, shard):
     return tuple(jax.device_put(v, shard) for v in vals)
 
 
+def _cast_floating(x, dtype):
+    """The batch in the compute dtype; token ids and other integer inputs
+    stay what they are."""
+    import jax.numpy as jnp
+
+    return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+
 def _is_models(order):
     return order == tuple(range(len(order)))
 
@@ -476,7 +484,7 @@ class GluonTrainStep:
                 if cast is not None:
                     tv = tuple(v.astype(cast) if v.dtype == _np.float32
                                else v for v in tv)
-                    x_ = x.astype(cast)
+                    x_ = _cast_floating(x, cast)
                 else:
                     x_ = x
                 return pure_loss(tv, aux_vals, x_, y, key)
@@ -511,12 +519,16 @@ class GluonTrainStep:
             sig_in = (tv_shard, state_shard, aux_shard, x_shard, y_shard,
                       repl, repl)
 
-        self.train_vals = _put(self.train_vals, tv_shard)
-        self.opt_state = _put(self.opt_state, state_shard)
-        self.aux_vals = _put(self.aux_vals, aux_shard)
-
         def per_leaf(shard, vals):
             return shard if isinstance(shard, tuple) else (shard,) * len(vals)
+
+        self.train_vals = _put(self.train_vals, tv_shard)
+        # fresh and the step's own, so placed without _put's copy: Adam's
+        # state of a model that fills the chip does not fit there twice
+        self.opt_state = tuple(map(
+            jax.device_put, self.opt_state,
+            per_leaf(state_shard, self.opt_state)))
+        self.aux_vals = _put(self.aux_vals, aux_shard)
 
         self._state_shard = tuple(
             per_leaf(shard, vals) for shard, vals in zip(
@@ -738,7 +750,7 @@ class GluonTrainStep:
                 if cast is not None:
                     tv = tuple(v.astype(cast) if v.dtype == _np.float32
                                else v for v in tv)
-                    x_ = x.astype(cast)
+                    x_ = _cast_floating(x, cast)
                 else:
                     x_ = x
                 return pure_loss(tv, aux_vals, x_, y, key)

@@ -20,6 +20,9 @@ _CONTRIB_OPS = [
     "adamw_update", "_contrib_flash_attention", "_contrib_div_sqrt_dim",
     "_contrib_interleaved_matmul_selfatt_qk",
     "_contrib_interleaved_matmul_selfatt_valatt",
+    "_contrib_rms_norm", "_contrib_rope", "_contrib_gated_silu",
+    "_contrib_moe_route", "_contrib_moe_experts",
+    "_contrib_linear_cross_entropy",
 ]
 
 _populate(globals(), names=[n for n in _CONTRIB_OPS if n in _reg.list_ops()])

@@ -43,6 +43,7 @@ DTYPE_GAPS = {
                                           "accumulator out",
     "_contrib_quantized_pooling": "uint8 in, uint8 out",
     "_contrib_quantized_flatten": "uint8 in, uint8 out",
+    "_contrib_moe_route": "expert ids are int32 whatever the scores' type",
 }
 
 # shape-side gaps: none today — every canonical-spec op's infer_shape

@@ -1,0 +1,327 @@
+"""The operators of ``ops/llm.py`` against numpy, with gradients, and the
+flash-attention kernels with a value head size that differs from the
+scores' (interpret mode).  Docs: docs/LLM_OPS.md."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import llm
+from mxnet_tpu.ops.attention import flash_attention, mha_reference
+
+
+def _rs(seed=0):
+    return np.random.RandomState(seed)
+
+
+def _f(a):
+    return jnp.asarray(np.asarray(a, np.float32))
+
+
+# ------------------------------------------------- numpy forms of each op
+
+
+def np_rms_norm(x, gamma, eps=1e-6):
+    return x / np.sqrt((x * x).mean(-1, keepdims=True) + eps) * gamma
+
+
+def np_rope(x, theta):
+    seq, dim = x.shape[-2:]
+    inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    angle = np.arange(seq)[:, None] * inv[None, :]
+    cos, sin = np.cos(angle), np.sin(angle)
+    out = np.empty_like(x, dtype=np.float64)
+    a, b = x[..., 0::2], x[..., 1::2]
+    out[..., 0::2], out[..., 1::2] = a * cos - b * sin, a * sin + b * cos
+    return out
+
+
+def np_silu(x):
+    return x / (1 + np.exp(-x))
+
+
+def np_gated_silu(x, gate, up, down):
+    return (np_silu(x @ gate.T) * (x @ up.T)) @ down.T
+
+
+def np_route(x, w, b, k, scale):
+    s = 1 / (1 + np.exp(-(x @ w.T)))
+    ids = np.argsort(-(s + b), axis=-1, kind="stable")[:, :k]
+    picked = np.take_along_axis(s, ids, -1)
+    return ids, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+def np_experts(x, ids, weights, wg, wu, wd, first):
+    y = np.zeros_like(x)
+    for j in range(wg.shape[0]):        # a dense mask, expert by expert
+        w = np.where(ids == first + j, weights, 0).sum(-1)
+        y += w[:, None] * ((np_silu(x @ wg[j]) * (x @ wu[j])) @ wd[j])
+    return y
+
+
+def np_linear_ce(h, w, label):
+    logits = h @ w.T
+    logz = np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) \
+        + logits.max(-1)
+    picked = np.take_along_axis(logits, np.maximum(label, 0)[:, None], 1)[:, 0]
+    return np.where(label >= 0, logz - picked, 0.0)
+
+
+def _moe_inputs(tokens=37, hidden=8, width=6, experts=12, held=4):
+    rs = _rs(1)
+    return dict(
+        x=rs.randn(tokens, hidden), router=rs.randn(experts, hidden),
+        bias=rs.randn(experts) * 0.1,
+        wg=rs.randn(held, hidden, width), wu=rs.randn(held, hidden, width),
+        wd=rs.randn(held, width, hidden))
+
+
+CASES = {}
+
+
+def case(fn):
+    CASES[fn.__name__] = fn
+    return fn
+
+
+@case
+def rms_norm():
+    rs = _rs()
+    args = (rs.randn(3, 5, 8), rs.rand(8) + 0.5)
+    return (lambda x, g: llm.rms_norm(x, g, eps=1e-6),
+            lambda x, g: np_rms_norm(x, g), args, (0, 1))
+
+
+@case
+def rope_four_axes():
+    x = _rs().randn(2, 3, 7, 8)
+    return (lambda x: llm.rope(x, theta=32e6),
+            lambda x: np_rope(x, 32e6), (x,), (0,))
+
+
+@case
+def rope_three_axes():
+    x = _rs().randn(2, 7, 8)
+    return (lambda x: llm.rope(x, theta=1e4),
+            lambda x: np_rope(x, 1e4), (x,), (0,))
+
+
+@case
+def gated_silu():
+    rs = _rs()
+    args = (rs.randn(2, 5, 8), rs.randn(12, 8), rs.randn(12, 8),
+            rs.randn(8, 12))
+    return llm.gated_silu, np_gated_silu, args, (0, 1, 2, 3)
+
+
+@case
+def moe_route_weights():
+    m = _moe_inputs()
+    return (lambda x, w: llm.moe_route(x, w, _f(m["bias"]), k=3,
+                                       scale=2.5)[1],
+            lambda x, w: np_route(x, w, m["bias"], 3, 2.5)[1],
+            (m["x"], m["router"]), (0, 1))
+
+
+@case
+def moe_experts():
+    m = _moe_inputs()
+    ids, weights = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
+
+    def op(x, weights, wg, wu, wd):
+        return llm.moe_experts(x, jnp.asarray(ids, jnp.int32), weights, wg,
+                               wu, wd, first_expert=4, tile=4)[0]
+
+    return (op, lambda x, w, wg, wu, wd: np_experts(x, ids, w, wg, wu, wd, 4),
+            (m["x"], weights, m["wg"], m["wu"], m["wd"]), (0, 1, 2, 3, 4))
+
+
+@case
+def linear_cross_entropy():
+    rs = _rs()
+    label = rs.randint(-1, 11, (16,))
+    return (lambda h, w: llm.linear_cross_entropy(h, w, jnp.asarray(label),
+                                                  chunk=4),
+            lambda h, w: np_linear_ce(h, w, label),
+            (rs.randn(16, 8), rs.randn(11, 8)), (0, 1))
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_op_against_numpy_with_gradients(name):
+    """Forward against the numpy form in float64; the gradient of a fixed
+    random projection of the output against central differences of the
+    numpy form."""
+    op, numpy_form, args, wrt = CASES[name]()
+    got = np.asarray(op(*[_f(a) for a in args]))
+    want = numpy_form(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+    proj = _rs(9).randn(*want.shape)
+    grads = jax.grad(lambda *a: jnp.sum(op(*a) * _f(proj)), argnums=wrt)(
+        *[_f(a) for a in args])
+    rs = _rs(5)
+    for i, g in zip(wrt, grads):
+        direction = rs.randn(*args[i].shape)
+        h = 1e-5
+        plus = [a + h * direction if j == i else a
+                for j, a in enumerate(args)]
+        minus = [a - h * direction if j == i else a
+                 for j, a in enumerate(args)]
+        numeric = ((numpy_form(*plus) - numpy_form(*minus)) * proj).sum() \
+            / (2 * h)
+        assert np.sum(np.asarray(g) * direction) == pytest.approx(
+            numeric, rel=2e-3, abs=2e-4), (name, i)
+
+
+def test_the_ops_are_in_the_contrib_namespaces():
+    x = mx.nd.array(_rs().randn(2, 5, 8).astype(np.float32))
+    out = mx.nd.contrib.rms_norm(x, mx.nd.ones((8,)))
+    np.testing.assert_allclose(
+        out.asnumpy(), np_rms_norm(x.asnumpy(), 1.0), rtol=1e-5)
+    for name in ("rope", "gated_silu", "moe_route", "moe_experts",
+                 "linear_cross_entropy"):
+        assert hasattr(mx.nd.contrib, name) and hasattr(mx.sym.contrib, name)
+
+
+# ------------------------------------------------------- the expert layer
+
+
+def _layer(m, first, held, ids, weights, tile=4):
+    sl = slice(first, first + held)
+    return np.asarray(llm.moe_experts(
+        _f(m["x"]), jnp.asarray(ids, jnp.int32), _f(weights),
+        _f(m["wg"][sl]), _f(m["wu"][sl]), _f(m["wd"][sl]),
+        first_expert=first, tile=tile)[0])
+
+
+def test_the_shares_of_a_layer_sum_to_the_uncut_layer():
+    """Three chips hold 4 experts each of 12: their parts of the routed sum
+    add up to what one chip holding all 12 gives, which is the numpy form
+    of the whole layer.  (What every chip computes alike, the shared
+    expert, is outside the op and counted once: tests/test_mla_moe.py.)"""
+    m = _moe_inputs(held=12)
+    ids, weights = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
+    whole = _layer(m, 0, 12, ids, weights)
+    np.testing.assert_allclose(
+        whole, np_experts(m["x"], ids, weights, m["wg"], m["wu"], m["wd"], 0),
+        rtol=1e-4, atol=1e-4)
+    shares = sum(_layer(m, first, 4, ids, weights) for first in (0, 4, 8))
+    np.testing.assert_allclose(shares, whole, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("tile", [4, 16, 64])
+def test_nothing_is_dropped_with_every_token_on_one_held_expert(tile):
+    m = _moe_inputs(tokens=50, held=12)
+    ids = np.tile(np.array([[6, 1, 11]]), (50, 1))     # 6 is held below
+    weights = _rs(3).rand(50, 3)
+    got = _layer(m, 4, 4, ids, weights, tile=tile)
+    want = np_experts(m["x"], ids, weights, m["wg"][4:8], m["wu"][4:8],
+                      m["wd"][4:8], 4)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    _, _, n_tiles, counts = llm.expert_tiles(jnp.asarray(ids, jnp.int32), 4,
+                                             4, tile)
+    assert list(np.asarray(counts)) == [0, 0, 50, 0]
+    assert int(n_tiles) == -(-50 // tile)
+
+
+def test_the_loop_runs_over_the_tiles_in_use_not_over_the_capacity():
+    """The trip count follows the pairs routed here: sum over the held
+    experts of ceil(pairs / tile), against a capacity that covers every
+    pair on one expert."""
+    m = _moe_inputs(tokens=200)
+    ids, _ = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
+    row_pair, tile_expert, n_tiles, counts = llm.expert_tiles(
+        jnp.asarray(ids, jnp.int32), 4, 4, 8)
+    counts = np.asarray(counts)
+    assert list(counts) == [(ids == 4 + j).sum() for j in range(4)]
+    assert int(n_tiles) == sum(-(-c // 8) for c in counts)
+    assert len(tile_expert) * 8 == len(row_pair) >= ids.size
+    assert int(n_tiles) < len(tile_expert) / 2
+    # every pair on a held expert has one row, in its expert's tiles
+    rows = np.asarray(row_pair)
+    real = rows[rows < ids.size]
+    assert sorted(real) == sorted(np.flatnonzero(
+        (ids.reshape(-1) >= 4) & (ids.reshape(-1) < 8)))
+    for t in range(int(n_tiles)):
+        pairs = rows[t * 8:(t + 1) * 8]
+        pairs = pairs[pairs < ids.size]
+        assert (ids.reshape(-1)[pairs] == 4 + int(tile_expert[t])).all()
+
+
+def test_the_bias_changes_the_selection_and_not_the_weights():
+    m = _moe_inputs()
+    x, w = _f(m["x"]), _f(m["router"])
+    ids0, w0 = llm.moe_route(x, w, jnp.zeros(12), k=3, scale=2.5)
+    bias = np.zeros(12, np.float32)
+    bias[5] = 10.0                              # expert 5 is always chosen
+    ids1, w1 = llm.moe_route(x, w, _f(bias), k=3, scale=2.5)
+    assert (np.asarray(ids1) == 5).any(axis=1).all()
+    assert not (np.asarray(ids0) == 5).any(axis=1).all()
+    s = 1 / (1 + np.exp(-(m["x"] @ m["router"].T)))
+    picked = np.take_along_axis(s, np.asarray(ids1), -1)
+    np.testing.assert_allclose(
+        np.asarray(w1), picked / picked.sum(-1, keepdims=True) * 2.5,
+        rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w1).sum(-1), 2.5, rtol=1e-5)
+    # where the bias changed nothing of the selection, nor did the weights
+    same = (np.asarray(ids0) == np.asarray(ids1)).all(axis=1)
+    np.testing.assert_array_equal(np.asarray(w0)[same], np.asarray(w1)[same])
+
+
+# ------------------------------------- flash attention, v of another size
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d,dv", [(48, 32), (32, 64)],
+                         ids=["v_smaller", "v_larger"])
+def test_flash_attention_with_another_value_head_size(causal, d, dv):
+    rs = _rs(2)
+    q, k = _f(rs.randn(1, 2, 128, d)), _f(rs.randn(1, 2, 128, d))
+    v = _f(rs.randn(1, 2, 128, dv))
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=64,
+                               block_k=64, interpret=True)
+
+    def plain(q, k, v):
+        return mha_reference(q, k, v, causal=causal)
+
+    out = kernel(q, k, v)
+    assert out.shape == (1, 2, 128, dv)
+    np.testing.assert_allclose(out, plain(q, k, v), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(kernel(*a))), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_attention_at_the_timed_blocks_and_head_sizes():
+    """Latent attention's head sizes (192 for the scores, 128 for the
+    values) at the default blocks (256 / 512), causal, over four query and
+    two key blocks: unequal blocks, a diagonal that crosses a key block,
+    key blocks skipped above it; forward, dq and dkv."""
+    from mxnet_tpu.ops.attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
+
+    assert (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) == (256, 512)
+    rs = _rs(3)
+    q, k = _f(rs.randn(1, 1, 1024, 192)), _f(rs.randn(1, 1, 1024, 192))
+    v = _f(rs.randn(1, 1, 1024, 128))
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True)
+
+    def plain(q, k, v):
+        return mha_reference(q, k, v, causal=True)
+
+    with jax.default_matmul_precision("highest"):
+        out = kernel(q, k, v)
+        np.testing.assert_allclose(out, plain(q, k, v), rtol=2e-5, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(jnp.sin(kernel(*a))),
+                       (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))),
+                        (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)

@@ -543,6 +543,13 @@ ELSEWHERE = {
                                        "Deformable"),
     "_contrib_SyncBatchNorm": ("tests/test_sync_bn.py", "SyncBatchNorm"),
     "Correlation": ("tests/test_extended_ops.py", "Correlation"),
+    "_contrib_rms_norm": ("tests/test_llm_ops.py", "llm.rms_norm"),
+    "_contrib_rope": ("tests/test_llm_ops.py", "llm.rope"),
+    "_contrib_gated_silu": ("tests/test_llm_ops.py", "llm.gated_silu"),
+    "_contrib_moe_route": ("tests/test_llm_ops.py", "llm.moe_route"),
+    "_contrib_moe_experts": ("tests/test_llm_ops.py", "llm.moe_experts"),
+    "_contrib_linear_cross_entropy": ("tests/test_llm_ops.py",
+                                      "llm.linear_cross_entropy"),
     "_contrib_flash_attention": ("tests/test_attention.py",
                                  "flash_attention"),
     "_contrib_interleaved_matmul_selfatt_qk": (

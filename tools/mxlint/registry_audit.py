@@ -109,6 +109,18 @@ def canonical_spec(name):
                  ((_rnn_param_len(3, 4, 1, 1, 1),), f),
                  ((1, 2, 4), f), ((1, 2, 4), f)],
                 {"state_size": 4, "num_layers": 1, "mode": "rnn_tanh"}),
+        "_contrib_rms_norm": ([((2, 5, 8), f), ((8,), f)], {}),
+        "_contrib_rope": ([((2, 3, 5, 8), f)], {"theta": 1e4}),
+        "_contrib_gated_silu": ([((2, 5, 8), f), ((12, 8), f), ((12, 8), f),
+                                 ((8, 12), f)], {}),
+        "_contrib_moe_route": ([((6, 8), f), ((12, 8), f), ((12,), f)],
+                               {"k": 3, "scale": 2.5}),
+        "_contrib_moe_experts": ([((6, 8), f), ((6, 3), i32), ((6, 3), f),
+                                  ((4, 8, 5), f), ((4, 8, 5), f),
+                                  ((4, 5, 8), f)],
+                                 {"first_expert": 4, "tile": 4}),
+        "_contrib_linear_cross_entropy": ([((8, 6), f), ((11, 6), f),
+                                           ((8,), i32)], {"chunk": 4}),
         "_contrib_quantize": ([((2, 3), f), ((1,), f), ((1,), f)], {}),
         "_contrib_quantize_v2": ([((2, 3), f)],
                                  {"min_calib_range": -1.0,
