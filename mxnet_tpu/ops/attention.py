@@ -6,7 +6,13 @@ valatt, div_sqrt_dim) materialise the full (seq, seq) score matrix in
 HBM.  On TPU that is HBM-bandwidth-bound; the TPU-native design is a
 flash-attention kernel that tiles Q/K/V through VMEM, keeps the online
 softmax statistics in VMEM scratch across the (sequential) K-block grid
-steps, and feeds the MXU with (block_q x d) @ (d x block_k) matmuls.
+steps, and feeds the MXU with (block_k x d) @ (d x block_q) matmuls whose
+operands are in the dtype the inputs arrive in (bfloat16 inputs are never
+cast up; every product accumulates in float32, and the scores, the
+softmax statistics and the accumulators are float32).  With ``causal``
+the kernels do the causal work only: a grid step above the diagonal
+fetches nothing and computes nothing, and a block on the diagonal is
+computed in tiles below it.
 
 Layout: (batch, heads, seq, head_dim) throughout.
 
@@ -31,12 +37,31 @@ from ..base import MXNetError
 from ..util import pallas_interpret
 from .registry import register
 
-# Measured on v5e (tools/bench_attention.py, r3): 256/512 blocks run
-# the fwd kernel ~2.9x faster than 128/128 (6.1 -> 17.6 TFLOP/s at
-# seq 4096, d=64) — larger K blocks amortize the online-softmax
-# rescale and keep the MXU busy despite the narrow d=64 operand.
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 512
+# Blocks, measured on v5e (tools/bench_attention.py, PR 29; PERF.md has the
+# table): causal, bfloat16, 2 x 32 heads x 4,096, head sizes 192 / 128,
+# forward + dq + dk/dv in ms:
+#     1,024 / 1,024  3.61 + 4.37 + 4.98 = 12.96
+#     1,024 / 512    4.32 + 5.07 + 5.97 = 15.36
+#       512 / 512    4.40 + 5.40 + 5.59 = 15.39
+#       512 / 1,024  4.48 + 5.27 + 5.95 = 15.69
+#       256 / 512    5.93 + 7.56 + 6.76 = 20.25
+#       256 / 256    7.81 + 10.91 + 8.37 = 27.09
+# A grid step that runs costs a pass over the query block's statistics and
+# accumulator whatever its key block's width, and a skipped one still its
+# ~0.35 us, so the fewest steps win although a block on the diagonal is
+# half masked; cutting that block into tiles of _TRIANGLE_TILE took
+# 1,024 / 1,024 from 14.68 to 12.96 (128: 13.46, 512: 13.40).  At
+# 2 x 8 x 2,048, d = 64 causal and d = 128 full, 1,024 / 1,024 also wins
+# (0.64 against 1.01 ms, 1.04 against 1.66).  Mosaic's 16 MiB of scoped
+# VMEM takes 1,024 / 1,024 up to _WIDE_ROW_BYTES of a q row and a v row
+# together (bfloat16 256 / 256, float32 128 / 128); past that (float32
+# 192 / 128, bfloat16 512 / 512) it does not compile, and the blocks are
+# 256 / 512 (r3's measurement with float32 tiles: 2.9x the forward of
+# 128 / 128 at seq 4096, d = 64).
+_WIDE_BLOCKS = (1024, 1024)
+_NARROW_BLOCKS = (256, 512)
+_WIDE_ROW_BYTES = 1024
+_TRIANGLE_TILE = 256
 _NEG_INF = -1e30
 
 
@@ -61,8 +86,114 @@ def mha_reference(q, k, v, causal=False, sm_scale=None):
 
 
 # ---------------------------------------------------------------------------
+# the causal rule over blocks
+# ---------------------------------------------------------------------------
+#
+# Masking is top-left aligned: key ``c`` is seen by query ``r`` when
+# ``c <= r``, whatever the two lengths.  Over blocks (query block ``i`` of
+# ``block_q`` rows, key block ``j`` of ``block_k`` columns) a grid step
+# either runs or is skipped whole, and a step that is skipped must cost
+# nothing: its index map names the block the neighbouring step that runs
+# has in VMEM already, and Pallas issues no copy for an unchanged block
+# index.  The functions take program ids or plain integers.
+
+def _runs(i, j, block_q, block_k):
+    """Block (i, j) holds a pair the causal rule lets through."""
+    return j * block_k <= i * block_q + block_q - 1
+
+
+def _crosses_diagonal(i, j, block_q, block_k):
+    """Block (i, j) holds a pair the causal rule masks (a block wholly
+    below the diagonal needs no mask)."""
+    return j * block_k + block_k - 1 > i * block_q
+
+
+def _kv_block(i, j, block_q, block_k, num_k):
+    """The key / value block grid step (i, j) of the forward and dq kernels
+    names (key blocks innermost): its own while it runs, after that the
+    last one that ran."""
+    last = jnp.minimum((i * block_q + block_q - 1) // block_k, num_k - 1)
+    return jnp.minimum(j, last)
+
+
+def _q_block(i, j, block_q, block_k, num_q):
+    """The query block grid step (j, i) of the dk/dv kernel names (query
+    blocks innermost): its own once it runs, before that the first one
+    that will (with fewer queries than keys, none may: then the last)."""
+    first = jnp.minimum((j * block_k) // block_q, num_q - 1)
+    return jnp.maximum(i, first)
+
+
+def _causal_steps(step, causal, i, j, block_q, block_k):
+    """Run ``step(masked)`` for grid step (i, j): always and unmasked
+    without ``causal``; with it, not at all above the diagonal, with the
+    mask in the blocks the diagonal crosses, without it below."""
+    if not causal:
+        step(False)
+        return
+    run = _runs(i, j, block_q, block_k)
+    masked = _crosses_diagonal(i, j, block_q, block_k)
+    pl.when(jnp.logical_and(run, masked))(lambda: step(True))
+    pl.when(jnp.logical_and(run, jnp.logical_not(masked)))(
+        lambda: step(False))
+
+
+def _step_tiles(masked, i, j, block_q, block_k, whole):
+    """What grid step (i, j) computes, as [(query slice, key slice, mask
+    origin)] within its blocks; the origin is the (query, key) position the
+    mask counts from, None for no mask.  An unmasked step is one tile.  So
+    is a masked one, but where the blocks are equal (the diagonal then
+    starts in the block's corner) and hold several ``_TRIANGLE_TILE``s it
+    is cut so that the half above the diagonal is not computed: strips of
+    queries with the keys up to their last when the keys are taken
+    ``whole`` (forward and dq: one update of a query's statistics and
+    accumulator), strips of keys with the queries from their first when
+    the queries are (dk/dv)."""
+    everything = slice(None)
+    if not masked:
+        return [(everything, everything, None)]
+    t = _TRIANGLE_TILE
+    if block_q != block_k or block_q % t or block_q == t:
+        return [(everything, everything, (i * block_q, j * block_k))]
+    strips = [slice(n, n + t) for n in range(0, block_q, t)]
+    if whole == "keys":
+        return [(qs, slice(0, qs.stop), (qs.start, 0)) for qs in strips]
+    return [(slice(ks.start, block_q), ks, (ks.start, ks.start))
+            for ks in strips]
+
+
+def _mask(s, origin):
+    """Transposed scores ``s`` (keys down, queries across, from query and
+    key ``origin``): keys after their query to -inf."""
+    if origin is None:
+        return s
+    row = origin[0] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = origin[1] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    return jnp.where(col > row, _NEG_INF, s)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b^T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a^T @ b
+
+
+def _dot(a, b, dims):
+    """A product on the MXU: operands in the dtype they arrive in, float32
+    accumulation."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
+#
+# All three kernels form the scores transposed, (block_k, block_q) = k @ q^T:
+# what belongs to a query (running maximum and sum, lse, delta) is then a
+# row over 128 lanes, 1/16 of the registers a column takes, reduced and
+# broadcast along sublanes, and the products into dk and dv need no
+# transposed operand.  The forward and dq kernels accumulate transposed,
+# (head, block_q), and transpose once per query block.
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, causal,
@@ -78,50 +209,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: skip blocks strictly above the diagonal
-    run = True
-    if causal:
-        run = kj * block_k <= qi * block_q + block_q - 1
+    def step(masked):
+        for qs, ks, origin in _step_tiles(masked, qi, kj, block_q, block_k,
+                                          "keys"):
+            s = _dot(k_ref[0, ks, :], q_ref[0, qs, :], _NT) * sm_scale
+            s = _mask(s, origin)                     # (keys, queries)
+            m_prev = m_ref[:, qs]                    # (1, queries)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:, qs] = (l_ref[:, qs] * corr
+                            + jnp.sum(p, axis=0, keepdims=True))
+            vb = v_ref[0, ks, :]                     # (keys, dv)
+            acc_ref[:, qs] = (acc_ref[:, qs] * corr
+                              + _dot(vb, p.astype(vb.dtype), _TN))
+            m_ref[:, qs] = m_new
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)            # (block_q, d)
-        kb = k_ref[0].astype(jnp.float32)           # (block_k, d)
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col > row, _NEG_INF, s)
-
-        m_prev = m_ref[:, 0:1]                       # (bq, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                       # (bq, bk)
-        corr = jnp.exp(m_prev - m_new)               # (bq, 1)
-        l_new = l_ref[:, 0:1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        vb = v_ref[0].astype(jnp.float32)            # (block_k, d)
-        pv = jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    _causal_steps(step, causal, qi, kj, block_q, block_k)
 
     @pl.when(kj == num_k - 1)
     def _():
-        l = l_ref[:, 0:1]
+        l = l_ref[:]
         l = jnp.where(l == 0.0, 1.0, l)              # fully-masked rows
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        # lse rides as (bh, sq, 1): a (block_q, 1) block keeps the TPU
-        # (8, 128)-tiling rule satisfied (last dim == full array dim)
-        lse_ref[0] = m_ref[:, 0:1] + jnp.log(l)
+        o_ref[0] = (acc_ref[:] / l).T.astype(o_ref.dtype)
+        lse_ref[0] = m_ref[:] + jnp.log(l)
+
+
+def _kv_spec(block_k, width, causal, block_q, num_k):
+    """Key / value blocks of the forward and dq kernels' grid."""
+    def index(z, i, j):
+        if causal:
+            j = _kv_block(i, j, block_q, block_k, num_k)
+        return (z, j, 0)
+    return pl.BlockSpec((1, block_k, width), index)
 
 
 def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+    """out (b, h, sq, dv) and lse (bh, 1, sq): a row a head, because a
+    column, (bh, sq, 1), is padded to 128 lanes in HBM (134 MB a layer at
+    64 x 4,096, kept from the forward to the backward pass)."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     bh = b * h
@@ -139,25 +265,25 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         grid=(bh, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda z, i, j: (z, j, 0)),
+            _kv_spec(block_k, d, causal, block_q, num_k),
+            _kv_spec(block_k, dv, causal, block_q, num_k),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda z, i, j: (z, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((dv, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
+    return out.reshape(b, h, sq, dv), lse
 
 
 # ---------------------------------------------------------------------------
@@ -166,7 +292,8 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, sm_scale, causal, block_q, block_k, num_k):
-    """Grid = (bh, num_q, num_k): accumulate dq over K blocks."""
+    """Grid = (bh, num_q, num_k): accumulate dq over K blocks, transposed
+    like the scores: (d, queries) += k^T @ ds^T."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -174,37 +301,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = True
-    if causal:
-        run = kj * block_k <= qi * block_q + block_q - 1
+    def step(masked):
+        for qs, ks, origin in _step_tiles(masked, qi, kj, block_q, block_k,
+                                          "keys"):
+            kb = k_ref[0, ks, :]
+            s = _dot(kb, q_ref[0, qs, :], _NT) * sm_scale
+            p = jnp.exp(_mask(s, origin) - lse_ref[0, :, qs])
+            dp = _dot(v_ref[0, ks, :], do_ref[0, qs, :], _NT)
+            ds = p * (dp - delta_ref[0, :, qs]) * sm_scale
+            acc_ref[:, qs] += _dot(kb, ds.astype(kb.dtype), _TN)
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                             # (bq, 1)
-        delta = delta_ref[0]                         # (bq, 1)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col > row, _NEG_INF, s)
-        p = jnp.exp(s - lse)                         # softmax probs
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _causal_steps(step, causal, qi, kj, block_q, block_k)
 
     @pl.when(kj == num_k - 1)
     def _():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[0] = acc_ref[:].T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -219,38 +330,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        run = qi * block_q + block_q - 1 >= kj * block_k
+    def step(masked):
+        for qs, ks, origin in _step_tiles(masked, qi, kj, block_q, block_k,
+                                          "queries"):
+            q = q_ref[0, qs, :]
+            do = do_ref[0, qs, :]
+            s = _dot(k_ref[0, ks, :], q, _NT) * sm_scale
+            p = jnp.exp(_mask(s, origin) - lse_ref[0, :, qs])
+            dv_acc[ks, :] += _dot(p.astype(do.dtype), do, _NN)
+            dp = _dot(v_ref[0, ks, :], do, _NT)
+            ds = p * (dp - delta_ref[0, :, qs]) * sm_scale
+            dk_acc[ks, :] += _dot(ds.astype(q.dtype), q, _NN)
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                             # (bq, 1)
-        delta = delta_ref[0]                         # (bq, 1)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col > row, _NEG_INF, s)
-        p = jnp.exp(s - lse)                         # (bq, bk)
-        # dv += p^T @ do
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale             # (bq, bk)
-        # dk += ds^T @ q
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _causal_steps(step, causal, qi, kj, block_q, block_k)
 
     @pl.when(qi == num_q - 1)
     def _():
@@ -258,66 +350,91 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
-                block_q, block_k, interpret):
+def _bwd_operands(q, k, v, o, lse, do):
+    """The two backward kernels' operands: (bh, seq, head) each, lse and
+    delta a row a head, (bh, 1, sq)."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     bh = b * h
-    qr, kr = (x.reshape(bh, -1, d) for x in (q, k))
-    vr = v.reshape(bh, sk, dv)
-    dor = do.reshape(bh, sq, dv)
-    lser = lse.reshape(bh, sq, 1)
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(bh, sq, 1)
-    num_q = sq // block_q
-    num_k = sk // block_k
+                    axis=-1).reshape(bh, 1, sq)
+    return (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, dv),
+            do.reshape(bh, sq, dv), lse, delta)
 
-    dq = pl.pallas_call(
+
+def _dq_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
+    qr, _, vr = operands[:3]
+    bh, sq, d = qr.shape
+    sk, dv = vr.shape[1:]
+    num_k = sk // block_k
+    rows = pl.BlockSpec((1, 1, block_q), lambda z, i, j: (z, 0, i))
+    return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_k=num_k),
-        grid=(bh, num_q, num_k),
+        grid=(bh, sq // block_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda z, i, j: (z, j, 0)),
+            _kv_spec(block_k, d, causal, block_q, num_k),
+            _kv_spec(block_k, dv, causal, block_q, num_k),
             pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda z, i, j: (z, i, 0)),
+            rows, rows,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
+    )(*operands)
 
-    dk, dv = pl.pallas_call(
+
+def _dkv_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
+    qr, kr, vr = operands[:3]
+    bh, sq, d = qr.shape
+    sk, dv = vr.shape[1:]
+    num_q = sq // block_q
+
+    def q_block(i, j):
+        return _q_block(i, j, block_q, block_k, num_q) if causal else i
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda z, j, i: (z, q_block(i, j), 0))
+
+    rows = pl.BlockSpec((1, 1, block_q),
+                        lambda z, j, i: (z, 0, q_block(i, j)))
+    return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q=num_q),
-        grid=(bh, num_k, num_q),
+        grid=(bh, sk // block_k, num_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda z, j, i: (z, i, 0)),
+            q_spec(d),
             pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda z, j, i: (z, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda z, j, i: (z, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda z, j, i: (z, i, 0)),
+            q_spec(dv),
+            rows, rows,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda z, j, i: (z, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv), v.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
+            jax.ShapeDtypeStruct((bh, sk, dv), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
+    )(*operands)
 
+
+def _bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
+                block_q, block_k, interpret):
+    operands = _bwd_operands(q, k, v, o, lse, do)
+    how = (sm_scale, causal, block_q, block_k, interpret)
+    dq = _dq_pallas(operands, *how)
+    dk, dv = _dkv_pallas(operands, *how)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
@@ -363,17 +480,34 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _block_choices(q, v):
+    """The block pairs to try for these operands, best first (the
+    measurements stand at the constants)."""
+    row_bytes = (q.shape[-1] + v.shape[-1]) * q.dtype.itemsize
+    wide = [_WIDE_BLOCKS] if row_bytes <= _WIDE_ROW_BYTES else []
+    return wide + [_NARROW_BLOCKS, (128, 128)]
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False):
+                    block_q=None, block_k=None, interpret=False):
     """Fused attention over (batch, heads, seq, head_dim) arrays.  ``v``
     (and so the output) may have another head size than ``q`` and ``k``
     (latent attention trains with 192 for the scores and 128 for the
     values); ``sm_scale`` defaults to the scores' head size.
 
-    On an accelerator this is always the Pallas flash kernel: a sequence
-    the blocks cannot tile raises instead of materialising seq x seq
-    scores.  The CPU platform computes the XLA reference;
+    The products run in the inputs' dtype with float32 accumulation: for
+    bfloat16 inputs the only roundings beyond the reference's are those
+    of the probabilities and of ``ds`` to bfloat16 before their second
+    product (``mha_reference`` rounds the probabilities the same way);
+    float32 inputs are multiplied as float32 operands at jax's matmul
+    precision (on the MXU the default is one bfloat16 pass, ``highest``
+    is exact).  ``block_q`` / ``block_k``
+    default to the measured choice for the operands (``_block_choices``);
+    a sequence shorter than a block is one block, whatever its length.
+
+    On an accelerator this is always the Pallas flash kernel: a longer
+    sequence that no block pair tiles raises instead of materialising
+    seq x seq scores.  The CPU platform computes the XLA reference;
     ``interpret=True`` runs the kernel through the Pallas interpreter
     there (the test suite's path).
 
@@ -390,7 +524,11 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     if not on_cpu or interpret:
         # prefer the fast measured blocks, but step down to 128/128 for
         # sequences they don't divide
-        for cq, ck in ((block_q, block_k), (128, 128)):
+        if block_q is None or block_k is None:
+            choices = _block_choices(q, v)
+        else:
+            choices = [(block_q, block_k), (128, 128)]
+        for cq, ck in choices:
             bq = min(cq, q.shape[2])
             bk = min(ck, k.shape[2])
             if _tiles(q, k, bq, bk):
@@ -398,9 +536,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
         if not on_cpu:
             raise MXNetError(
                 "flash_attention: sequence lengths (q %d, k %d) are not "
-                "tiled by blocks (%d, %d) or (128, 128); pad the "
-                "sequence to a multiple of 128"
-                % (q.shape[2], k.shape[2], block_q, block_k))
+                "tiled by blocks %s; pad the sequence to a multiple of 128"
+                % (q.shape[2], k.shape[2],
+                   " or ".join("(%d, %d)" % pair for pair in choices)))
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
 
 

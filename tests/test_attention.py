@@ -5,6 +5,8 @@ forward vs an unfused numpy/jnp reference, gradients vs jax.grad of the
 reference.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -113,27 +115,221 @@ def test_interleaved_matmul_selfatt():
 
 def test_flash_interpret_ragged_seq_falls_back_correctly():
     """ADVICE r3: interpret mode must apply the same divisibility check
-    as hardware — a ragged seq (300 with 256/512 default blocks) would
-    otherwise leave trailing output rows unwritten.  The public entry
-    must produce correct values for ANY seq length."""
-    b, h, s, d = 1, 2, 300, 32
-    q, k, v = (_rand((b, h, s, d), seed=20 + i) for i in range(3))
-    ref = att.mha_reference(q, k, v)
-    out = att.flash_attention(q, k, v, interpret=True)  # default blocks
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-    # and the tiling check itself refuses ragged shapes
-    assert not att._tiles(q, k, 256, 512)
-    assert not att._tiles(q, k, 128, 128)
+    as hardware — a ragged seq the blocks do not tile would otherwise
+    leave trailing output rows unwritten.  The public entry must produce
+    correct values for ANY seq length: 300 is shorter than a block and
+    runs as one block, 1100 is tiled by no block pair and falls back."""
+    for s in (300, 1100):
+        q, k, v = (_rand((1, 2, s, 32), seed=20 + i) for i in range(3))
+        ref = att.mha_reference(q, k, v)
+        out = att.flash_attention(q, k, v, interpret=True)  # chosen blocks
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+        # and the tiling check itself refuses ragged shapes
+        assert not att._tiles(q, k, 256, 512)
+        assert not att._tiles(q, k, 128, 128)
+    assert not att._tiles(q, k, 1024, 1024)
 
 
 def test_flash_ragged_seq_raises_off_cpu(monkeypatch):
     """On an accelerator a sequence the blocks cannot tile is an error,
-    never a silent drop to materialised seq x seq scores."""
+    never a silent drop to materialised seq x seq scores (a sequence
+    shorter than a block is one block, whatever its length)."""
     from mxnet_tpu.base import MXNetError
 
     monkeypatch.setattr(att, "pallas_interpret", lambda: False)
-    q, k, v = (_rand((1, 1, 300, 32), seed=i) for i in range(3))
+    q, k, v = (_rand((1, 1, 1100, 32), seed=i) for i in range(3))
     with pytest.raises(MXNetError, match="not tiled"):
         att.flash_attention(q, k, v)
 
+
+
+# ------------------------------------------------ 16-bit operands (PR 29)
+
+
+def _with_grads(attn, causal, q, k, v, g, **how):
+    out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, causal=causal, **how),
+                       q, k, v)
+    return (out,) + vjp(g)
+
+
+def _oracle(causal, *args):
+    """out, dq, dk, dv of the unfused reference in float32 at highest
+    matmul precision, on the arguments as they are rounded."""
+    with jax.default_matmul_precision("highest"):
+        return _with_grads(att.mha_reference, causal,
+                           *(a.astype(jnp.float32) for a in args))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d,dv", [(64, 64), (192, 128)])
+def test_flash_bfloat16_against_the_float32_oracle(causal, d, dv):
+    """bfloat16 inputs go into the products as they are; what is lost is
+    the rounding of the bfloat16 results (measured 2-4e-3 of the largest
+    value, in the interpreter and on the chip: PERF.md, PR 29)."""
+    s = 1024
+    q, k = (_rand((1, 2, s, d), seed=40 + i).astype(jnp.bfloat16)
+            for i in range(2))
+    v, g = (_rand((1, 2, s, dv), seed=42 + i).astype(jnp.bfloat16)
+            for i in range(2))
+    got = _with_grads(att.flash_attention, causal, q, k, v, g, interpret=True)
+    for name, a, w in zip(("out", "dq", "dk", "dv"), got,
+                          _oracle(causal, q, k, v, g)):
+        assert a.dtype == jnp.bfloat16 and a.shape == w.shape
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - w))
+                    / jnp.max(jnp.abs(w)))
+        assert err < 1e-2, (name, err)
+
+
+def _equations(jaxpr, name):
+    """Every equation called ``name`` in a jaxpr and the jaxprs under it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_equations(sub, name))
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_flash_products_take_their_operands_dtype(dtype):
+    """The three kernels' products run in the dtype the inputs arrive in
+    and accumulate in float32: no tile is cast up on its way to the MXU."""
+    q, k = (_rand((1, 1, 512, 64), seed=i).astype(dtype) for i in range(2))
+    v, g = (_rand((1, 1, 512, 32), seed=2 + i).astype(dtype)
+            for i in range(2))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _with_grads, att.flash_attention, True, interpret=True,
+        block_q=256, block_k=256))(q, k, v, g).jaxpr
+    kernels = _equations(jaxpr, "pallas_call")
+    assert len(kernels) == 3
+    for kernel, products in zip(kernels, (2, 3, 4)):
+        dots = _equations(kernel.params["jaxpr"], "dot_general")
+        # the masked and the unmasked branch hold the products once each
+        assert len(dots) == 2 * products
+        for dot in dots:
+            assert [x.aval.dtype for x in dot.invars] == [dtype, dtype]
+            assert dot.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("blocks", [(1024, 1024), (256, 512), (128, 128)],
+                         ids=lambda b: "%dx%d" % b)
+@pytest.mark.parametrize("sq,sk", [(512, 1024), (1024, 512)])
+def test_flash_causal_rectangular_at_the_blocks_it_chooses(sq, sk, blocks):
+    """Top-left-aligned causal masking with more keys than queries and
+    with fewer, at every block pair ``_block_choices`` can give."""
+    d, dv = 48, 32
+    q = _rand((1, 2, sq, d), seed=50)
+    k, v = _rand((1, 2, sk, d), seed=51), _rand((1, 2, sk, dv), seed=52)
+    g = _rand((1, 2, sq, dv), seed=53)
+    assert blocks in att._block_choices(q.astype(jnp.bfloat16), v)
+    got = _with_grads(att.flash_attention, True, q, k, v, g, interpret=True,
+                      block_q=blocks[0], block_k=blocks[1])
+    with jax.default_matmul_precision("highest"):
+        want = _with_grads(att.mha_reference, True, q, k, v, g)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_blocks_follow_the_operands_row_bytes():
+    """1,024 / 1,024 where Mosaic's scoped VMEM takes it (PERF.md, PR 29),
+    the 256 / 512 of the float32 kernels past that."""
+    def first(d, dv, dtype):
+        q = jax.ShapeDtypeStruct((1, 1, 4096, d), dtype)
+        v = jax.ShapeDtypeStruct((1, 1, 4096, dv), dtype)
+        return att._block_choices(q, v)[0]
+
+    assert first(192, 128, jnp.bfloat16) == (1024, 1024)
+    assert first(256, 256, jnp.bfloat16) == (1024, 1024)
+    assert first(128, 128, jnp.float32) == (1024, 1024)
+    assert first(192, 128, jnp.float32) == (256, 512)
+    assert first(512, 512, jnp.bfloat16) == (256, 512)
+
+
+# -------------------------------------------- the causal rule over blocks
+
+
+@pytest.mark.parametrize("sq,sk", [(4096, 4096), (2048, 4096), (4096, 2048)],
+                         ids=lambda n: str(n))
+@pytest.mark.parametrize("block_q,block_k",
+                         [(256, 512), (512, 512), (128, 128), (512, 256)])
+def test_causal_index_maps_against_brute_force(block_q, block_k, sq, sk):
+    """A step that runs names its own block; a skipped step names the block
+    its neighbour that runs has fetched (the step before it in the forward
+    and dq grids, the step after it in the dk/dv grid), so the blocks
+    fetched in a row are as many as the steps that run."""
+    num_q, num_k = sq // block_q, sk // block_k
+    rows, cols = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    seen = (cols <= rows).reshape(num_q, block_q, num_k, block_k)
+    runs = seen.any(axis=(1, 3))                      # brute force
+    crosses = runs & ~seen.all(axis=(1, 3))
+    for i in range(num_q):
+        for j in range(num_k):
+            assert bool(att._runs(i, j, block_q, block_k)) == runs[i, j]
+            if runs[i, j]:
+                assert bool(att._crosses_diagonal(
+                    i, j, block_q, block_k)) == crosses[i, j]
+
+    assert runs[:, 0].all()                 # key block 0 runs for every i
+    named = np.array([[int(att._kv_block(i, j, block_q, block_k, num_k))
+                       for j in range(num_k)] for i in range(num_q)])
+    own = np.broadcast_to(np.arange(num_k), named.shape)
+    assert (named[runs] == own[runs]).all()
+    before = np.roll(named, 1, axis=1)
+    assert (named[~runs] == before[~runs]).all()
+    fetched = 1 + (np.diff(named, axis=1) != 0).sum(axis=1)
+    assert (fetched == runs.sum(axis=1)).all()
+
+    named = np.array([[int(att._q_block(i, j, block_q, block_k, num_q))
+                       for j in range(num_k)] for i in range(num_q)])
+    own = np.broadcast_to(np.arange(num_q)[:, None], named.shape)
+    assert (named[runs] == own[runs]).all()
+    assert ((0 <= named) & (named < num_q)).all()
+    after = np.roll(named, -1, axis=0)
+    skipped = ~runs
+    skipped[-1] = False                     # the last step has none after
+    assert (named[skipped] == after[skipped]).all()
+    fetched = 1 + (np.diff(named, axis=0) != 0).sum(axis=0)
+    assert (fetched == np.maximum(runs.sum(axis=0), 1)).all()
+
+
+@pytest.mark.parametrize("whole", ["keys", "queries"])
+@pytest.mark.parametrize("block", [1024, 512, 256, 384])
+def test_the_tiles_of_a_diagonal_block_cover_its_lower_half(block, whole):
+    """What ``_step_tiles`` leaves out of a block on the diagonal is masked
+    anyway, and no pair is computed twice."""
+    count = np.zeros((block, block), int)             # (query, key)
+    kept = np.zeros((block, block), bool)
+    for qs, ks, (row0, col0) in att._step_tiles(True, 3, 3, block, block,
+                                                whole):
+        count[qs, ks] += 1
+        rows = np.arange(block)[qs][:, None] - np.arange(block)[qs][0] + row0
+        cols = np.arange(block)[ks][None, :] - np.arange(block)[ks][0] + col0
+        kept[qs, ks] |= cols <= rows
+    lower = np.tril(np.ones((block, block), bool))
+    assert (kept == lower).all() and count.max() == 1
+    if block % att._TRIANGLE_TILE == 0 and block > att._TRIANGLE_TILE:
+        n = block // att._TRIANGLE_TILE
+        assert count.sum() == block * block * (n + 1) // (2 * n)
+    assert att._step_tiles(False, 3, 2, block, block, whole) == [
+        (slice(None), slice(None), None)]
+
+
+def test_flash_diagonal_blocks_cut_into_tiles_match_the_reference():
+    """Equal blocks of two tiles a side over a 2 x 2 grid, float32: the
+    blocks on the diagonal are computed as strips."""
+    q, k = (_rand((1, 2, 1024, 48), seed=60 + i) for i in range(2))
+    v, g = (_rand((1, 2, 1024, 32), seed=62 + i) for i in range(2))
+    assert 512 == 2 * att._TRIANGLE_TILE
+    got = _with_grads(att.flash_attention, True, q, k, v, g, interpret=True,
+                      block_q=512, block_k=512)
+    with jax.default_matmul_precision("highest"):
+        want = _with_grads(att.mha_reference, True, q, k, v, g)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for a, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)

@@ -300,15 +300,16 @@ def test_flash_attention_with_another_value_head_size(causal, d, dv):
 
 def test_flash_attention_at_the_timed_blocks_and_head_sizes():
     """Latent attention's head sizes (192 for the scores, 128 for the
-    values) at the default blocks (256 / 512), causal, over four query and
-    two key blocks: unequal blocks, a diagonal that crosses a key block,
-    key blocks skipped above it; forward, dq and dkv."""
-    from mxnet_tpu.ops.attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
+    values) at the blocks float32 takes (256 / 512: the cell's check runs
+    them), causal, over four query and two key blocks: unequal blocks, a
+    diagonal that crosses a key block, key blocks skipped above it;
+    forward, dq and dkv."""
+    from mxnet_tpu.ops.attention import _block_choices
 
-    assert (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) == (256, 512)
     rs = _rs(3)
     q, k = _f(rs.randn(1, 1, 1024, 192)), _f(rs.randn(1, 1, 1024, 192))
     v = _f(rs.randn(1, 1, 1024, 128))
+    assert _block_choices(q, v)[0] == (256, 512)
 
     def kernel(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=True)

@@ -361,6 +361,60 @@ def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
     assert copies_at_the_entry(orders) == 0
 
 
+@pytest.mark.parametrize("dtype,blocks", [("bfloat16", (1024, 1024)),
+                                          ("float32", (256, 512))])
+def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
+        v5e, monkeypatch, dtype, blocks):
+    """Mosaic takes the three flash-attention kernels at the language-model
+    cell's shape (2 x 32 heads x 4,096, 192 / 128; bfloat16 as the step
+    runs them, float32 as the check does), and their instructions are the
+    ones ``benchmark/harness/attention_cost.py`` counts: q, k, v (and do,
+    lse, delta), one result for dq, two for the others."""
+    import os
+    import re
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mxnet_tpu.ops import attention as att
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.harness import attention_cost, hlo_cost
+
+    # traced on the CPU platform the entry would take its XLA branch
+    monkeypatch.setattr(att, "pallas_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(width):
+        return jax.ShapeDtypeStruct((2, 32, 4096, width), jnp.dtype(dtype),
+                                    sharding=chip)
+
+    def forward_and_backward(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: att.flash_attention(q, k, v, causal=True),
+            q, k, v)
+        return (out,) + vjp(g)
+
+    assert att._block_choices(arg(192), arg(128))[0] == blocks
+    text = jax.jit(forward_and_backward).lower(
+        arg(192), arg(192), arg(128), arg(128)).compile().as_text()
+    # the profiler names an instruction with its operands' types inline
+    lines = [hlo_cost.split_instruction(line) for line in text.splitlines()]
+    types = {name: result for name, _, (result, _, _) in lines}
+    flops = []
+    for name, opcode, (result, operands, attrs) in lines:
+        if attention_cost.KERNEL_TARGET in attrs:
+            operands = re.sub(r"%([\w.\-]+)", lambda m: "%s %s" % (
+                types[m.group(1)], m.group(0)), operands)
+            flops.append(attention_cost.kernel_flops("%%%s = %s %s(%s)%s" % (
+                name, result, opcode, operands, attrs)))
+    pairs = 2 * 32 * 4096 * 4096 / 2
+    assert sorted(flops) == [pairs * n for n in (640, 1024, 1280)]
+
+
 # ------------------------------------ why orders, and not layouts, are held
 
 _ASKS_FOR_A_LAYOUT = """
