@@ -6,7 +6,7 @@ published width of the one model the repo has measured (ResNet-50 v1
 from ``gluon.model_zoo.vision``, 1000 classes, 224x224, NHWC, bf16
 compute over f32 masters), with seeded random weights:
 
-  train/benchmark  GluonTrainStep (the step bench.py times), bs=128
+  train/benchmark  GluonTrainStep (the step the benchmark's cells time), bs=128
   train/users      gluon.Trainer + trainer.compile + cs.step (README)
   serve            net.export -> Predictor -> InferenceServer, requests
                    of 1-8 images against the unbatched predictor
@@ -167,7 +167,8 @@ def check_losses(name, losses):
 
 
 def phase_train_benchmark(cfg, platform, net, n_devices=1):
-    """The step bench.py times: GluonTrainStep over a {'dp': n} mesh.
+    """The step the benchmark's cells time: GluonTrainStep over a
+    {'dp': n} mesh.
     The step trains its own copy of the parameters; ``net`` keeps its
     initial ones."""
     import jax

@@ -1010,7 +1010,7 @@ def auto_resume(trainer=None, block=None, zero_step=None):
     manager into ``trainer``/``block``.  Returns the resumed step (int)
     or None when checkpointing is off or nothing valid exists.
 
-    ``zero_step`` (a ``GluonStep(..., zero=True)`` or
+    ``zero_step`` (a ``GluonTrainStep(..., zero=True)`` or
     ``ZeroCompiledStep``) selects the SHARDED resume path instead: the
     newest valid checkpoint's per-rank shard files are loaded and
     re-sharded onto the current mesh layout (``restore_zero`` — a run

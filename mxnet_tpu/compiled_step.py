@@ -43,15 +43,14 @@ Contract
   the rest — host syncs (LBSGD), cross-step host recurrences (Nadam),
   raw host-scalar NDArray math — keep the eager path and raise a clear
   error here.
-- ``compile_step(..., zero=True)`` / ``MXNET_TPU_ZERO=1`` routes the
-  same seam through :class:`ZeroCompiledStep`: the fused program with
-  ZeRO weight-update sharding over the 'dp' mesh axis — grads
+- ``compile_step(..., zero=True)`` routes the same seam through
+  :class:`ZeroCompiledStep`: the fused program with ZeRO
+  weight-update sharding over the 'dp' mesh axis — grads
   reduce-scattered to 1/n shards, the update on each device's
   param+state shard, updated params all-gathered inside the program
   (parallel/gluon_step.py zero path; docs/ZERO.md).
 - The eager path stays the untouched default and the
-  debugging/interop mode; ``MXNET_TPU_COMPILED_STEP=1``
-  (:func:`env_enabled`) is the opt-in for bench/launch wiring.
+  debugging/interop mode.
 
 Observability: each ``step()`` emits the same ``trainer:step``
 span/histogram as the eager Trainer, counts ``trainer_steps`` /
@@ -65,7 +64,6 @@ convention).  Docs: docs/COMPILED_STEP.md.
 
 from __future__ import annotations
 
-import os
 import weakref
 
 from . import health as _health
@@ -79,8 +77,7 @@ from .optimizer import optimizer as _opt
 from .ops import registry as _registry
 
 __all__ = ["CompiledStep", "ZeroCompiledStep", "compile_step",
-           "env_enabled", "donation_active", "cost_snapshot",
-           "xray_snapshot"]
+           "donation_active", "cost_snapshot", "xray_snapshot"]
 
 # live CompiledStep instances, for the read-side cost aggregation
 # (runtime_stats.snapshot merges cost_snapshot() into its "costs"
@@ -104,23 +101,14 @@ def donation_active():
     return _state["donating"]
 
 
-def env_enabled():
-    """True when ``MXNET_TPU_COMPILED_STEP=1`` asks launch/bench wiring
-    to train through the compiled whole-step path."""
-    return os.environ.get("MXNET_TPU_COMPILED_STEP") == "1"
-
-
-def compile_step(block, loss, trainer, zero=None, mesh=None):
+def compile_step(block, loss, trainer, zero=False, mesh=None):
     """Compile ``block`` + ``loss`` + ``trainer``'s optimizer into one
     donated whole-step XLA program (see module docstring).
 
-    ``zero=True`` (default from ``MXNET_TPU_ZERO=1``) routes through
-    :class:`ZeroCompiledStep` — the same fused program with ZeRO
-    weight-update sharding over the 'dp' mesh axis (docs/ZERO.md);
-    ``mesh`` optionally pins the device mesh for that path."""
-    if zero is None:
-        from .parallel.gluon_step import zero_env_enabled
-        zero = zero_env_enabled()
+    ``zero=True`` routes through :class:`ZeroCompiledStep` — the same
+    fused program with ZeRO weight-update sharding over the 'dp' mesh
+    axis (docs/ZERO.md); ``mesh`` optionally pins the device mesh for
+    that path."""
     if zero:
         return ZeroCompiledStep(block, loss, trainer, mesh=mesh)
     return CompiledStep(block, loss, trainer)
@@ -610,7 +598,7 @@ class ZeroCompiledStep:
         if not hit:
             _rts.record_dispatch("compiled_step", "miss")
             _rts.record_compile_key("compiled_step", key)
-            entry = _Entry(self._gstep._step, 0)
+            entry = _Entry(None, 0)     # the program is the wrapped step's
             self._cache[key] = entry
         else:
             _rts.record_dispatch("compiled_step", "hit")
@@ -651,22 +639,9 @@ class ZeroCompiledStep:
             return
         import time as _time
 
-        import jax
-
-        g = self._gstep
         t0 = _time.perf_counter()
         try:
-            def spec(a):
-                return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-            key = jax.random.PRNGKey(0)  # shape/dtype stand-in only
-            args = [tuple(spec(v) for v in g.train_vals),
-                    tuple(spec(v) for v in g.opt_state),
-                    tuple(spec(v) for v in g.aux_vals),
-                    spec(batch[0]), spec(batch[1]), spec(key)]
-            if g._opt_update is not None:
-                args.append(tuple(0.0 for _ in g._opt_update.slots))
-            compiled = g._step.lower(*args).compile()
+            compiled = self._gstep.program_for(*batch)
             entry.cost = _registry.compiled_cost(compiled)
             entry.xray = _xray.analyze(compiled, cost=entry.cost,
                                        label="zero_step", zero=True)

@@ -14,10 +14,10 @@ conv+bn+relu fusion under ``hybridize()``).
 TPU layout option (beyond reference parity): every constructor takes
 ``layout="NCHW"|"NHWC"``.  NCHW (default) keeps the reference's exact
 param shapes (OIHW conv weights) for checkpoint interop; NHWC stores
-OHWI weights and expects NHWC input — measured ~7% faster on the
-flagship training step (tools/bench_layout_experiment.py) because the
-channel-last layout maps directly onto the MXU tiling with fewer HBM
-relayout bytes.
+OHWI weights and expects NHWC input — the channel-last layout maps
+directly onto the MXU tiling with fewer HBM relayout bytes (measured
+~7% faster on an isolated conv tower on another toolchain, not
+re-measured; the benchmark's ResNet-50 cells run NHWC).
 
 ResNet-50 v1 is the flagship benchmark model (BASELINE.md: ResNet-50
 ImageNet img/s).

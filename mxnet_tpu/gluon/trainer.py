@@ -9,7 +9,7 @@ multi-device data parallel, grads living on different devices are
 reduced through the KVStore façade ('local'/'device'/'tpu'), whose
 'tpu' backend lowers push+pull to an XLA psum over the mesh
 (SURVEY.md §2.3) — the sharded flagship path instead jits the whole
-train step over the mesh (parallel/data_parallel.py).
+train step over the mesh (parallel/gluon_step.py).
 """
 
 from __future__ import annotations
@@ -211,7 +211,7 @@ class Trainer:
         return self._optimizer
 
     # ------------------------------------------------------- compiled step
-    def compile(self, block, loss, zero=None, mesh=None):
+    def compile(self, block, loss, zero=False, mesh=None):
         """Fuse ``block``'s forward + ``loss`` + backward + this
         trainer's optimizer update into ONE donated XLA program
         (``compiled_step.CompiledStep``): ``cs = trainer.compile(net,
@@ -220,10 +220,9 @@ class Trainer:
         the default/debug mode; see docs/COMPILED_STEP.md for the
         donation/rebind contract and the supported-optimizer set.
 
-        ``zero=True`` (default from ``MXNET_TPU_ZERO=1``) builds the
-        same fused program with ZeRO weight-update sharding over the
-        'dp' mesh axis — params and optimizer state live as 1/n
-        per-device shards inside the program
+        ``zero=True`` builds the same fused program with ZeRO
+        weight-update sharding over the 'dp' mesh axis — params and
+        optimizer state live as 1/n per-device shards inside the program
         (``compiled_step.ZeroCompiledStep``, docs/ZERO.md); ``mesh``
         optionally pins the device mesh for that path."""
         from .. import compiled_step as _compiled

@@ -10,6 +10,5 @@ ICI (in-slice) and DCN (cross-slice).
 
 from .mesh import (create_mesh, data_parallel_sharding, get_default_mesh,  # noqa: F401
                    host_allreduce, set_default_mesh)
-from .data_parallel import DataParallelStep, make_train_step  # noqa: F401
 from .gluon_step import GluonTrainStep  # noqa: F401
 from .ring_attention import ring_attention, ulysses_attention  # noqa: F401

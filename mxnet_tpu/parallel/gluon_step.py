@@ -7,30 +7,19 @@ reference splits this across GraphExecutor fwd/bwd + KVStore push/pull
 + python optimizer updates (SURVEY.md §3.1/§3.4); GSPMD inserts the
 gradient all-reduce over the 'dp' mesh axis automatically, riding ICI.
 
-ZeRO weight-update sharding (``zero=True`` / ``MXNET_TPU_ZERO=1``,
-Xu et al. arXiv:2004.13336): instead of every device holding the full
-replicated parameters + optimizer state, each parameter is flattened,
-padded to a multiple of the 'dp' axis size n, and laid out as 1-D
-shards — each device owns exactly 1/n of every parameter and of every
-optimizer-state leaf (state is *born* on that layout, never
-materialized replicated).  Inside the one donated program the flat
-shards are constrained to replicated for the forward (GSPMD emits the
-param all-gather, overlapped with forward compute), the backward's
-summed gradients are constrained back to the 1/n layout (the
-reduce-scatter; on some backends GSPMD expresses it as
-all-reduce + slice — semantically identical), and the optimizer update
-runs elementwise on the shards.  The math is unchanged — elementwise
-updates commute with sharding — so the step is bit-exact vs the
-unsharded dp step.  Docs: docs/ZERO.md.
+``zero=True`` holds parameters and optimizer state as flat 1/n 'dp'
+shards instead (ZeRO weight-update sharding: ``_FlatShards``,
+docs/ZERO.md).
 
 ``optimizer=`` accepts any ``compiled_step_safe`` Optimizer (SGD, NAG,
 Signum, Adam, Adamax, FTML, Ftrl, RMSProp, AdaGrad, AdaDelta): the
 real fused-kernel update is traced into the step, with per-step
 scalars (scheduler lr, bias corrections, t) refilled host-side each
 call — the compiled_step.py protocol.  The default stays the fused
-sgd-momentum closure.
+sgd-momentum rule, its hyper-parameters constants of the program.
 
-Used by bench.py, __graft_entry__.py and the multi-chip Trainer path.
+Used by the benchmark's cells (``benchmark/entries``), chip_smoke.py,
+__graft_entry__.py and ``trainer.compile(..., zero=True)``.
 """
 
 from __future__ import annotations
@@ -42,7 +31,6 @@ import os
 
 import numpy as _np
 
-from .. import autograd
 from .. import health as _health
 from .. import profiler as _profiler
 from .. import random as _random
@@ -52,14 +40,7 @@ from ..base import MXNetError
 from ..gluon.block import staged_call
 from ..ndarray import NDArray
 
-__all__ = ["GluonTrainStep", "GluonStep", "sgd_momentum_init",
-           "sgd_momentum_update", "zero_env_enabled"]
-
-
-def zero_env_enabled():
-    """True when ``MXNET_TPU_ZERO=1`` asks training wiring to run the
-    ZeRO weight-update-sharded step (docs/ZERO.md)."""
-    return os.environ.get("MXNET_TPU_ZERO") == "1"
+__all__ = ["GluonTrainStep"]
 
 
 def _padded_size(size, n):
@@ -99,28 +80,6 @@ def _pure_loss_builder(block, loss_block, trainable, aux,
     return pure_loss
 
 
-def sgd_momentum_init(train_vals):
-    import jax.numpy as jnp
-
-    return tuple(jnp.zeros_like(v) for v in train_vals)
-
-
-def sgd_momentum_update(lr, momentum=0.9, wd=0.0):
-    """Fused SGD(+momentum, +wd) matching the reference semantics
-    (src/operator/optimizer_op.cc sgd_mom_update)."""
-
-    def update(train_vals, grads, states):
-        new_vals, new_states = [], []
-        for w, g, s in zip(train_vals, grads, states):
-            g = g + wd * w
-            s = momentum * s + g
-            new_vals.append((w - lr * s).astype(w.dtype))
-            new_states.append(s)
-        return tuple(new_vals), tuple(new_states)
-
-    return update
-
-
 def _global_grad_norm(grads):
     """Fused global grad L2 norm over RAVELED f32 views — the same
     reduction shape on the dp and ZeRO paths (full vs flat-padded
@@ -137,7 +96,61 @@ def _global_grad_norm(grads):
     return jnp.sqrt(total)
 
 
-class _OptimizerUpdate:
+class _UpdateRule:
+    """Which rule updates the parameters inside the step program.
+
+    ``leaf_dtypes``: per parameter, the dtypes of its state leaves (every
+    leaf starts as zeros of the parameter's shape, wherever the step
+    holds it).  ``slots``: the (parameter index, name) of every per-step
+    scalar the host refills; none by default, and an empty tuple of
+    scalars adds no parameter to the program.  ``apply(train_vals, grads,
+    state_vals, scalars)`` is traced into the step and returns (new
+    values, new state leaves)."""
+
+    slots = ()
+    opt = None      # the Optimizer whose host side a checkpoint keeps
+
+    def init_state(self, alloc):
+        """Flat state-leaf tuple via ``alloc(param_index, leaf_dtype)``
+        — the caller chooses placement (ZeRO passes jitted zeros with
+        sharded out_shardings, so leaves are born 1/n per device)."""
+        return tuple(alloc(i, dt)
+                     for i, dts in enumerate(self.leaf_dtypes)
+                     for dt in dts)
+
+    def host_scalars(self):
+        """The values of ``slots`` for the next call."""
+        return ()
+
+    def host_state(self):
+        """What of the rule lives on the host, for a checkpoint."""
+        return None
+
+    def load_host_state(self, blob):
+        pass
+
+
+class _FusedSGD(_UpdateRule):
+    """Fused SGD(+momentum, +wd) matching the reference semantics
+    (src/operator/optimizer_op.cc sgd_mom_update): one momentum leaf per
+    parameter in the parameter's dtype, ``lr`` / ``momentum`` / ``wd``
+    constants of the program."""
+
+    def __init__(self, lr, momentum, wd, dtypes):
+        self.lr, self.momentum, self.wd = lr, momentum, wd
+        self.leaf_dtypes = [[dt] for dt in dtypes]
+
+    def apply(self, train_vals, grads, state_vals, scalars):
+        new_vals, new_states = [], []
+        for w, g, s in zip(train_vals, grads, state_vals):
+            g = g + self.wd * w
+            s = self.momentum * s + g
+            new_vals.append((w - self.lr * s).astype(w.dtype))
+            new_states.append(s)
+        return tuple(new_vals), tuple(new_states)
+
+
+class _OptimizerUpdate(_UpdateRule):
     """The real fused-kernel ``Optimizer`` traced into the functional
     step — compiled_step.py's updater-tracing idiom, functional-state
     edition.
@@ -185,23 +198,31 @@ class _OptimizerUpdate:
         self.slots = [(i, name) for i in range(len(dtypes))
                       for name in sorted(optimizer.step_scalars(i))]
 
-    def init_state(self, alloc):
-        """Flat state-leaf tuple via ``alloc(param_index, leaf_dtype)``
-        — the caller chooses placement (ZeRO passes jitted zeros with
-        sharded out_shardings, so leaves are born 1/n per device)."""
-        return tuple(alloc(i, dt)
-                     for i, dts in enumerate(self.leaf_dtypes)
-                     for dt in dts)
-
     def host_scalars(self):
         """Advance the host step counters and refill every per-step
         scalar slot — one float per (index, name) — for the next call."""
         opt = self.opt
         table = {}
-        for i in range(len(self.templates)):
-            opt._update_count(i)
-            table[i] = opt.step_scalars(i)
-        return tuple(float(table[i][name]) for i, name in self.slots)
+        with _profiler.boundary_span("mxtpu.step.scalars"):
+            for i in range(len(self.templates)):
+                opt._update_count(i)
+                table[i] = opt.step_scalars(i)
+            return tuple(float(table[i][name]) for i, name in self.slots)
+
+    def host_state(self):
+        """The optimizer's hyper-state (update counts drive Adam-family
+        bias correction; schedulers drive lr): the device shards alone
+        do not make the step resumable."""
+        from .. import checkpoint as _ckpt
+
+        return _ckpt._strip_optimizer(self.opt)
+
+    def load_host_state(self, blob):
+        import pickle
+
+        hyper = dict(pickle.loads(blob).__dict__)
+        hyper.pop("param_dict", None)
+        self.opt.__dict__.update(hyper)
 
     def apply(self, train_vals, grads, state_vals, scalars):
         """Traced: run the real ``update()`` on NDArray views of the
@@ -227,7 +248,11 @@ class _OptimizerUpdate:
         return tuple(new_vals), tuple(new_state)
 
 
-def _put(vals, shard):
+def _values(params):
+    return tuple(p.data().data_jax for p in params)
+
+
+def _put(vals, shards):
     """Place functional values onto their shardings up front: committed
     single-device arrays cannot be implicitly resharded by jit, and
     this also avoids a first-step transfer.  jnp.array(copy=True)
@@ -237,10 +262,8 @@ def _put(vals, shard):
     import jax
     import jax.numpy as jnp
 
-    vals = tuple(jnp.array(v, copy=True) for v in vals)
-    if isinstance(shard, tuple):
-        return tuple(jax.device_put(v, s) for v, s in zip(vals, shard))
-    return tuple(jax.device_put(v, shard) for v in vals)
+    return tuple(jax.device_put(jnp.array(v, copy=True), s)
+                 for v, s in zip(vals, shards))
 
 
 def _cast_floating(x, dtype):
@@ -328,6 +351,308 @@ def _state_tree(i, doc):
     return property(get, put, doc=doc)
 
 
+class _InCompilersOrder:
+    """The form the state is held in, replicated or ``param_spec_fn``-
+    sharded: every leaf in the model's shape on the mesh, its dimensions
+    in the order the step program's compiler lays it out in
+    (``GluonTrainStep``'s docstring)."""
+
+    def __init__(self, mesh, param_spec_fn):
+        self._mesh, self._spec_fn = mesh, param_spec_fn
+
+    def place(self, trainable, aux, rule):
+        """-> [train_vals, opt_state, aux_vals] on the mesh, the optimizer
+        state born there.  Sets ``shards``: per tree, per leaf, its
+        sharding in the model's order."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+
+        from .mesh import replicated_sharding
+
+        repl = replicated_sharding(self._mesh)
+
+        def shard(p):
+            # per-parameter shardings (tensor parallelism etc.)
+            if self._spec_fn is None:
+                return repl
+            return NamedSharding(self._mesh, self._spec_fn(p.name, p.shape))
+
+        tv_shard = tuple(shard(p) for p in trainable)
+        train_vals = _values(trainable)
+        shapes = [v.shape for v in train_vals]
+        # fresh and the step's own, so placed without _put's copy: Adam's
+        # state of a model that fills the chip does not fit there twice
+        state = rule.init_state(lambda i, dt: jax.device_put(
+            jnp.zeros(shapes[i], dt), tv_shard[i]))
+        # one sharding per state leaf, mirroring its parameter
+        self.shards = (tv_shard,
+                       tuple(tv_shard[i]
+                             for i, dts in enumerate(rule.leaf_dtypes)
+                             for _ in dts),
+                       tuple(shard(p) for p in aux))
+        return [_put(train_vals, tv_shard), state,
+                _put(_values(aux), self.shards[2])]
+
+    # traced into the step program
+    def models(self, held):
+        """What is held of the parameters, in the model's shapes."""
+        return held
+
+    def for_update(self, grads, held):
+        """-> (the gradients as the update rule takes them, their norm)."""
+        grads = tuple(g.astype(v.dtype) for g, v in zip(grads, held))
+        return grads, _global_grad_norm(grads)
+
+    # on the host
+    def orders(self, held, learn):
+        """Per state leaf, the order it is held in: the compiler's."""
+        _rts.inc("step_state_relayouts")
+        return learn()
+
+    def params_on_host(self, train_vals):
+        return [_np.asarray(v) for v in train_vals]
+
+    def after_step(self):
+        pass
+
+
+class _FlatShards:
+    """The ZeRO form (weight-update sharding, Xu et al. arXiv:2004.13336):
+    instead of every device holding the full replicated parameters +
+    optimizer state, each parameter is flattened, padded to a multiple
+    of the 'dp' axis size n, and laid out as 1-D shards — each device
+    owns exactly 1/n of every parameter and of every optimizer-state
+    leaf (state is *born* on that layout, never materialized
+    replicated); batch-norm statistics stay replicated.  Inside the one
+    donated program the flat shards are constrained to replicated for
+    the forward (GSPMD emits the param all-gather, overlapped with
+    forward compute), the backward's summed gradients are constrained
+    back to the 1/n layout (the reduce-scatter; on some backends GSPMD
+    expresses it as all-reduce + slice — semantically identical), and
+    the optimizer update runs elementwise on the shards (pads carry
+    exact zeros through: zero grad -> zero update).  The math is
+    unchanged — elementwise updates commute with sharding — so the step
+    is bit-exact vs the unsharded dp step.  ``zero_layout`` describes
+    the layout and the per-step collective bytes; the sharded
+    checkpoint is written and read here.  Docs: docs/ZERO.md."""
+
+    def __init__(self, mesh):
+        from jax.sharding import NamedSharding, PartitionSpec as _P
+
+        from .mesh import replicated_sharding
+
+        self._n = int(mesh.shape["dp"])
+        self._repl = replicated_sharding(mesh)
+        self._flat = NamedSharding(mesh, _P("dp"))
+
+    def _flat_put(self, flat, meta, dtype):
+        """``flat`` (its first ``size`` elements) padded onto the layout."""
+        import jax
+
+        padded = _np.zeros((meta["padded"],), dtype)
+        padded[:meta["size"]] = flat[:meta["size"]]
+        return jax.device_put(padded, self._flat)
+
+    def place(self, trainable, aux, rule):
+        import jax
+        import jax.numpy as jnp
+
+        n = self._n
+        train_vals = _values(trainable)
+        layout = []
+        for p, v in zip(trainable, train_vals):
+            size = int(v.size)
+            layout.append({"name": p.name,
+                           "shape": tuple(int(s) for s in v.shape),
+                           "dtype": str(v.dtype), "size": size,
+                           "padded": _padded_size(size, n)})
+        train = tuple(
+            self._flat_put(_np.asarray(v).reshape(-1), m,
+                           _np.dtype(m["dtype"]))
+            for v, m in zip(train_vals, layout))
+        # optimizer state is BORN on the shard layout — a jitted zeros
+        # with sharded out_shardings allocates 1/n per device directly;
+        # the replicated full-size state never exists at any point
+        state = rule.init_state(lambda i, dt: jax.jit(
+            lambda: jnp.zeros((layout[i]["padded"],), dt),
+            out_shardings=self._flat)())
+        self.shards = ((self._flat,) * len(train),
+                       (self._flat,) * len(state),
+                       (self._repl,) * len(aux))
+
+        leaves_per = [len(dts) for dts in rule.leaf_dtypes]
+        isz = [_np.dtype(m["dtype"]).itemsize for m in layout]
+        gather_bytes = sum(m["padded"] * s for m, s in zip(layout, isz))
+        self.zero_layout = {
+            "n": n,
+            "params": layout,
+            "state_leaves": leaves_per,
+            "state_dtypes": [[str(d) for d in dts]
+                             for dts in rule.leaf_dtypes],
+            # logical collective payload per step: every param is
+            # gathered once for the forward and its grad reduced once
+            # into the shard layout
+            "per_step_allgather_bytes": gather_bytes,
+            "per_step_reduce_bytes": gather_bytes,
+            "replicated_param_bytes": sum(
+                m["size"] * s for m, s in zip(layout, isz)),
+            "per_device_param_bytes": sum(
+                m["padded"] // n * s for m, s in zip(layout, isz)),
+            "per_device_state_bytes": sum(
+                m["padded"] // n * s * l
+                for m, s, l in zip(layout, isz, leaves_per)),
+        }
+        return [train, state, _put(_values(aux), self.shards[2])]
+
+    # traced into the step program
+    def models(self, held):
+        import jax
+
+        # the param all-gather
+        with _xray.scope(_xray.REGION_ZERO_AG):
+            return tuple(
+                jax.lax.with_sharding_constraint(f, self._repl)
+                [:m["size"]].reshape(m["shape"])
+                for f, m in zip(held, self.zero_layout["params"]))
+
+    def for_update(self, grads, held):
+        import jax
+
+        # norm over the still-replicated grads: identical reduction
+        # to the dp path's, so health trajectories match bit-exact
+        with _xray.scope(_xray.REGION_ZERO_GNORM):
+            gnorm = _global_grad_norm(grads)
+        # the reduce-scatter: each device keeps only the shard of the
+        # dp-summed grads its update needs
+        with _xray.scope(_xray.REGION_ZERO_RS):
+            grads = tuple(
+                jax.lax.with_sharding_constraint(g.astype(f.dtype),
+                                                 self._flat)
+                for g, f in zip(grads, held))
+        return grads, gnorm
+
+    # on the host
+    def orders(self, held, learn):
+        """The model's for every leaf: a flat shard has one dimension, and
+        no order was ever learned for the replicated statistics."""
+        return tuple(tuple(tuple(range(v.ndim)) for v in tree)
+                     for tree in held)
+
+    def params_on_host(self, train_vals):
+        return [_np.asarray(v)[:m["size"]].reshape(m["shape"])
+                for v, m in zip(train_vals, self.zero_layout["params"])]
+
+    def after_step(self):
+        zl = self.zero_layout
+        _rts.inc("zero_steps")
+        _rts.inc("zero_allgather_bytes", zl["per_step_allgather_bytes"])
+        _rts.inc("zero_reduce_bytes", zl["per_step_reduce_bytes"])
+
+    # ------------------------------------------------ sharded checkpoint
+    def shard_payloads(self, train_vals, opt_state):
+        """``{rank: payload}`` for every locally-addressable 'dp'
+        position — the per-rank shard files of a sharded checkpoint.
+        Each payload carries exactly the 1/n slice that rank owns
+        (params + optimizer-state leaves), so a rank never persists
+        another rank's bytes; in a multi-host run each process sees
+        only its own ranks here."""
+        out = {}
+
+        def collect(vals, kind):
+            for j, v in enumerate(vals):
+                shard_len = int(v.shape[0]) // self._n
+                for s in v.addressable_shards:
+                    rank = int(s.index[0].start or 0) // shard_len
+                    slot = out.setdefault(
+                        rank, {"params": {}, "state": {}})
+                    slot[kind][j] = _np.asarray(s.data)
+
+        collect(train_vals, "params")
+        collect(opt_state, "state")
+        return out
+
+    def save(self, step, train_vals, opt_state, rule, mgr):
+        """Commit a sharded checkpoint: one global manifest over
+        per-rank shard files (``CheckpointManager.save_sharded`` — the
+        rank-0 commit barrier lives there), layout metadata in the
+        ``aux`` sideband so resume can re-shard."""
+        from .. import checkpoint as _ckpt
+
+        mgr = mgr if mgr is not None else _ckpt.manager()
+        if mgr is None:
+            raise MXNetError(
+                "save_zero: no checkpoint manager — call "
+                "checkpoint.enable(directory) first or pass mgr=")
+        files = {"zero-shard-%05d-of-%05d" % (r, self._n): payload
+                 for r, payload in self.shard_payloads(
+                     train_vals, opt_state).items()}
+        aux = {"zero_layout": self.zero_layout}
+        blob = rule.host_state()
+        if blob is not None:
+            aux["optimizer"] = blob
+        return mgr.save_sharded(step, files, aux=aux)
+
+    def restore(self, manifest, rule, mgr):
+        """-> (the checkpoint's step, train_vals, opt_state) on this
+        layout, RE-SHARDING when the checkpoint's dp width differs from
+        the current mesh (the layout-change resume path): each full flat
+        vector is rebuilt from the old ranks' slices, stripped of the old
+        padding, re-padded to the current multiple and placed onto the
+        current 'dp' layout.  Restores the rule's host state and the RNG
+        stream too."""
+        from .. import checkpoint as _ckpt
+
+        mgr = mgr if mgr is not None else _ckpt.manager()
+        if mgr is None:
+            raise MXNetError("restore_zero: no checkpoint manager")
+        aux = mgr.load_aux(manifest)
+        if not aux or "zero_layout" not in aux:
+            raise MXNetError(
+                "restore_zero: checkpoint %s carries no zero_layout "
+                "sideband — not a sharded checkpoint"
+                % manifest.get("path"))
+        old, new = aux["zero_layout"], self.zero_layout
+        ranks = mgr.load_shard_files(manifest)
+        if len(ranks) != old["n"]:
+            raise MXNetError(
+                "restore_zero: checkpoint %s has %d of %d rank shard "
+                "files" % (manifest.get("path"), len(ranks), old["n"]))
+        if old["state_leaves"] != new["state_leaves"]:
+            raise MXNetError(
+                "restore_zero: optimizer state structure changed "
+                "(%r leaves saved vs %r now) — restore with the same "
+                "optimizer family"
+                % (old["state_leaves"], new["state_leaves"]))
+
+        def rebuild(kind, j, meta, dtype):
+            full = _np.concatenate(
+                [ranks[r][kind][j] for r in range(old["n"])])
+            return self._flat_put(full, meta, dtype)
+
+        train_vals = []
+        for j, (mo, mn) in enumerate(zip(old["params"], new["params"])):
+            if (mo["name"], mo["size"]) != (mn["name"], mn["size"]):
+                raise MXNetError(
+                    "restore_zero: parameter %d mismatch (%s/%d saved "
+                    "vs %s/%d now) — the model changed"
+                    % (j, mo["name"], mo["size"], mn["name"], mn["size"]))
+            train_vals.append(
+                rebuild("params", j, mn, _np.dtype(mn["dtype"])))
+        leaves = [(i, dt) for i, dts in enumerate(new["state_dtypes"])
+                  for dt in dts]
+        opt_state = [
+            rebuild("state", j, new["params"][i], _np.dtype(dt))
+            for j, (i, dt) in enumerate(leaves)]
+        blob = aux.get("optimizer")
+        if blob is not None:
+            rule.load_host_state(blob)
+        rng = manifest.get("rng")
+        if rng:
+            _random.set_state(rng)
+        return int(manifest.get("step", 0)), train_vals, opt_state
+
+
 class GluonTrainStep:
     """Compile a Gluon block + loss + optimizer into one sharded step.
 
@@ -360,15 +685,15 @@ class GluonTrainStep:
     TPU-native analog of the reference's multi-precision SGD
     (mp_sgd_update, src/operator/optimizer_op.cc).
 
-    zero: weight-update sharding (module docstring) — params and
-    optimizer state live as flat 1/n 'dp' shards; default from
-    ``MXNET_TPU_ZERO``.  ``self.zero_layout`` describes the layout and
-    the per-step collective bytes (also fed into the
-    ``zero_allgather_bytes`` / ``zero_reduce_bytes`` runtime counters).
+    zero: weight-update sharding — params and optimizer state live as
+    flat 1/n 'dp' shards (``_FlatShards``).  ``self.zero_layout``
+    describes the layout and the per-step collective bytes (also fed
+    into the ``zero_allgather_bytes`` / ``zero_reduce_bytes`` runtime
+    counters).
 
     optimizer: a ``compiled_step_safe`` Optimizer instance traced into
     the step (the real fused-kernel update); None keeps the fused
-    sgd-momentum closure built from ``lr/momentum/wd``.
+    sgd-momentum rule built from ``lr/momentum/wd``.
     """
 
     train_vals = _state_tree(0, "The trainable parameters' values.")
@@ -379,8 +704,7 @@ class GluonTrainStep:
     def __init__(self, block, loss_block, mesh=None, lr=0.1, momentum=0.9,
                  wd=0.0, compute_dtype=None, param_spec_fn=None,
                  data_spec=None, label_spec=None, aux_loss_weight=None,
-                 zero=None, optimizer=None):
-        import jax
+                 zero=False, optimizer=None):
         from jax.sharding import NamedSharding
 
         from .mesh import (data_parallel_sharding, get_default_mesh,
@@ -388,8 +712,7 @@ class GluonTrainStep:
 
         self.block = block
         self.mesh = mesh or get_default_mesh()
-        self._zero = zero_env_enabled() if zero is None else bool(zero)
-        if self._zero and param_spec_fn is not None:
+        if zero and param_spec_fn is not None:
             raise MXNetError(
                 "GluonTrainStep: zero=True owns the parameter layout "
                 "(flat 1-D 'dp' shards) and cannot compose with "
@@ -397,15 +720,14 @@ class GluonTrainStep:
         params = list(block.collect_params().values())
         self.trainable = [p for p in params if p.grad_req != "null"]
         self.aux = [p for p in params if p.grad_req == "null"]
-        self._held = [tuple(p.data().data_jax for p in self.trainable), (),
-                      tuple(p.data().data_jax for p in self.aux)]
-        if optimizer is not None:
-            self._opt_update = _OptimizerUpdate(
-                optimizer, [v.dtype for v in self._held[0]])
-            self._update = None
-        else:
-            self._opt_update = None
-            self._update = sgd_momentum_update(lr, momentum, wd)
+        dtypes = [v.dtype for v in _values(self.trainable)]
+        # which rule updates the parameters, and in what form the state
+        # is held between steps: everything below is written once
+        self._rule = (_FusedSGD(lr, momentum, wd, dtypes)
+                      if optimizer is None
+                      else _OptimizerUpdate(optimizer, dtypes))
+        self._form = (_FlatShards(self.mesh) if zero
+                      else _InCompilersOrder(self.mesh, param_spec_fn))
         self._compute_dtype = compute_dtype
         self.last_grad_norm = None
         self._step = None
@@ -413,9 +735,6 @@ class GluonTrainStep:
         self._leaves = None    # array arguments of one launch
         self._orders = None    # per state leaf, the order it is held in
         self._relaid = 0       # leaves held in another order than the model's
-        pure_loss = _pure_loss_builder(block, loss_block, self.trainable,
-                                       self.aux,
-                                       aux_loss_weight=aux_loss_weight)
 
         repl = replicated_sharding(self.mesh)
         x_shard = (NamedSharding(self.mesh, data_spec) if data_spec is not None
@@ -426,61 +745,33 @@ class GluonTrainStep:
             # labels are rank-1: shard them along the data spec's batch axis
             from jax.sharding import PartitionSpec as _P
             y_shard = NamedSharding(self.mesh, _P(data_spec[0]))
-        elif data_spec is not None:
-            y_shard = x_shard  # P(): replicated batch -> replicated labels
         else:
-            y_shard = x_shard
+            y_shard = x_shard  # P(): replicated batch -> replicated labels
         # place batch-sharded inputs via these shardings
         self.batch_sharding = x_shard
         self.label_sharding = y_shard
         self._repl = repl
+        self._rest_in = (x_shard, y_shard, repl, repl)  # ..., key, scalars
 
-        if self._zero:
-            self._build_zero(pure_loss, compute_dtype, repl,
-                             x_shard, y_shard)
-        else:
-            self._build_classic(pure_loss, compute_dtype, repl,
-                                x_shard, y_shard, param_spec_fn)
+        self._held = self._form.place(self.trainable, self.aux, self._rule)
+        # un-jitted.  self._step is built by _adopt_orders() at the first
+        # call: the order the state is held in (class docstring) needs
+        # the batch's shape to be learned
+        self._step_py = self._build(_pure_loss_builder(
+            block, loss_block, self.trainable, self.aux,
+            aux_loss_weight=aux_loss_weight), compute_dtype)
 
-    # ------------------------------------------------- replicated/dp path
-    def _build_classic(self, pure_loss, cast, repl, x_shard, y_shard,
-                       param_spec_fn):
+    def _build(self, pure_loss, cast):
+        """step(train_vals, opt_state, aux_vals, x, y, key, scalars) ->
+        (loss, train_vals, opt_state, aux_vals, grad norm), the state as
+        the form holds it."""
         import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding
 
-        opt_update = self._opt_update
-        update = self._update
-
-        if param_spec_fn is None:
-            tv_shard = aux_shard = repl
-        else:
-            # per-parameter shardings (tensor parallelism etc.) — the
-            # optimizer state mirrors the parameter sharding
-            tv_shard = tuple(
-                NamedSharding(self.mesh, param_spec_fn(p.name, p.shape))
-                for p in self.trainable)
-            aux_shard = tuple(
-                NamedSharding(self.mesh, param_spec_fn(p.name, p.shape))
-                for p in self.aux)
-        if opt_update is None:
-            self.opt_state = sgd_momentum_init(self.train_vals)
-            state_shard = tv_shard
-        else:
-            shapes = [v.shape for v in self.train_vals]
-            self.opt_state = opt_update.init_state(
-                lambda i, dt: jnp.zeros(shapes[i], dt))
-            if param_spec_fn is None:
-                state_shard = repl
-            else:
-                # one sharding per state leaf, mirroring its parameter
-                state_shard = tuple(
-                    tv_shard[i]
-                    for i, dts in enumerate(opt_update.leaf_dtypes)
-                    for _ in dts)
+        form, rule = self._form, self._rule
 
         def fwd_bwd(train_vals, aux_vals, x, y, key):
-            def loss_of(tv):
+            def loss_of(held):
+                tv = form.models(held)
                 if cast is not None:
                     tv = tuple(v.astype(cast) if v.dtype == _np.float32
                                else v for v in tv)
@@ -492,52 +783,17 @@ class GluonTrainStep:
             with _xray.scope(_xray.GRAD_MARKER):
                 (loss, new_aux), grads = jax.value_and_grad(
                     loss_of, has_aux=True)(train_vals)
-            grads = tuple(g.astype(v.dtype)
-                          for g, v in zip(grads, train_vals))
-            return loss, grads, new_aux, _global_grad_norm(grads)
+            return (loss, new_aux, *form.for_update(grads, train_vals))
 
-        if opt_update is None:
-            def step(train_vals, opt_state, aux_vals, x, y, key):
-                loss, grads, new_aux, gnorm = fwd_bwd(
-                    train_vals, aux_vals, x, y, key)
-                with _xray.scope(_xray.REGION_OPT):
-                    new_vals, new_state = update(train_vals, grads,
-                                                 opt_state)
-                return loss, new_vals, new_state, new_aux, gnorm
+        def step(train_vals, opt_state, aux_vals, x, y, key, scalars):
+            loss, new_aux, grads, gnorm = fwd_bwd(
+                train_vals, aux_vals, x, y, key)
+            with _xray.scope(_xray.REGION_OPT):
+                new_vals, new_state = rule.apply(train_vals, grads,
+                                                 opt_state, scalars)
+            return loss, new_vals, new_state, new_aux, gnorm
 
-            sig_in = (tv_shard, state_shard, aux_shard, x_shard, y_shard,
-                      repl)
-        else:
-            def step(train_vals, opt_state, aux_vals, x, y, key, scalars):
-                loss, grads, new_aux, gnorm = fwd_bwd(
-                    train_vals, aux_vals, x, y, key)
-                with _xray.scope(_xray.REGION_OPT):
-                    new_vals, new_state = opt_update.apply(
-                        train_vals, grads, opt_state, scalars)
-                return loss, new_vals, new_state, new_aux, gnorm
-
-            sig_in = (tv_shard, state_shard, aux_shard, x_shard, y_shard,
-                      repl, repl)
-
-        def per_leaf(shard, vals):
-            return shard if isinstance(shard, tuple) else (shard,) * len(vals)
-
-        self.train_vals = _put(self.train_vals, tv_shard)
-        # fresh and the step's own, so placed without _put's copy: Adam's
-        # state of a model that fills the chip does not fit there twice
-        self.opt_state = tuple(map(
-            jax.device_put, self.opt_state,
-            per_leaf(state_shard, self.opt_state)))
-        self.aux_vals = _put(self.aux_vals, aux_shard)
-
-        self._state_shard = tuple(
-            per_leaf(shard, vals) for shard, vals in zip(
-                (tv_shard, state_shard, aux_shard), self._held))
-        self._rest_in = sig_in[3:]
-        # un-jitted; composed by make_chained().  self._step is built by
-        # _adopt_orders() at the first call: the order the state is held
-        # in (class docstring) needs the batch's shape to be learned
-        self._step_py = step
+        return step
 
     def _compilers_orders(self, x, y, rest):
         """Per state leaf, its dimensions in the order (major first) the
@@ -548,7 +804,7 @@ class GluonTrainStep:
         from jax.experimental.layout import Format, Layout
 
         auto = jax.tree.map(lambda shard: Format(Layout.AUTO, shard),
-                            self._state_shard)
+                            self._form.shards)
 
         def spec(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -584,30 +840,36 @@ class GluonTrainStep:
         asked = repr((
             jax.__version__, chip.client.platform_version, chip.device_kind,
             tuple(self.mesh.shape.items()), type(self.block).__name__,
-            type(getattr(self._opt_update, "opt", None)).__name__,
+            type(self._rule.opt).__name__,
             str(self._compute_dtype),
             [p.name for p in self.trainable + self.aux],
             [(v.shape, str(v.dtype), str(shard.spec))
-             for tree, shards in zip(self._held, self._state_shard)
+             for tree, shards in zip(self._held, self._form.shards)
              for v, shard in zip(tree, shards)],
             x.shape, str(x.dtype), y.shape, str(y.dtype)))
         return os.path.join(cache, "mxtpu-step-orders-%s.json"
                             % hashlib.sha256(asked.encode()).hexdigest()[:32])
 
-    def _adopt_orders(self, x, y, rest):
-        """Once, at the first call: learn the compiler's order of every
-        state leaf (or read what an earlier process learned), transpose
-        the state into it and build the jitted step that takes and
-        returns it so."""
-        import jax
-
+    def _learned_orders(self, x, y, rest):
+        """The compiler's order of every state leaf: what an earlier
+        process learned, else learned now and kept."""
         path = self._orders_path(x, y)
         orders = path and _read_orders(path, self._held)
         if not orders:
             orders = self._compilers_orders(x, y, rest)
             if path:
                 _write_orders(path, orders)
-        self._orders = orders
+        return orders
+
+    def _adopt_orders(self, x, y, rest):
+        """Once, at the first call: settle the order every state leaf is
+        held in, transpose the state into it and build the jitted step
+        that takes and returns it so."""
+        import jax
+
+        shards = self._form.shards
+        self._orders = orders = self._form.orders(
+            self._held, functools.partial(self._learned_orders, x, y, rest))
         # one program moves the leaves whose order is not the model's
         moving = [(i, j) for i, tree in enumerate(orders)
                   for j, order in enumerate(tree) if not _is_models(order)]
@@ -617,28 +879,26 @@ class GluonTrainStep:
                     _in_order(v, orders[i][j])
                     for v, (i, j) in zip(leaves, moving)),
                 out_shardings=tuple(
-                    _shard_in_order(self._state_shard[i][j], orders[i][j])
+                    _shard_in_order(shards[i][j], orders[i][j])
                     for i, j in moving))(
                 *(self._held[i][j] for i, j in moving))))
             self._held = [
                 tuple(moved.get((i, j), v) for j, v in enumerate(tree))
                 for i, tree in enumerate(self._held)]
         self._relaid = len(moving)
-        _rts.inc("step_state_relayouts")
-        self._step = self._jit(self._step_py, 1)
+        self._step = self._jit()
 
-    def _jit(self, fn, n_tail):
-        """jit ``fn(train_vals, opt_state, aux_vals, x, y, key[, scalars])
-        -> (loss, train_vals, opt_state, aux_vals, *tail)`` on the state
-        as it is held: donated, each leaf transposed to the model's order
-        on the way in and back on the way out (free: the held order is
-        the order the compiler lays the leaf out in)."""
+    def _jit(self):
+        """The step jitted on the state as it is held: donated, each leaf
+        transposed to the model's order on the way in and back on the way
+        out (free: the held order is the order the compiler lays the leaf
+        out in; none is traced where the order is the model's)."""
         import jax
 
-        orders = self._orders
+        fn, orders = self._step_py, self._orders
         shards = tuple(
             tuple(_shard_in_order(s, o) for s, o in zip(tree, tree_orders))
-            for tree, tree_orders in zip(self._state_shard, orders))
+            for tree, tree_orders in zip(self._form.shards, orders))
 
         def reorder(one, state):
             return tuple(
@@ -658,225 +918,11 @@ class GluonTrainStep:
             # pin outputs to the input layouts: the functional state must
             # keep its sharding across steps (otherwise the compiler may
             # re-shard e.g. a bias, and step 2's in_shardings reject it)
-            out_shardings=(self._repl, *shards) + (self._repl,) * n_tail,
-            donate_argnums=(0, 1, 2),
-        )
-
-    # ------------------------------------------------- ZeRO sharded path
-    def _build_zero(self, pure_loss, cast, repl, x_shard, y_shard):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as _P
-
-        opt_update = self._opt_update
-        update = self._update
-        mesh = self.mesh
-        n = int(mesh.shape["dp"])
-        flat_shard = NamedSharding(mesh, _P("dp"))
-        self._flat_shard = flat_shard
-
-        layout = []
-        for p, v in zip(self.trainable, self.train_vals):
-            size = int(v.size)
-            layout.append({"name": p.name,
-                           "shape": tuple(int(s) for s in v.shape),
-                           "dtype": str(v.dtype), "size": size,
-                           "padded": _padded_size(size, n)})
-
-        def _flat_put(v, meta):
-            flat = _np.zeros((meta["padded"],), _np.dtype(meta["dtype"]))
-            flat[:meta["size"]] = _np.asarray(v).reshape(-1)
-            return jax.device_put(flat, flat_shard)
-
-        self.train_vals = tuple(
-            _flat_put(v, m) for v, m in zip(self.train_vals, layout))
-        self.aux_vals = _put(self.aux_vals, repl)
-
-        # optimizer state is BORN on the shard layout — a jitted zeros
-        # with sharded out_shardings allocates 1/n per device directly;
-        # the replicated full-size state never exists at any point
-        def _shard_zeros(padded, dtype):
-            return jax.jit(lambda: jnp.zeros((padded,), dtype),
-                           out_shardings=flat_shard)()
-
-        if opt_update is not None:
-            self.opt_state = opt_update.init_state(
-                lambda i, dt: _shard_zeros(layout[i]["padded"], dt))
-            leaves_per = [len(d) for d in opt_update.leaf_dtypes]
-            leaf_dtypes = [[str(d) for d in dts]
-                           for dts in opt_update.leaf_dtypes]
-        else:
-            self.opt_state = tuple(
-                _shard_zeros(m["padded"], _np.dtype(m["dtype"]))
-                for m in layout)
-            leaves_per = [1] * len(layout)
-            leaf_dtypes = [[m["dtype"]] for m in layout]
-
-        isz = [_np.dtype(m["dtype"]).itemsize for m in layout]
-        gather_bytes = sum(m["padded"] * s for m, s in zip(layout, isz))
-        self.zero_layout = {
-            "n": n,
-            "params": layout,
-            "state_leaves": leaves_per,
-            "state_dtypes": leaf_dtypes,
-            # logical collective payload per step: every param is
-            # gathered once for the forward and its grad reduced once
-            # into the shard layout
-            "per_step_allgather_bytes": gather_bytes,
-            "per_step_reduce_bytes": gather_bytes,
-            "replicated_param_bytes": sum(
-                m["size"] * s for m, s in zip(layout, isz)),
-            "per_device_param_bytes": sum(
-                m["padded"] // n * s for m, s in zip(layout, isz)),
-            "per_device_state_bytes": sum(
-                m["padded"] // n * s * l
-                for m, s, l in zip(layout, isz, leaves_per)),
-        }
-
-        sizes = [m["size"] for m in layout]
-        shapes = [m["shape"] for m in layout]
-        wsc = jax.lax.with_sharding_constraint
-
-        def fwd_bwd(train_flat, aux_vals, x, y, key):
-            def loss_of(tf):
-                # the param all-gather: constraining each flat shard to
-                # replicated makes GSPMD materialize the full value on
-                # every device inside this one program, overlapped with
-                # forward compute
-                with _xray.scope(_xray.REGION_ZERO_AG):
-                    tv = tuple(
-                        wsc(f, repl)[:size].reshape(shape)
-                        for f, size, shape in zip(tf, sizes, shapes))
-                if cast is not None:
-                    tv = tuple(v.astype(cast) if v.dtype == _np.float32
-                               else v for v in tv)
-                    x_ = _cast_floating(x, cast)
-                else:
-                    x_ = x
-                return pure_loss(tv, aux_vals, x_, y, key)
-
-            with _xray.scope(_xray.GRAD_MARKER):
-                (loss, new_aux), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(train_flat)
-            # norm over the still-replicated grads: identical reduction
-            # to the dp path's, so health trajectories match bit-exact
-            with _xray.scope(_xray.REGION_ZERO_GNORM):
-                gnorm = _global_grad_norm(grads)
-            # the reduce-scatter: the backward's dp-summed grads are
-            # constrained back to the 1/n flat layout — each device
-            # keeps only the shard its update needs (GSPMD may lower
-            # this as all-reduce + slice on backends without a fused
-            # reduce-scatter; the data movement is semantically the
-            # ZeRO reduce-scatter either way)
-            with _xray.scope(_xray.REGION_ZERO_RS):
-                grads = tuple(wsc(g.astype(f.dtype), flat_shard)
-                              for g, f in zip(grads, train_flat))
-            return loss, grads, new_aux, gnorm
-
-        if opt_update is None:
-            def step(train_flat, opt_flat, aux_vals, x, y, key):
-                loss, grads, new_aux, gnorm = fwd_bwd(
-                    train_flat, aux_vals, x, y, key)
-                # elementwise update on the 1/n shards (pads carry
-                # exact zeros through: zero grad -> zero update)
-                with _xray.scope(_xray.REGION_OPT):
-                    new_vals, new_state = update(train_flat, grads,
-                                                 opt_flat)
-                return loss, new_vals, new_state, new_aux, gnorm
-
-            sig_in = (flat_shard, flat_shard, repl, x_shard, y_shard,
-                      repl)
-        else:
-            def step(train_flat, opt_flat, aux_vals, x, y, key, scalars):
-                loss, grads, new_aux, gnorm = fwd_bwd(
-                    train_flat, aux_vals, x, y, key)
-                with _xray.scope(_xray.REGION_OPT):
-                    new_vals, new_state = opt_update.apply(
-                        train_flat, grads, opt_flat, scalars)
-                return loss, new_vals, new_state, new_aux, gnorm
-
-            sig_in = (flat_shard, flat_shard, repl, x_shard, y_shard,
-                      repl, repl)
-
-        self._step_py = step
-        self._step = jax.jit(
-            step,
-            in_shardings=sig_in,
-            out_shardings=(repl, flat_shard, flat_shard, repl, repl),
+            out_shardings=(self._repl, *shards, self._repl),
             donate_argnums=(0, 1, 2),
         )
 
     # --------------------------------------------------------- execution
-    def make_chained(self, n_steps):
-        """Jit n_steps training steps as ONE device computation.
-
-        One host dispatch covers the whole chain (lax.fori_loop carrying
-        the functional state), so per-call host overhead is paid
-        once per n_steps instead of once per step — the device-only
-        timing primitive bench.py's device metric is built on (the
-        same chaining trick as tools/bench_device_latency.py, extended
-        to the full fwd+bwd+update+BN-stat step).  The per-iteration RNG
-        key is fold_in(key, i), so chained(n) visits the same key
-        sequence regardless of chain depth.
-
-        The param/optimizer/aux carry is DONATED into the chain (like
-        the single-step path): without donation XLA must keep the
-        undonated inputs alive across the whole fori_loop, doubling
-        peak param+optimizer memory.  Donation invalidates the input
-        buffers, so the final carry is written back into this object's
-        state — chained(n) advances training exactly like n ``__call__``
-        steps (same fold_in key schedule) and repeat calls keep working.
-
-        Works in both layouts (the ZeRO chain carries the flat shards);
-        not with ``optimizer=``: its per-step scalars are refilled
-        host-side each step and cannot cross a fori_loop.
-
-        Returns fn(x, y, key) -> last_loss.
-        """
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        if self._opt_update is not None:
-            raise MXNetError(
-                "make_chained: per-step optimizer scalars (schedules, "
-                "bias corrections) are refilled host-side each step and "
-                "cannot cross a fori_loop chain; use optimizer=None "
-                "(the fused sgd-momentum closure) for chained "
-                "micro-benchmarks")
-
-        step = self._step_py
-
-        def chained(train_vals, opt_state, aux_vals, x, y, key):
-            def body(i, carry):
-                tv, os_, av, _ = carry
-                loss, tv, os_, av, _gn = step(tv, os_, av, x, y,
-                                              jax.random.fold_in(key, i))
-                # fp32 carry regardless of compute dtype (bf16 steps
-                # return a bf16 loss; the carry structure must be fixed)
-                return (tv, os_, av, loss.astype(jnp.float32))
-
-            init = (train_vals, opt_state, aux_vals,
-                    jnp.zeros((), jnp.float32))
-            tv, os_, av, loss = lax.fori_loop(0, n_steps, body, init)
-            return loss, tv, os_, av
-
-        def run(x, y, key):
-            if run._jitted is None:
-                # ZeRO: flat shards, a plain jit.  Else the carry is the
-                # state as it is held (class docstring)
-                if self._zero:
-                    run._jitted = jax.jit(chained, donate_argnums=(0, 1, 2))
-                else:
-                    if self._orders is None:
-                        self._adopt_orders(x, y, (key,))
-                    run._jitted = self._jit(chained, 0)
-            loss, *self._held = run._jitted(*self._held, x, y, key)
-            return loss
-
-        run._jitted = None  # from the first run on; donation introspection
-        return run
-
     def put_batch(self, x, y):
         """Place a host batch onto the mesh with the dp sharding."""
         import jax
@@ -896,10 +942,7 @@ class GluonTrainStep:
                     x, y = self.put_batch(x, y)
             with span("mxtpu.step.key"):
                 key = _random.next_key()
-            rest = [key]
-            if self._opt_update is not None:
-                with span("mxtpu.step.scalars"):
-                    rest.append(self._opt_update.host_scalars())
+            rest = (key, self._rule.host_scalars())
             if self._step is None:
                 self._adopt_orders(x, y, rest)
             args = [*self._held, x, y, *rest]
@@ -912,12 +955,7 @@ class GluonTrainStep:
             # the span (0.7-2.4 ms a step on the chip), not after it
             del args
             self.last_grad_norm = gnorm
-            if self._zero:
-                zl = self.zero_layout
-                _rts.inc("zero_steps")
-                _rts.inc("zero_allgather_bytes",
-                         zl["per_step_allgather_bytes"])
-                _rts.inc("zero_reduce_bytes", zl["per_step_reduce_bytes"])
+            self._form.after_step()
             if _health._state["on"]:
                 hm = _health.monitor()
                 if hm is not None:
@@ -927,12 +965,12 @@ class GluonTrainStep:
     def program_for(self, x, y):
         """The step compiled ahead of time for a batch like (x, y), for
         ``as_text()``, ``cost_analysis()`` and ``memory_analysis()``: the
-        program ``__call__`` runs for it, compiled once more."""
+        program ``__call__`` runs for it, compiled once more.  The one
+        place that stands in for the key and the rule's host scalars."""
         import jax
 
-        rest = [jax.random.PRNGKey(0)]  # shape/dtype stand-in only
-        if self._opt_update is not None:
-            rest.append(tuple(0.0 for _ in self._opt_update.slots))
+        # shape/dtype stand-ins only
+        rest = (jax.random.PRNGKey(0), tuple(0.0 for _ in self._rule.slots))
         if self._step is None:
             self._adopt_orders(x, y, rest)
         return self._step.lower(*self._held, x, y, *rest).compile()
@@ -947,153 +985,40 @@ class GluonTrainStep:
         unpadded and reshaped back to the parameter's shape."""
         import jax.numpy as jnp
 
-        if self._zero:
-            for p, v, m in zip(self.trainable, self.train_vals,
-                               self.zero_layout["params"]):
-                host = jnp.asarray(
-                    _np.asarray(v)[:m["size"]].reshape(m["shape"]))
-                for d in p._data:
-                    d._assign(host)
-        else:
-            for p, v in zip(self.trainable, self.train_vals):
-                host = jnp.asarray(_np.asarray(v))
-                for d in p._data:
-                    d._assign(host)
-        for p, v in zip(self.aux, self.aux_vals):
-            host = jnp.asarray(_np.asarray(v))
+        values = self._form.params_on_host(self.train_vals) \
+            + [_np.asarray(v) for v in self.aux_vals]
+        for p, v in zip(self.trainable + self.aux, values):
+            host = jnp.asarray(v)
             for d in p._data:
                 d._assign(host)
 
     # ------------------------------------------------ sharded checkpoint
-    def zero_shard_payloads(self):
-        """``{rank: payload}`` for every locally-addressable 'dp'
-        position — the per-rank shard files of a sharded checkpoint.
-        Each payload carries exactly the 1/n slice that rank owns
-        (params + optimizer-state leaves), so a rank never persists
-        another rank's bytes; in a multi-host run each process sees
-        only its own ranks here."""
-        if not self._zero:
+    def _flat_shards(self, asked):
+        if not isinstance(self._form, _FlatShards):
             raise MXNetError(
-                "zero_shard_payloads: this step was not built with "
-                "zero=True")
-        n = self.zero_layout["n"]
-        out = {}
+                "%s: this step was not built with zero=True" % asked)
+        return self._form
 
-        def collect(vals, kind):
-            for j, v in enumerate(vals):
-                shard_len = int(v.shape[0]) // n
-                for s in v.addressable_shards:
-                    rank = int(s.index[0].start or 0) // shard_len
-                    slot = out.setdefault(
-                        rank, {"params": {}, "state": {}})
-                    slot[kind][j] = _np.asarray(s.data)
+    @property
+    def zero_layout(self):
+        """The ZeRO layout and its per-step collective bytes."""
+        return self._form.zero_layout
 
-        collect(self.train_vals, "params")
-        collect(self.opt_state, "state")
-        return out
+    def zero_shard_payloads(self):
+        """``{rank: payload}``: the 1/n slice of parameters and optimizer
+        state each locally-addressable 'dp' rank owns."""
+        return self._flat_shards("zero_shard_payloads").shard_payloads(
+            self.train_vals, self.opt_state)
 
     def save_zero(self, step, mgr=None):
-        """Commit a sharded checkpoint: one global manifest over
-        per-rank shard files (``CheckpointManager.save_sharded`` — the
-        rank-0 commit barrier lives there), layout metadata in the
-        ``aux`` sideband so resume can re-shard."""
-        from .. import checkpoint as _ckpt
-
-        mgr = mgr if mgr is not None else _ckpt.manager()
-        if mgr is None:
-            raise MXNetError(
-                "save_zero: no checkpoint manager — call "
-                "checkpoint.enable(directory) first or pass mgr=")
-        n = self.zero_layout["n"]
-        files = {"zero-shard-%05d-of-%05d" % (r, n): payload
-                 for r, payload in self.zero_shard_payloads().items()}
-        aux = {"zero_layout": self.zero_layout}
-        if self._opt_update is not None:
-            # host-side optimizer hyper-state (update counts drive
-            # Adam-family bias correction; schedulers drive lr) — the
-            # device shards alone do not make the step resumable
-            aux["optimizer"] = _ckpt._strip_optimizer(
-                self._opt_update.opt)
-        return mgr.save_sharded(step, files, aux=aux)
+        """Commit a sharded checkpoint (``_FlatShards.save``)."""
+        return self._flat_shards("save_zero").save(
+            step, self.train_vals, self.opt_state, self._rule, mgr)
 
     def restore_zero(self, manifest, mgr=None):
         """Load a sharded checkpoint back into this step's flat shards,
-        RE-SHARDING when the checkpoint's dp width differs from the
-        current mesh (the layout-change resume path): each full flat
-        vector is rebuilt from the old ranks' slices, stripped of the
-        old padding, re-padded to the current multiple and placed onto
-        the current 'dp' layout.  Restores the RNG stream too; returns
-        the checkpoint step."""
-        import jax
-
-        from .. import checkpoint as _ckpt
-
-        if not self._zero:
-            raise MXNetError(
-                "restore_zero: this step was not built with zero=True")
-        mgr = mgr if mgr is not None else _ckpt.manager()
-        if mgr is None:
-            raise MXNetError("restore_zero: no checkpoint manager")
-        aux = mgr.load_aux(manifest)
-        if not aux or "zero_layout" not in aux:
-            raise MXNetError(
-                "restore_zero: checkpoint %s carries no zero_layout "
-                "sideband — not a sharded checkpoint"
-                % manifest.get("path"))
-        old = aux["zero_layout"]
-        ranks = mgr.load_shard_files(manifest)
-        if len(ranks) != old["n"]:
-            raise MXNetError(
-                "restore_zero: checkpoint %s has %d of %d rank shard "
-                "files" % (manifest.get("path"), len(ranks), old["n"]))
-        if old["state_leaves"] != self.zero_layout["state_leaves"]:
-            raise MXNetError(
-                "restore_zero: optimizer state structure changed "
-                "(%r leaves saved vs %r now) — restore with the same "
-                "optimizer family"
-                % (old["state_leaves"], self.zero_layout["state_leaves"]))
-
-        def rebuild(kind, j, meta_old, meta_new, dtype):
-            full = _np.concatenate(
-                [ranks[r][kind][j] for r in range(old["n"])])
-            flat = _np.zeros((meta_new["padded"],), dtype)
-            flat[:meta_new["size"]] = full[:meta_old["size"]]
-            return jax.device_put(flat, self._flat_shard)
-
-        new_params = []
-        for j, (mo, mn) in enumerate(zip(old["params"],
-                                         self.zero_layout["params"])):
-            if (mo["name"], mo["size"]) != (mn["name"], mn["size"]):
-                raise MXNetError(
-                    "restore_zero: parameter %d mismatch (%s/%d saved "
-                    "vs %s/%d now) — the model changed"
-                    % (j, mo["name"], mo["size"], mn["name"], mn["size"]))
-            new_params.append(
-                rebuild("params", j, mo, mn, _np.dtype(mn["dtype"])))
-        self.train_vals = tuple(new_params)
-
-        new_state = []
-        leaf = 0
-        for i, count in enumerate(self.zero_layout["state_leaves"]):
-            mo, mn = old["params"][i], self.zero_layout["params"][i]
-            for c in range(count):
-                dt = _np.dtype(self.zero_layout["state_dtypes"][i][c])
-                new_state.append(rebuild("state", leaf, mo, mn, dt))
-                leaf += 1
-        self.opt_state = tuple(new_state)
-        blob = aux.get("optimizer")
-        if blob is not None and self._opt_update is not None:
-            import pickle
-
-            src = pickle.loads(blob)
-            hyper = dict(src.__dict__)
-            hyper.pop("param_dict", None)
-            self._opt_update.opt.__dict__.update(hyper)
-        rng = manifest.get("rng")
-        if rng:
-            _random.set_state(rng)
-        return int(manifest.get("step", 0))
-
-
-#: ISSUE-14 spelling: ``GluonStep(..., zero=True)``
-GluonStep = GluonTrainStep
+        re-sharding when its dp width differs from the current mesh
+        (``_FlatShards.restore``); returns the checkpoint step."""
+        step, self.train_vals, self.opt_state = self._flat_shards(
+            "restore_zero").restore(manifest, self._rule, mgr)
+        return step

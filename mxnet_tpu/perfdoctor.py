@@ -20,8 +20,8 @@ Rules
   compile share of step time.
 - **eager dispatch tax** — warm per-op dispatch (+ compile) dominating
   an eager run's step time: recommends the compiled whole-step path
-  (``MXNET_TPU_COMPILED_STEP`` / ``trainer.compile``) with projected
-  savings derived from the warm-dispatch counters.
+  (``trainer.compile``) with projected savings derived from the
+  warm-dispatch counters.
 - **host-sync stalls** — monitor/health host-sync seconds on the hot
   path (the deliberate sync sinks, when their cost stops being small).
 - **idle gaps inside steps** — wall time inside ``trainer:step`` spans
@@ -285,7 +285,7 @@ def _check_recompiles(dump):
 def _check_eager_dispatch(dump):
     """Eager per-op dispatch tax: warm dispatch (+ compile) dominating
     the step while the run never used the compiled whole-step path —
-    the exact profile ``MXNET_TPU_COMPILED_STEP`` exists for
+    the exact profile ``trainer.compile`` exists for
     (compiled_step.py: fwd+bwd+update traced into ONE donated XLA
     program, ~1 warm dispatch per step instead of one per op).
     Projected savings derive from the warm-dispatch counters: of the
@@ -326,8 +326,7 @@ def _check_eager_dispatch(dump):
          "compile share %.0f%% also amortizes to one program per "
          "input signature under the compiled step" % (comp * 100)],
         "train through the fused whole-step program: "
-        "cs = trainer.compile(net, loss); cs.step(x, y) — or set "
-        "MXNET_TPU_COMPILED_STEP=1 where the launch wiring honors it "
+        "cs = trainer.compile(net, loss); cs.step(x, y) "
         "(docs/COMPILED_STEP.md); the eager path remains the "
         "debugging/interop mode")]
 
@@ -418,9 +417,9 @@ def _check_roofline(dump, top=3):
         "%d op(s) far above their roofline bound, worst %r"
         % (len(culprits), worst["op"]),
         worst["op"], evidence,
-        "these are cache-warm HOST dispatch rates — confirm with the "
-        "measured device trace (tools/profile_step.py), then fuse/"
-        "batch the op or fix its layout")]
+        "these are cache-warm HOST dispatch rates — confirm with a "
+        "measured device trace (a benchmark cell's `--trace 1` run), "
+        "then fuse/batch the op or fix its layout")]
 
 
 def _check_stragglers(dump):

@@ -27,7 +27,7 @@ Memory & cost analytics (PR 3): ``snapshot()`` additionally carries a
 a ``costs`` section (per-op XLA cost/memory analysis captured at
 compile time by ``ops/registry.py``), and :func:`roofline` derives
 achieved GB/s / GFLOP/s per op from profiled dispatch wall-time — the
-in-production analog of the offline ``tools/profile_step.py`` audit.
+in-production analog of an offline device-trace audit.
 :func:`dump_diag` writes the whole picture atomically to a JSON file;
 ``MXNET_TPU_DIAG=<file>`` arms a ``SIGUSR1`` handler (plus an atexit
 dump) so a live training job can be asked for it at any time, and
@@ -94,10 +94,9 @@ STORM_WARN_INTERVAL = float(os.environ.get(
 # but no rates.  Import-time, like the rest of the DIAG arming.
 DIAG_TIMING = bool(os.environ.get("MXNET_TPU_DIAG"))
 
-# THE table of published per-chip peaks, keyed by jax's ``device_kind``
-# (tools/profile_step.py imports it).  A device that is not here has no
-# roofline: ``device_peaks`` raises and the headroom columns are left
-# out — never a default.  Source: Google Cloud documentation, "TPU v5e"
+# THE table of published per-chip peaks, keyed by jax's ``device_kind``.
+# A device that is not here has no roofline: ``device_peaks`` raises and
+# the headroom columns are left out — never a default.  Source: Google Cloud documentation, "TPU v5e"
 # (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16 (394 is the int8
 # figure), 16 GB HBM2e at 819 GB/s per chip.
 DEVICE_PEAKS = {
@@ -217,8 +216,8 @@ def add_dispatch_seconds(name, seconds):
     HOST wall-time of the dispatch call: on a synchronous backend (CPU
     tests) it tracks execution, but async device dispatch returns
     early, so the derived rates are cache-warm dispatch diagnostics,
-    not physics — the measured-trace audit (tools/profile_step.py)
-    stays the ground-truth instrument.  When latency histograms are on
+    not physics — a measured device trace (the benchmark's ``--trace
+    1`` run) stays the ground-truth instrument.  When latency histograms are on
     the sample additionally lands in the ``dispatch:warm``
     distribution."""
     s = _op_stats(name)

@@ -41,9 +41,9 @@ millions-of-users north star.  This module is the serving layer:
   one dict read per seam when disabled (docs/OBSERVABILITY.md
   "Request x-ray & SLOs").
 
-Bench: ``tools/loadgen.py`` (open-loop Poisson arrivals, p50/p99/p99.9
-vs offered QPS, serial-`Predictor.forward` baseline) — also reachable
-as ``python bench.py --serve``.  Doctor rules: ``perfdoctor``'s
+No cell of the benchmark measures it yet; the open-loop generator a
+serving cell will use is ``benchmark/harness/loadgen.py`` (PERF.md §7).
+Doctor rules: ``perfdoctor``'s
 ``serve-queue-dominated`` / ``serve-bucket-churn``; section rendering:
 ``tools/diagnose.py --serving``.  Docs: docs/SERVING.md.
 

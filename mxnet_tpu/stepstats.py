@@ -11,7 +11,7 @@ never silently over-claims (arXiv:2301.13062's fusion/idle-gap lens,
 applied host-side).
 
 Phases (:data:`PHASES`; shared vocabulary with ``tools/diagnose.py
---doctor`` and ``tools/profile_step.py`` — same names, ms units):
+--doctor`` — same names, ms units):
 
 - ``data_wait``        ``DataIter.__next__`` (batch assembly / input wait)
 - ``forward``          the ``autograd.record()`` region / symbolic
@@ -71,9 +71,9 @@ __all__ = ["PHASES", "PHASE_LABELS", "enable", "disable", "is_enabled",
            "device_anatomy_ms", "render", "reset"]
 
 # canonical phase vocabulary, in render order.  The perf doctor
-# (tools/diagnose.py --doctor), runtime_stats.compare, and
-# tools/profile_step.py all name phases from this table so a finding,
-# a diff row, and a measured-trace column agree on names and units.
+# (tools/diagnose.py --doctor) and runtime_stats.compare both name
+# phases from this table so a finding and a diff row agree on names
+# and units.
 PHASES = ("data_wait", "forward", "backward", "dispatch_warm", "compile",
           "compiled_step", "kvstore", "optimizer_update",
           "checkpoint_write", "health_drain")
@@ -89,7 +89,7 @@ PHASE_LABELS = {
     "optimizer_update": "optimizer update",
     "checkpoint_write": "checkpoint snapshot",
     "health_drain": "health drain",
-    # device-trace phases (tools/profile_step.py's measured anatomy)
+    # device-trace phases (device_anatomy_ms)
     "device_compute": "device compute (HLO)",
     "hbm_prefetch": "HBM prefetch (overlapped)",
     "unattributed": "unattributed remainder",
@@ -269,9 +269,9 @@ def anatomy(snap=None):
 
 
 def device_anatomy_ms(step_wall_ms, phases_ms):
-    """Shape a measured device-trace breakdown (``tools/profile_step.py``)
-    into the same anatomy structure the host-side phases use: ``{
-    "step_wall_ms", "phases_ms": {phase: ms}, "unattributed_ms"}`` with
+    """Shape a measured device-trace breakdown into the same anatomy
+    structure the host-side phases use: ``{"step_wall_ms",
+    "phases_ms": {phase: ms}, "unattributed_ms"}`` with
     the explicit-remainder convention (``unattributed`` clamped to 0;
     when async device phases overlap the wall and sum past it, the
     excess is reported as ``overlap_ms`` instead of being hidden).

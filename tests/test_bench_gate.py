@@ -1,45 +1,15 @@
-"""bench.py's device gate and measurement primitives, plus the
+"""The no-fallback accelerator context and device provisioning, plus the
 pay-for-use bounds of every telemetry layer.
 
-A measurement path that finds no chip fails; it never falls back to the
-CPU and never prints a verdict from one.  These tests pin that gate
-(``require_chip``), the no-fallback accelerator context, and the
-correctness of the chained measurement
-primitive (GluonTrainStep.make_chained) the device metric is produced
-by.
+A path that names a chip and finds none fails; it never falls back to
+the CPU.  (The benchmark's own refusal of anything but a TPU is pinned
+in ``tests/benchmark/test_harness.py``.)
 """
 
 import importlib.util
 import os
 
-import numpy as np
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_no_chip_exits_nonzero(monkeypatch):
-    """Every bench mode starts at require_chip(): on a platform that is
-    not a TPU it exits non-zero with one line — unless the CPU was
-    asked for explicitly, which runs the checks and returns False (no
-    verdict)."""
-    import pytest
-
-    bench = _load_bench()
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(SystemExit) as e:
-        bench.require_chip()
-    assert isinstance(e.value.code, str) and "not 'tpu'" in e.value.code
-    assert "\n" not in e.value.code
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench.require_chip() is False
 
 
 def test_accelerator_context_without_a_chip_raises():
@@ -58,51 +28,6 @@ def test_accelerator_context_without_a_chip_raises():
             ctx.jax_device
     with pytest.raises(MXNetError):
         mx.nd.ones((2,), ctx=mx.tpu())
-
-
-def test_make_chained_matches_sequential_steps():
-    """chained(n) must compute the same loss trajectory as n sequential
-    _step calls with the same fold_in key schedule — the measurement
-    primitive must measure the real training computation.  The carry is
-    DONATED and written back (tests/test_compiled_step.py pins the
-    donation), so the chain also ADVANCES the step state like n
-    __call__ steps."""
-    import jax
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import gluon
-    from mxnet_tpu.gluon import nn
-    from mxnet_tpu.parallel.gluon_step import GluonTrainStep
-    from mxnet_tpu.parallel.mesh import create_mesh
-
-    mesh = create_mesh({"dp": 1}, devices=jax.devices("cpu")[:1])
-    net = nn.Dense(4)
-    net.initialize(ctx=mx.cpu())
-    net(mx.nd.zeros((1, 6), ctx=mx.cpu()))
-    loss = gluon.loss.SoftmaxCrossEntropyLoss()
-    step = GluonTrainStep(net, loss, mesh=mesh, lr=0.1, momentum=0.9)
-
-    rs = np.random.RandomState(0)
-    x = rs.rand(8, 6).astype(np.float32)
-    y = rs.randint(0, 4, (8,)).astype(np.int32)
-    x, y = step.put_batch(x, y)
-    key = jax.random.PRNGKey(7)
-
-    # reference trajectory: the un-jitted step fn, eagerly, same keys
-    tv, os_, av = step.train_vals, step.opt_state, step.aux_vals
-    for i in range(3):
-        want, tv, os_, av, _gn = step._step_py(tv, os_, av, x, y,
-                                               jax.random.fold_in(key, i))
-
-    orig_train_vals = step.train_vals
-    got = step.make_chained(3)(x, y, key)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-6)
-    # the donated carry was written back: the chain advanced training
-    assert step.train_vals is not orig_train_vals
-    for new, ref in zip(step.train_vals, tv):
-        np.testing.assert_allclose(np.asarray(new), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-6)
 
 
 def test_provision_devices_never_probes_in_a_child(monkeypatch):

@@ -47,13 +47,18 @@ def _dense_net():
     return net
 
 
-def test_dp_step_contains_gradient_allreduce():
+@pytest.mark.parametrize("rule", [{"lr": 0.1}, {"optimizer": "adam"}],
+                         ids=["fused_sgd", "optimizer"])
+def test_dp_step_contains_gradient_allreduce(rule):
     """Data parallelism = GSPMD inserts an all-reduce for the gradient
-    sync (the reference's KVStore push/pull, riding ICI here)."""
+    sync (the reference's KVStore push/pull, riding ICI here), whichever
+    rule then updates the replicated parameters."""
     mesh = create_mesh({"dp": 8})
     net = _dense_net()
+    if "optimizer" in rule:
+        rule = {"optimizer": mx.optimizer.create(rule["optimizer"])}
     step = GluonTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                          mesh=mesh, lr=0.1)
+                          mesh=mesh, **rule)
     x, y = step.put_batch(np.random.rand(16, D).astype(np.float32),
                           np.zeros((16,), np.int32))
     hlo = _step_hlo(step, x, y)

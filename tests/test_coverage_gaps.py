@@ -52,35 +52,6 @@ def test_rtc_cuda_module_errors_pallas_module_runs():
     np.testing.assert_allclose(out.asnumpy(), np.arange(8) * 2.0)
 
 
-def test_make_train_step_data_parallel_mesh():
-    """parallel.data_parallel.make_train_step: pure loss_fn + update on
-    an 8-device dp mesh; loss decreases and params stay replicated."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.parallel.data_parallel import make_train_step
-    from mxnet_tpu.parallel.mesh import create_mesh
-
-    mesh = create_mesh({"dp": 8})
-    rs = np.random.RandomState(0)
-    params = {"w": jnp.asarray(rs.randn(4, 3).astype(np.float32))}
-    xb = jnp.asarray(rs.rand(16, 4).astype(np.float32))
-    yb = jnp.asarray(rs.rand(16, 3).astype(np.float32))
-
-    def loss_fn(p, batch):
-        x, y = batch
-        return ((x @ p["w"] - y) ** 2).mean()
-
-    def update(p, g, s):
-        return jax.tree_util.tree_map(lambda w, d: w - 0.1 * d, p, g), s
-
-    step = make_train_step(loss_fn, update, mesh)
-    l1, params, _ = step(params, None, (xb, yb))
-    l2, params, _ = step(params, None, (xb, yb))
-    assert float(l2) < float(l1)
-    assert params["w"].addressable_shards[0].data.size == 12  # replicated
-
-
 def test_transformer_encoder_trains_in_process():
     """gluon.nn.transformer: encoder stack forward + one backward step
     in-process (previously exercised only in the dryrun subprocess)."""

@@ -1060,13 +1060,12 @@ def test_donation_pinned_capture_and_rebinds_silent():
 
 
 def test_donation_sites_cover_all_three_jit_wrappers():
-    """The repo's three donate_argnums sites are all discovered."""
+    """The repo's donate_argnums sites are all discovered."""
     from tools.mxlint.checkers import _FileCtx
     from tools.mxlint.donation import find_donation_sites
 
     expected = {"mxnet_tpu/compiled_step.py",
-                "mxnet_tpu/parallel/gluon_step.py",
-                "mxnet_tpu/parallel/data_parallel.py"}
+                "mxnet_tpu/parallel/gluon_step.py"}
     ctxs = []
     for rel in sorted(expected):
         with open(os.path.join(REPO, rel), encoding="utf-8") as f:

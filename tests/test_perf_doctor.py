@@ -537,45 +537,3 @@ def test_cli_doctor_json_output(tmp_path):
     assert isinstance(findings, list) and findings
     assert {"rule", "severity", "score", "title", "anchor", "evidence",
             "action"} <= set(findings[0])
-
-
-# ------------------------------------------- profile_step anatomy wiring
-
-
-def test_profile_step_summary_uses_shared_anatomy(tmp_path):
-    """tools/profile_step.py --parse-only emits a step_anatomy section
-    in the stepstats shape (same names/units as the doctor)."""
-    import gzip
-
-    trace = {"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 1,
-         "args": {"name": "XLA Ops"}},
-        {"ph": "X", "name": "jit_step", "pid": 1, "tid": 1,
-         "ts": 0, "dur": 1000},
-        {"ph": "X", "name": "fusion.1", "pid": 1, "tid": 1, "ts": 0,
-         "dur": 700,
-         "args": {"long_name": "f32[128,64]{1,0} fusion",
-                  "bytes_accessed": 32768, "model_flops": 1000}},
-    ]}
-    path = tmp_path / "t.trace.json.gz"
-    with gzip.open(path, "wt") as f:
-        json.dump(trace, f)
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import profile_step
-        summary, _rows = profile_step.main(
-            ["--parse-only", str(path), "--steps", "1", "--top", "5",
-             "--device-kind", "TPU v5 lite"])
-        # the trace's chip must be named: this process's device ("cpu")
-        # has no published peaks, and that is an error, not a default
-        with pytest.raises(KeyError, match="no published peaks"):
-            profile_step.main(["--parse-only", str(path)])
-    finally:
-        sys.path.remove(os.path.join(REPO, "tools"))
-    anat = summary["step_anatomy"]
-    assert anat["step_wall_ms"] == pytest.approx(1.0)
-    assert anat["phases_ms"]["device_compute"] == pytest.approx(0.7)
-    assert anat["unattributed_ms"] == pytest.approx(0.3)
-    assert "device_compute" in stepstats.PHASE_LABELS

@@ -9,8 +9,7 @@ fire on burning traffic and stay quiet on healthy traffic (and under
 MIN_EVENTS), the ``slo-shed`` autopilot reflex respects its
 off/dry-run/armed gate and its knob bounds, ``--compare`` treats a
 one-sided objective as a note and a burn increase as a regression,
-the loadgen exports a latency CDF + SLO verdict, and the end-to-end
-drill (induced slow tail + one injected NaN through a real
+and the end-to-end drill (induced slow tail + one injected NaN through a real
 ``InferenceServer``) produces the retained ring, a merged chrome
 trace with cross-thread flow events, and a ``diagnose.py --slo``
 rendering with window evidence from a diag dump.
@@ -325,40 +324,6 @@ def test_compare_slo_burn_regression_and_one_sided_note():
              if e["metric"] == "slo:e2e budget_burned"]
     assert len(notes) == 1 and notes[0]["side"] == "after-only"
     assert "SLO objectives differ" in runtime_stats.render_compare(res2)
-
-
-# ------------------------------------------------------------ loadgen
-
-
-def _load_loadgen():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "loadgen", os.path.join(REPO, "tools", "loadgen.py"))
-    loadgen = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(loadgen)
-    return loadgen
-
-
-def test_loadgen_cdf_and_slo_verdict():
-    loadgen = _load_loadgen()
-    cdf = loadgen._latency_cdf([0.001 * i for i in range(1, 101)])
-    assert cdf["max"] == pytest.approx(100.0)
-    assert cdf["p50"] <= cdf["p90"] <= cdf["p99"] <= cdf["p99.9"]
-    assert cdf["p99.9"] <= cdf["max"]
-    assert loadgen._latency_cdf([]) is None
-    # verdict: objective missed, budget burned 2x
-    assert loadgen.slo_verdict() is None
-    assert slo.enable(spec="e2e:50ms:90", scale=1.0)
-    for i in range(100):
-        slo.on_request(100.0 if i < 20 else 1.0, True)
-    verdict = loadgen.slo_verdict()
-    assert len(verdict) == 1
-    v = verdict[0]
-    assert v["objective"] == "e2e" and v["events"] == 100
-    assert v["achieved"] == pytest.approx(0.80)
-    assert v["budget_burned"] == pytest.approx(2.0)
-    assert v["met"] is False
 
 
 # ----------------------------------------------------------- e2e drill

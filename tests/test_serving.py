@@ -5,8 +5,7 @@ of the unbatched Predictor and padding never bleeds into results,
 concurrent clients get their own answers, the NaN sentinel rejects (one
 rate-limited warning, never a silent bad payload), shutdown drains,
 the serve:* telemetry reaches histograms / Prometheus / diag dumps /
---compare / the perf doctor, and the open-loop loadgen smoke holds a
-p99-vs-serial ordering.  Docs: docs/SERVING.md.
+--compare / the perf doctor.  Docs: docs/SERVING.md.
 """
 
 import json
@@ -520,95 +519,3 @@ def test_perfdoctor_serve_bucket_churn():
     worst["snapshot"]["counters"]["serve_batches"] = 20
     fired = perfdoctor.diagnose(dump=worst)
     assert "serve-bucket-churn" in {f["rule"] for f in fired}
-
-
-# -------------------------------------------------------------- loadgen
-
-
-def _load_loadgen():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "loadgen", os.path.join(REPO, "tools", "loadgen.py"))
-    loadgen = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(loadgen)
-    return loadgen
-
-
-def test_trend_doctor_throughput_is_load_aware(tmp_path):
-    """The soak gate's throughput verdict must survive a loaded CI box:
-    the mean-window perf-doctor rule fires on a couple of
-    scheduler-jitter batches, so trend_doctor only keeps it when a
-    median-window recheck over enough samples confirms sustained decay
-    (was the test_loadgen_open_loop_smoke flake)."""
-    from mxnet_tpu import perfdoctor
-
-    loadgen = _load_loadgen()
-    path = str(tmp_path / "soak.jsonl")
-
-    def write(walls):
-        with open(path, "w") as f:
-            for i, w in enumerate(walls):
-                f.write(json.dumps({"step": i, "wall_ms": w}) + "\n")
-
-    # two jitter-slowed batches in a short soak: the raw rule fires,
-    # the confirmation (too few samples; medians flat) drops it
-    jitter = [5.0] * 10 + [55.0, 5.0]
-    write(jitter)
-    raw = perfdoctor.diagnose(
-        timeline=[{"step": i, "wall_ms": w} for i, w in enumerate(jitter)])
-    assert "timeline-throughput" in {f["rule"] for f in raw}
-    assert loadgen.trend_doctor(path) == []  # dropped, NOT None
-    # genuine sustained decay over enough samples stays a finding
-    write([5.0] * 12 + [20.0] * 12)
-    kept = loadgen.trend_doctor(path)
-    assert [f["rule"] for f in kept] == ["timeline-throughput"]
-    # sub-floor micro-batch noise never fires regardless of ratio
-    write([0.5] * 12 + [1.9] * 12)
-    assert loadgen.trend_doctor(path) == []
-
-
-def test_trend_doctor_keeps_leak_findings_unfiltered(tmp_path):
-    """A leak slope is monotonic, not jitter — the load-aware guard
-    must not swallow it even on a short timeline."""
-    loadgen = _load_loadgen()
-    path = str(tmp_path / "leak.jsonl")
-    with open(path, "w") as f:
-        for i in range(10):
-            f.write(json.dumps({"step": i, "wall_ms": 5.0,
-                                "live_bytes": 1_000_000 + i * 500_000})
-                    + "\n")
-    kept = loadgen.trend_doctor(path)
-    assert [f["rule"] for f in kept] == ["timeline-leak"]
-
-
-def test_loadgen_open_loop_smoke(tmp_path):
-    """Open-loop loadgen end-to-end: the server sustains more than the
-    serial rate, and at that same offered load its p99 beats the
-    one-at-a-time serial replay (the continuous-batching claim).  Kept
-    small — the real sweep is ``python bench.py --serve``."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "loadgen", os.path.join(REPO, "tools", "loadgen.py"))
-    loadgen = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(loadgen)
-
-    metrics = str(tmp_path / "serve_soak.jsonl")
-    pred, shape = loadgen.build_demo_predictor()
-    serial = loadgen.serial_baseline(pred, shape, n_requests=60)
-    report = loadgen.sweep(
-        qps_levels=[serial["qps"] * 1.5, serial["qps"] * 3.0],
-        duration=0.5, serial_requests=60, metrics_path=metrics,
-        model=(pred, shape))
-    assert report["serial"]["qps"] > 0
-    assert report["max_sustained_qps"] is not None, \
-        "no offered level was sustained: %s" % report["levels"]
-    assert report["speedup_vs_serial"] > 1.0
-    # the p99-vs-serial assertion: at the SAME offered load the
-    # one-at-a-time replay's p99 must not beat continuous batching
-    assert report["p99_vs_serial_at_load"] is not None
-    assert report["p99_vs_serial_at_load"] <= 1.0
-    # the soak ran, produced a timeline, and the trend doctor gated it
-    assert os.path.exists(metrics)
-    assert report["soak_clean"] is True, report["trend_doctor_findings"]

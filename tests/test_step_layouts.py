@@ -16,6 +16,12 @@ from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
 
+FORMS = pytest.mark.parametrize("zero", [False, True],
+                                ids=["in_order", "flat_shards"])
+RULES = pytest.mark.parametrize("with_optimizer", [False, True],
+                                ids=["fused_sgd", "optimizer"])
+
+
 def _net(prefix):
     mx.random.seed(7)
     net = nn.HybridSequential(prefix=prefix)
@@ -71,7 +77,7 @@ def _three_steps(step, x, y):
     return [np.asarray(step(x, y)) for _ in range(3)]
 
 
-def _default_layout_steps(plain, x, y, with_optimizer):
+def _default_layout_steps(plain, x, y):
     """Three steps of ``_step_py`` under a plain ``jax.jit``, the state
     as the model has it: -> (losses, final state)."""
     import jax
@@ -83,10 +89,8 @@ def _default_layout_steps(plain, x, y, with_optimizer):
     state = (plain.train_vals, plain.opt_state, plain.aux_vals)
     losses = []
     for _ in range(3):
-        rest = [mxrandom.next_key()]
-        if with_optimizer:
-            rest.append(plain._opt_update.host_scalars())
-        loss, *state, _gnorm = default(*state, x, y, *rest)
+        loss, *state, _gnorm = default(
+            *state, x, y, mxrandom.next_key(), plain._rule.host_scalars())
         losses.append(np.asarray(loss))
     return losses, jax.tree.leaves(state)
 
@@ -95,8 +99,7 @@ def _read(step):
     return list(step.train_vals + step.opt_state + step.aux_vals)
 
 
-@pytest.mark.parametrize("with_optimizer", [False, True],
-                         ids=["fused_sgd", "optimizer"])
+@RULES
 def test_the_compilers_orders_and_bit_for_bit_the_default_step(
         with_optimizer):
     """The ahead-of-time compile with ``Layout.AUTO`` answers for every
@@ -114,7 +117,7 @@ def test_the_compilers_orders_and_bit_for_bit_the_default_step(
             == [list(range(v.ndim)) for v in tree]
     assert step._relaid == sum(
         o != tuple(range(len(o))) for tree in step._orders for o in tree)
-    want, state = _default_layout_steps(plain, x, y, with_optimizer)
+    want, state = _default_layout_steps(plain, x, y)
     if step._relaid == 0:
         assert all(np.array_equal(a, b) for a, b in zip(got, want))
         assert all(np.array_equal(np.asarray(a), np.asarray(b))
@@ -123,8 +126,7 @@ def test_the_compilers_orders_and_bit_for_bit_the_default_step(
         np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-@pytest.mark.parametrize("with_optimizer", [False, True],
-                         ids=["fused_sgd", "optimizer"])
+@RULES
 def test_the_state_moves_once_and_reads_in_the_models_shapes(
         minor_first, with_optimizer):
     net = _net("lay%d_" % with_optimizer)
@@ -140,7 +142,7 @@ def test_the_state_moves_once_and_reads_in_the_models_shapes(
         == [s[::-1] for s in shapes]
     assert [v.shape for v in _read(step)] == shapes
     assert step._relaid == sum(len(s) > 1 for s in shapes) > 0
-    want, state = _default_layout_steps(plain, x, y, with_optimizer)
+    want, state = _default_layout_steps(plain, x, y)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     if not with_optimizer:
         # (Adam turns the rounding noise of a gradient that is zero, the
@@ -174,7 +176,7 @@ def test_a_sharded_leaf_keeps_its_axes_in_the_held_order(minor_first):
     plain = _step(net, mesh={"dp": 2, "tp": 2}, param_spec_fn=spec)
     x, y = step.put_batch(*_batch())
     got = _three_steps(step, x, y)
-    want, _ = _default_layout_steps(plain, x, y, False)
+    want, _ = _default_layout_steps(plain, x, y)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for tree in step._held[:2]:
         dense = [v for p, v in zip(step.trainable, tree)
@@ -217,47 +219,71 @@ def test_what_was_learned_is_kept_beside_the_compile_cache(
         _step(net)(x, y)
 
 
-def test_sync_to_params_and_the_chain_round_trip(minor_first):
-    import jax
+def test_the_orders_one_rule_learned_are_not_the_other_rules(
+        minor_first, monkeypatch, tmp_path):
+    """The kept answer is named after the rule too: a step with another
+    rule asks the compiler itself and keeps its own file."""
+    from mxnet_tpu.parallel import gluon_step
 
-    net = _net("rt_")
-    step = _step(net, mesh={"dp": 1})
-    x, y = step.put_batch(*_batch())
-    key = jax.random.PRNGKey(3)
+    monkeypatch.setattr(gluon_step, "_orders_dir", lambda: str(tmp_path))
+    net = _net("rule_")
+    x, y = _batch()
+    _step(net)(x, y)
+    fused, = tmp_path.iterdir()
+    asked = []
+    learn = GluonTrainStep._compilers_orders
+
+    def counted(self, x, y, rest):
+        asked.append(type(self._rule.opt).__name__)
+        return learn(self, x, y, rest)
+
+    monkeypatch.setattr(GluonTrainStep, "_compilers_orders", counted)
+    _step(net, with_optimizer=True)(x, y)
+    assert asked == ["Adam"]
+    assert len(list(tmp_path.iterdir())) == 2
+    _step(net)(x, y)
+    _step(net, with_optimizer=True)(x, y)
+    assert asked == ["Adam"]            # both read their own file now
+    assert fused in tmp_path.iterdir()
+
+
+@FORMS
+@RULES
+def test_sync_to_params_round_trip(minor_first, zero, with_optimizer):
+    """Whatever form holds the state and whatever rule updates it, the
+    parameters come back in the model's shapes with the step's values."""
+    net = _net("rt%d%d_" % (zero, with_optimizer))
+    step = _step(net, with_optimizer, zero=zero)
+    plain = _step(net, with_optimizer)
+    shapes = [p.data().shape for p in step.trainable + step.aux]
+    x, y = _batch()
     before = _relayouts()
-
-    # the chain is the first to run: it learns the orders and moves the
-    # state, the step's own program takes the state as the chain left it
-    run = step.make_chained(2)
-    tv, os_, av = step.train_vals, step.opt_state, step.aux_vals
-    for i in range(2):
-        want, tv, os_, av, _gn = step._step_py(
-            tv, os_, av, x, y, jax.random.fold_in(key, i))
-    got = run(x, y, key)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-6)
-    for new, ref in zip(step.train_vals, tv):
-        np.testing.assert_allclose(np.asarray(new), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-6)
+    got = _three_steps(step, x, y)
+    want, state = _default_layout_steps(plain, *plain.put_batch(x, y))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert _relayouts() - before == (0 if zero else 1)
     held = step._held[0][0]
-    assert held.shape == (3, 3, 3, 8)
+    assert held.shape == ((3 * 3 * 3 * 8,) if zero else (3, 3, 3, 8))
     step(x, y)
     assert held.is_deleted()        # donated by the step, as it was held
-    run(x, y, key)
-    step(x, y)
-    assert _relayouts() - before == 1
 
     step.sync_to_params()
-    for p, v in zip(step.trainable + step.aux,
-                    step.train_vals + step.aux_vals):
-        assert p.data().shape == v.shape
-        assert np.array_equal(p.data().asnumpy(), np.asarray(v))
+    synced = [p.data() for p in step.trainable + step.aux]
+    assert [v.shape for v in synced] == shapes
+    if zero:
+        # a flat shard, unpadded and in the parameter's shape
+        for p, flat in zip(synced, step.train_vals):
+            assert np.array_equal(p.asnumpy().ravel(),
+                                  np.asarray(flat)[:p.size])
+    else:
+        for p, v in zip(synced, step.train_vals + step.aux_vals):
+            assert np.array_equal(p.asnumpy(), np.asarray(v))
     # and the parameters feed the eager API as ever
-    assert net(mx.nd.array(np.asarray(x))).shape == (8, 4)
+    assert net(mx.nd.array(x)).shape == (8, 4)
 
-    # assigned in the model's shapes, kept as held
+    # assigned as they read, kept as held
     step.train_vals = [np.asarray(v) * 0 for v in step.train_vals]
-    assert step._held[0][0].shape == (3, 3, 3, 8)
+    assert step._held[0][0].shape == held.shape
     assert not np.asarray(step.train_vals[0]).any()
 
 
@@ -276,18 +302,60 @@ def test_the_launch_span_carries_relaid_leaves(minor_first):
     assert launch["args"] == {"leaves": step._leaves, "relaid_leaves": 4}
 
 
-def test_zero_holds_flat_shards_and_nothing_moves(minor_first):
-    net = _net("zl_")
+@RULES
+def test_zero_holds_flat_shards_and_nothing_moves(minor_first,
+                                                  with_optimizer):
+    net = _net("zl%d_" % with_optimizer)
     before = _relayouts()
-    step = _step(net, zero=True)
+    step = _step(net, with_optimizer, zero=True)
     x, y = _batch()
     step(x, y)
     step(x, y)
-    assert step._orders is None and step._relaid == 0
-    assert _relayouts() == before
+    # the model's order for every leaf, whatever a compiler would answer
+    assert all(o == tuple(range(len(o)))
+               for tree in step._orders for o in tree)
+    assert step._relaid == 0 and _relayouts() == before
     assert all(v.ndim == 1 for v in step.train_vals + step.opt_state)
     assert "input_output_alias" in step.program_for(
         *step.put_batch(x, y)).as_text()
+
+
+@FORMS
+@RULES
+def test_the_program_takes_the_rules_scalars_and_aliases_the_state(
+        zero, with_optimizer):
+    """``program_for`` stands in for the key and the host scalars: as
+    many scalar arguments as the rule has slots (the fused rule's empty
+    tuple adds none), every state leaf donated and aliased to a result."""
+    import re
+
+    import jax
+
+    step = _step(_net("pf%d%d_" % (zero, with_optimizer)), with_optimizer,
+                 zero=zero)
+    program = step.program_for(*step.put_batch(*_batch()))
+    (*state, _x, _y, _key, scalars), _kwargs = program.args_info
+    assert len(scalars) == len(step._rule.slots)
+    assert (len(scalars) > 0) == with_optimizer
+    leaves = jax.tree.leaves(state)
+    assert len(leaves) == len(_read(step)) and all(
+        leaf.donated for leaf in leaves)
+    assert len(jax.tree.leaves(program.args_info)) \
+        == len(leaves) + 3 + len(scalars)
+    header = program.as_text().split("\n", 1)[0]
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", header)
+    assert len(set(aliased)) == len(leaves)
+
+
+def test_the_zero_methods_of_a_replicated_step_raise():
+    from mxnet_tpu.base import MXNetError
+
+    step = _step(_net("nz_"))
+    for call in (step.zero_shard_payloads, lambda: step.save_zero(1),
+                 lambda: step.restore_zero({})):
+        with pytest.raises(MXNetError, match="not built with zero=True"):
+            call()
+    assert not hasattr(step, "zero_layout")
 
 
 # --------------------------------------------------- the v5e's compiler
@@ -333,11 +401,11 @@ def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
         lr=0.1, momentum=0.9, wd=1e-4, compute_dtype="bfloat16")
     mesh = Mesh(np.array(v5e.devices[:1]), ("dp",))
     repl, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
-    step._repl, step._rest_in = repl, (batch, batch, repl)
-    step._state_shard = jax.tree.map(lambda _s: repl, step._state_shard)
+    step._repl, step._rest_in = repl, (batch, batch, repl, repl)
+    step._form.shards = jax.tree.map(lambda _s: repl, step._form.shards)
     x = jax.ShapeDtypeStruct((32, 16, 16, 64), jnp.float32)
     y = jax.ShapeDtypeStruct((32,), jnp.int32)
-    rest = [jax.ShapeDtypeStruct((2,), jnp.uint32)]
+    rest = [jax.ShapeDtypeStruct((2,), jnp.uint32), ()]
 
     def copies_at_the_entry(orders):
         step._orders = orders
@@ -345,8 +413,7 @@ def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
             tuple(v.shape[i] for i in order), v.dtype)
             for v, order in zip(tree, tree_orders))
             for tree, tree_orders in zip(step._held, orders)]
-        text = step._jit(step._step_py, 1).lower(
-            *held, x, y, *rest).compile().as_text()
+        text = step._jit().lower(*held, x, y, *rest).compile().as_text()
         entry = text[text.index("\nENTRY "):]
         return len(re.findall(r" copy\(", entry[:entry.index("\n}")]))
 

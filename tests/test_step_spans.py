@@ -104,7 +104,8 @@ def test_three_steps_on_the_profilers_clock(tmp_path, zero, with_optimizer):
     assert len(spans) == 3 * (1 + len(expected)) + 2
     # every array the launch flattens: parameters, optimizer state,
     # statistics, batch, labels, key and the optimizer's host scalars
-    n_scalars = len(step._opt_update.slots) if with_optimizer else 0
+    n_scalars = len(step._rule.slots)
+    assert (n_scalars > 0) == with_optimizer
     assert step._leaves == len(step.train_vals) + len(step.opt_state) \
         + len(step.aux_vals) + 3 + n_scalars
 

@@ -29,7 +29,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import checkpoint, gluon, optimizer as opt_mod, runtime_stats
 from mxnet_tpu.gluon import nn
-from mxnet_tpu.parallel.gluon_step import GluonStep
+from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,10 +59,10 @@ def _zstep(prefix, n=8, seed=7):
     import jax
 
     mesh = create_mesh({"dp": n}, devices=jax.devices()[:n])
-    return GluonStep(_mlp(prefix, seed=seed),
-                     gluon.loss.SoftmaxCrossEntropyLoss(), mesh=mesh,
-                     zero=True, optimizer=opt_mod.create(
-                         "adam", learning_rate=0.01))
+    return GluonTrainStep(_mlp(prefix, seed=seed),
+                          gluon.loss.SoftmaxCrossEntropyLoss(), mesh=mesh,
+                          zero=True, optimizer=opt_mod.create(
+                              "adam", learning_rate=0.01))
 
 
 def _data(n=8, batch=8, feat=12, classes=4, seed=3):
@@ -132,7 +132,7 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import checkpoint, gluon, optimizer as opt_mod
 from mxnet_tpu.gluon import nn
-from mxnet_tpu.parallel.gluon_step import GluonStep
+from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
 mode, ckdir = sys.argv[1], sys.argv[2]
@@ -143,9 +143,9 @@ with net.name_scope():
     net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
 net.initialize(ctx=mx.cpu())
 net(mx.nd.zeros((2, 12), ctx=mx.cpu()))
-zs = GluonStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-               mesh=create_mesh({"dp": 8}), zero=True,
-               optimizer=opt_mod.create("adam", learning_rate=0.01))
+zs = GluonTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                    mesh=create_mesh({"dp": 8}), zero=True,
+                    optimizer=opt_mod.create("adam", learning_rate=0.01))
 rs = np.random.RandomState(3)
 xs = [rs.rand(8, 12).astype(np.float32) for _ in range(7)]
 ys = [rs.randint(0, 4, (8,)).astype(np.int32) for _ in range(7)]
@@ -254,9 +254,9 @@ def test_restore_zero_guards(tmp_path):
     import jax
 
     mesh = create_mesh({"dp": 8}, devices=jax.devices()[:8])
-    zsgd = GluonStep(_mlp("zgd2_"), gluon.loss.SoftmaxCrossEntropyLoss(),
-                     mesh=mesh, zero=True,
-                     optimizer=opt_mod.create("sgd", learning_rate=0.1,
-                                              momentum=0.9))
+    zsgd = GluonTrainStep(
+        _mlp("zgd2_"), gluon.loss.SoftmaxCrossEntropyLoss(), mesh=mesh,
+        zero=True, optimizer=opt_mod.create("sgd", learning_rate=0.1,
+                                            momentum=0.9))
     with pytest.raises(MXNetError, match="state structure changed"):
         zsgd.restore_zero(manifest, mgr=mgr)

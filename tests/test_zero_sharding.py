@@ -11,9 +11,9 @@ Pins the acceptance criteria:
   live shards clear 0.8×n at n=2 and n=8 in-process and n=64 in a
   subprocess (the tier-1 guard against a regression to replicated
   state), and the compiled HLO carries the param all-gather;
-- the seam: ``trainer.compile(..., zero=True)`` /
-  ``MXNET_TPU_ZERO=1`` route to ZeroCompiledStep, guards reject
-  unsafe configurations, and the observability substrate sees the
+- the seam: ``trainer.compile(..., zero=True)`` routes to
+  ZeroCompiledStep, guards reject unsafe configurations, and the
+  observability substrate sees the
   sharded path (zero counters, compare() notes semantics, the
   zero-allgather-dominated doctor rule, metrics-timeline columns).
 """
@@ -31,7 +31,7 @@ from mxnet_tpu import gluon, health, optimizer as opt_mod
 from mxnet_tpu import metrics_timeline, perfdoctor, runtime_stats
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon import nn
-from mxnet_tpu.parallel.gluon_step import GluonStep, GluonTrainStep
+from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -102,8 +102,8 @@ def test_dp_vs_zero_bit_exact_20_steps(opt, kw):
     ld, gd = _run(dp, xs, ys)
 
     net_z = _mlp("zpar_")
-    zs = GluonStep(net_z, loss_fn, mesh=mesh, zero=True,
-                   optimizer=opt_mod.create(opt, **kw))
+    zs = GluonTrainStep(net_z, loss_fn, mesh=mesh, zero=True,
+                        optimizer=opt_mod.create(opt, **kw))
     lz, gz = _run(zs, xs, ys)
 
     assert ld == lz, "loss trajectories diverged for %s" % opt
@@ -154,10 +154,10 @@ def test_state_bytes_shrink_in_process(n):
     state leaf's addressable shard is 1/n of its global shape."""
     import jax
 
-    zs = GluonStep(_mlp("zshr%d_" % n),
-                   gluon.loss.SoftmaxCrossEntropyLoss(),
-                   mesh=create_mesh({"dp": n}, devices=jax.devices()[:n]),
-                   zero=True, optimizer=opt_mod.create("adam"))
+    zs = GluonTrainStep(_mlp("zshr%d_" % n),
+                        gluon.loss.SoftmaxCrossEntropyLoss(),
+                        mesh=create_mesh({"dp": n}, devices=jax.devices()[:n]),
+                        zero=True, optimizer=opt_mod.create("adam"))
     assert _measured_shrink(zs) >= 0.8 * n
     for v in zs.train_vals + zs.opt_state:
         assert int(v.shape[0]) % n == 0
@@ -165,34 +165,24 @@ def test_state_bytes_shrink_in_process(n):
             == int(v.shape[0]) // n
 
 
-def test_hlo_carries_allgather_and_sharded_update():
+@pytest.mark.parametrize("rule", [{"optimizer": "adam"}, {"lr": 0.1}],
+                         ids=["optimizer", "fused_sgd"])
+def test_hlo_carries_allgather_and_sharded_update(rule):
     """The compiled post-SPMD HLO of the zero step contains the param
     all-gather (GSPMD's lowering of the replicated forward constraint)
-    — the collective structure the SCALING_TABLE rows pin."""
-    import jax
-
-    from mxnet_tpu import random as mxrandom
-
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        from scaling_report import collective_stats
-    finally:
-        sys.path.pop(0)
-    zs = GluonStep(_mlp("zhlo_"), gluon.loss.SoftmaxCrossEntropyLoss(),
-                   mesh=create_mesh({"dp": 8}), zero=True,
-                   optimizer=opt_mod.create("adam"))
-    x, y = zs.put_batch(np.zeros((8, 12), np.float32),
-                        np.zeros((8,), np.int32))
-    hlo = zs._step.lower(
-        zs.train_vals, zs.opt_state, zs.aux_vals, x, y,
-        mxrandom.next_key(),
-        tuple(0.0 for _ in zs._opt_update.slots)).compile().as_text()
-    stats = collective_stats(hlo)
-    assert stats["all-gather"]["count"] >= 1
+    — the collective structure the SCALING_TABLE rows pin — whichever
+    rule updates the shards."""
+    if "optimizer" in rule:
+        rule = {"optimizer": opt_mod.create(rule["optimizer"])}
+    zs = GluonTrainStep(_mlp("zhlo_"), gluon.loss.SoftmaxCrossEntropyLoss(),
+                        mesh=create_mesh({"dp": 8}), zero=True, **rule)
+    hlo = zs.program_for(*zs.put_batch(
+        np.zeros((8, 12), np.float32), np.zeros((8,), np.int32))).as_text()
+    assert hlo.count(" all-gather(") + hlo.count(" all-gather-start(") >= 1
     # grad reduction present in some collective form (true
     # reduce-scatter on TPU; all-reduce+slice is the CPU lowering)
-    assert stats["reduce-scatter"]["count"] + \
-        stats["all-reduce"]["count"] >= 1
+    assert sum(hlo.count(" %s(" % op) for op in (
+        "reduce-scatter", "all-reduce", "all-reduce-start")) >= 1
 
 
 @pytest.mark.parametrize("n", [64])
@@ -206,7 +196,7 @@ import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, optimizer as opt_mod
 from mxnet_tpu.gluon import nn
-from mxnet_tpu.parallel.gluon_step import GluonStep
+from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
 mx.random.seed(1)
@@ -215,9 +205,9 @@ with net.name_scope():
     net.add(nn.Dense(64, activation="relu"), nn.Dense(10))
 net.initialize(ctx=mx.cpu())
 net(mx.nd.zeros((2, 32), ctx=mx.cpu()))
-zs = GluonStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-               mesh=create_mesh({"dp": %d}), zero=True,
-               optimizer=opt_mod.create("adam"))
+zs = GluonTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                    mesh=create_mesh({"dp": %d}), zero=True,
+                    optimizer=opt_mod.create("adam"))
 per_dev = sum(int(v.addressable_shards[0].data.nbytes)
               for v in zs.train_vals + zs.opt_state)
 json.dump({"per_dev": per_dev,
@@ -242,9 +232,9 @@ json.dump({"per_dev": per_dev,
 # ------------------------------------------------------- seam & guards
 
 
-def test_trainer_compile_zero_and_env_routing(monkeypatch):
-    """``trainer.compile(zero=True)`` and ``MXNET_TPU_ZERO=1`` both
-    yield a ZeroCompiledStep; the explicit argument wins over env."""
+def test_trainer_compile_zero_routing():
+    """``trainer.compile(zero=True)`` yields a ZeroCompiledStep; the
+    default is the replicated CompiledStep."""
     from mxnet_tpu.compiled_step import CompiledStep, ZeroCompiledStep
 
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
@@ -253,9 +243,7 @@ def test_trainer_compile_zero_and_env_routing(monkeypatch):
                        {"learning_rate": 0.1, "momentum": 0.9})
     assert isinstance(tr.compile(net, loss_fn, zero=True),
                       ZeroCompiledStep)
-    monkeypatch.setenv("MXNET_TPU_ZERO", "1")
-    assert isinstance(tr.compile(net, loss_fn), ZeroCompiledStep)
-    assert isinstance(tr.compile(net, loss_fn, zero=False), CompiledStep)
+    assert isinstance(tr.compile(net, loss_fn), CompiledStep)
 
 
 def test_zero_step_counters_timeline_and_health():
@@ -285,21 +273,16 @@ def test_zero_step_counters_timeline_and_health():
 
 def test_zero_guards():
     """Unsafe configurations raise, not silently degrade: non-safe
-    optimizer, param_spec_fn composition, make_chained with per-step
-    scalars, and trainer rescale changes after compile."""
+    optimizer, param_spec_fn composition."""
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
     mesh = create_mesh({"dp": 8})
     net = _mlp("zgrd_")
     with pytest.raises(MXNetError, match="param_spec_fn"):
-        GluonStep(net, loss_fn, mesh=mesh, zero=True,
-                  param_spec_fn=lambda *a: None)
+        GluonTrainStep(net, loss_fn, mesh=mesh, zero=True,
+                       param_spec_fn=lambda *a: None)
     with pytest.raises(MXNetError, match="not compiled-step safe"):
-        GluonStep(net, loss_fn, mesh=mesh, zero=True,
-                  optimizer=opt_mod.create("lbsgd"))
-    zs = GluonStep(net, loss_fn, mesh=mesh, zero=True,
-                   optimizer=opt_mod.create("adam"))
-    with pytest.raises(MXNetError, match="make_chained"):
-        zs.make_chained(4)
+        GluonTrainStep(net, loss_fn, mesh=mesh, zero=True,
+                       optimizer=opt_mod.create("lbsgd"))
 
 
 def test_adagrad_adadelta_eager_vs_compiled_within_tolerance():

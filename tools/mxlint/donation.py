@@ -29,7 +29,7 @@ Donating callables are tracked through the bindings the runtime
 actually uses: ``self._step = jax.jit(..., donate_argnums=...)``,
 ``fn = jax.jit(...)`` locals (including enclosing-function closures),
 and one-hop factories (``return jax.jit(...)`` → ``self._step =
-make_train_step(...)``).  ``donate_argnums`` values resolve through
+self._jit()``).  ``donate_argnums`` values resolve through
 literal tuples/ints and single-assignment locals of literal
 conditionals (``donate = (0, 1) if donate_params else ()``).  Call
 sites with ``*args`` are conservatively skipped — the argument mapping
@@ -221,7 +221,7 @@ def _collect_donating_bindings(ctx, module, graph, fn_map):
                     and fn is not None:
                 factories[fn.key] = argnums
 
-    # pass 2: one-hop factory bindings (self._step = make_train_step())
+    # pass 2: one-hop factory bindings (self._step = self._jit())
     if factories:
         for node in ast.walk(ctx.tree):
             if not (isinstance(node, ast.Assign)

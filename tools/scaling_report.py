@@ -6,8 +6,8 @@ For each n in --devices, a child process with n virtual CPU devices
 on tiny shapes —
 
 - **dp**: the flagship ResNet-50 v1 data-parallel GluonTrainStep
-  (what bench.py measures at n=1), params replicated, GSPMD inserting
-  the gradient all-reduce; and
+  (the step of the benchmark's ResNet-50 cells), params replicated,
+  GSPMD inserting the gradient all-reduce; and
 - **dp2 x tp2 x pp(n/4)**: the 3-axis composition from
   `__graft_entry__._dryrun_dp_tp_pp` — GPipe collective-permute ring
   over 'pp', Megatron row-parallel psum over 'tp', dp grad all-reduce —
@@ -88,7 +88,6 @@ def _child(n):
 
     import mxnet_tpu as mx
     from mxnet_tpu import gluon
-    from mxnet_tpu import random as mxrandom
     from mxnet_tpu.gluon.model_zoo import vision
     from mxnet_tpu.parallel.gluon_step import GluonTrainStep
     from mxnet_tpu.parallel.mesh import create_mesh
@@ -116,10 +115,7 @@ def _child(n):
                                                  learning_rate=1e-3))
     xz, yz = zstep.put_batch(np.zeros((n, 256), np.float32),
                              np.zeros((n,), np.int32))
-    hloz = zstep._step.lower(
-        zstep.train_vals, zstep.opt_state, zstep.aux_vals, xz, yz,
-        mxrandom.next_key(),
-        tuple(0.0 for _ in zstep._opt_update.slots)).compile().as_text()
+    hloz = zstep.program_for(xz, yz).as_text()
     out["zero"] = {
         "param_bytes_per_dev": _sharded_bytes(zstep.train_vals),
         "opt_bytes_per_dev": _sharded_bytes(zstep.opt_state),
