@@ -81,7 +81,12 @@ class GatedFFN(HybridBlock):
 
 
 class MLAttention(HybridBlock):
-    """Multi-head latent attention, causal, in its training form."""
+    """Multi-head latent attention, causal, in its training form:
+    ``mla_qkv`` (``ops/llm.py``), the flash kernels, ``mla_out``.  The
+    parameters have the names and shapes of the published checkpoints;
+    the heads and the nope / rotary / value parts are split on the
+    weights inside the operator, which hands ``q``, ``k`` and ``v`` to
+    the kernel as ``(B, heads, S, head)``, the layout it reads."""
 
     def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
@@ -89,10 +94,9 @@ class MLAttention(HybridBlock):
                  **kwargs):
         super().__init__(**kwargs)
         self._heads = num_heads
-        self._nope, self._rope = qk_nope_head_dim, qk_rope_head_dim
-        self._v, self._rank = v_head_dim, kv_lora_rank
         self._theta, self._epsilon = rope_theta, epsilon
         qk = qk_nope_head_dim + qk_rope_head_dim
+        self._sm_scale = qk ** -0.5
         init = _init.Normal(weight_std)
         shapes = {
             "qa_weight": (q_lora_rank, units),
@@ -113,45 +117,14 @@ class MLAttention(HybridBlock):
 
     def hybrid_forward(self, F, x, qa_weight, qb_weight, kva_weight,
                        kvb_weight, o_weight, qnorm_weight, kvnorm_weight):
-        heads, nope, rot, vd = self._heads, self._nope, self._rope, self._v
-
-        def dense(data, weight):
-            return F.FullyConnected(data, weight, no_bias=True,
-                                    flatten=False,
-                                    num_hidden=weight.shape[0])
-
-        def by_head(data, size):        # (B, S, heads * size) -> (B, h, S, .)
-            return F.transpose(F.reshape(data, shape=(0, 0, heads, size)),
-                               axes=(0, 2, 1, 3))
-
-        with _xray.scope("mla.proj"):
-            c_q = F.contrib.rms_norm(dense(x, qa_weight), qnorm_weight,
-                                     eps=self._epsilon)
-            q = by_head(dense(c_q, qb_weight), nope + rot)
-            kva = dense(x, kva_weight)
-            c_kv = F.contrib.rms_norm(
-                F.slice_axis(kva, axis=-1, begin=0, end=self._rank),
-                kvnorm_weight, eps=self._epsilon)
-            k_r = F.contrib.rope(
-                F.slice_axis(kva, axis=-1, begin=self._rank, end=None),
-                theta=self._theta)                          # (B, S, rot)
-            kv = by_head(dense(c_kv, kvb_weight), nope + vd)
-            q = F.concat(
-                F.slice_axis(q, axis=-1, begin=0, end=nope),
-                F.contrib.rope(F.slice_axis(q, axis=-1, begin=nope, end=None),
-                               theta=self._theta), dim=-1)
-            k = F.concat(
-                F.slice_axis(kv, axis=-1, begin=0, end=nope),
-                F.broadcast_axis(F.expand_dims(k_r, axis=1), axis=1,
-                                 size=heads), dim=-1)
-            v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+        q, k, v = F.contrib.mla_qkv(
+            x, qa_weight, qb_weight, kva_weight, kvb_weight, qnorm_weight,
+            kvnorm_weight, num_heads=self._heads, theta=self._theta,
+            eps=self._epsilon)
         with _xray.scope("mla.attention"):
             o = F.contrib.flash_attention(q, k, v, causal=True,
-                                          sm_scale=(nope + rot) ** -0.5)
-        with _xray.scope("mla.proj"):
-            o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)),
-                          shape=(0, 0, heads * vd))
-            return dense(o, o_weight)
+                                          sm_scale=self._sm_scale)
+        return F.contrib.mla_out(o, o_weight)
 
 
 class RoutedExperts(HybridBlock):

@@ -29,8 +29,8 @@ from jax import lax
 from .. import xray as _xray
 from .registry import OP_INPUT_NAMES, register
 
-__all__ = ["rms_norm", "rope", "gated_silu", "moe_route", "moe_experts",
-           "linear_cross_entropy", "expert_tiles"]
+__all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "moe_route",
+           "moe_experts", "linear_cross_entropy", "expert_tiles"]
 
 # rows of one expert tile.  A tile costs its expert's three weights read
 # (twice in the backward pass) and their three float32 gradients read and
@@ -51,24 +51,81 @@ def rms_norm(data, gamma, eps=1e-6, **_):
     return (x * scale * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
+def _rotary_tables(seq, dim, theta):
+    """``cos``, ``sin`` of ``p * theta^(-2i/dim)`` for positions ``p <
+    seq``, each repeated over its pair of lanes, the sine negative on a
+    pair's first lane: ``(seq, dim)`` float32 constants from a float64
+    host table (float32 angles at position 4096 and theta 3.2e7 are wrong
+    in the fourth digit)."""
+    f64 = _np.float64  # mxlint: disable=dtype-default -- host table, cast below
+    inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
+    angle = _np.arange(seq, dtype=f64)[:, None] * inv[None, :]
+    cos = _np.repeat(_np.cos(angle), 2, axis=-1)
+    sin = _np.repeat(_np.sin(angle), 2, axis=-1)
+    sin[:, 0::2] *= -1
+    return jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
+
+
+def _swap_pairs(x):
+    """``x[..., 2i] <-> x[..., 2i + 1]`` in float32, as a product with a
+    0 / 1 permutation: one term a result, so it is exact (float32
+    operands take the MXU's exact passes).  On a v5e XLA makes one fusion
+    of it and the arithmetic around it; lanes taken by stride become
+    gathers (scatters in the gradient), and rolled by one, four sliced
+    float32 copies (PERF.md, PR 31).  The permutation is at most 128
+    wide: a wider last axis is cut into equal parts."""
+    dim = x.shape[-1]
+    width = next(w for w in range(min(dim, 128), 1, -1)
+                 if dim % w == 0 and w % 2 == 0)
+    lane = _np.arange(width, dtype=_np.int32)
+    perm = _np.zeros((width, width), dtype=_np.float32)
+    perm[lane, lane ^ 1] = 1
+    parts = x.reshape(x.shape[:-1] + (dim // width, width))
+    out = lax.dot_general(
+        parts, jnp.asarray(perm, x.dtype), (((parts.ndim - 1,), (0,)),
+                                            ((), ())),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    return out.reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotary(x, cos, sin, start):
+    """Lanes ``start:`` of ``x (..., seq, dim)`` rotated pair by pair by
+    the tables of ``_rotary_tables``, float32 arithmetic, the lanes before
+    ``start`` left as they are: written in place of ``x`` where the
+    compiler may."""
+    tail = x[..., start:]
+    out = (tail.astype(jnp.float32) * cos + _swap_pairs(tail) * sin).astype(
+        x.dtype)
+    if not start:
+        return out
+    return lax.dynamic_update_slice_in_dim(x, out, start, axis=x.ndim - 1)
+
+
+def _rotary_fwd(x, cos, sin, start):
+    return _rotary(x, cos, sin, start), (cos, sin)
+
+
+def _rotary_bwd(start, tables, g):
+    # a rotation's transpose is the rotation back; the lanes before
+    # ``start`` pass through
+    cos, sin = tables
+    return _rotary(g, cos, -sin, start), None, None
+
+
+_rotary.defvjp(_rotary_fwd, _rotary_bwd)
+
+
 @register("_contrib_rope", aliases=("rope",))
 def rope(data, theta=10000.0, **_):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(..., seq, dim)``: position ``p`` rotates the adjacent pair ``(2i,
-    2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``).  The angles
-    are constants computed in float64 when the op is traced."""
-    seq, dim = data.shape[-2], data.shape[-1]
-    # a host-side table: float32 angles at position 4096 and theta 3.2e7
-    # are wrong in the fourth digit
-    f64 = _np.float64  # mxlint: disable=dtype-default -- host table, cast below
-    inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
-    angle = _np.arange(seq, dtype=f64)[:, None] * inv[None, :]
-    cos = jnp.asarray(_np.cos(angle), jnp.float32)
-    sin = jnp.asarray(_np.sin(angle), jnp.float32)
-    x = data.astype(jnp.float32)
-    a, b = x[..., 0::2], x[..., 1::2]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(data.shape).astype(data.dtype)
+    2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``):
+    ``x * cos + swap_pairs(x) * sin`` in float32, the angles constants
+    computed in float64 when the op is traced; its gradient is the
+    rotation back."""
+    cos, sin = _rotary_tables(data.shape[-2], data.shape[-1], theta)
+    return _rotary(data, cos, sin, 0)
 
 
 def _dot(a, b, contract):
@@ -86,6 +143,81 @@ def gated_silu(data, gate_weight, up_weight, down_weight, **_):
     u = _dot(data, up_weight, (last, (1,)))
     h = (jax.nn.silu(g) * u).astype(data.dtype)
     return _dot(h, down_weight, (last, (1,))).astype(data.dtype)
+
+
+# --------------------------------------------------- latent attention
+
+
+def _row_major(x):
+    """``x`` held with its last axis minor.  A Pallas kernel takes its
+    operands so, and left to itself the v5e's compiler lays a projection's
+    result out sequence-minor (a head size of 192 fills a lane tile and a
+    half) and copies it on the way to the kernel; its cotangent is held
+    the same way."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _by_head(x, weight):
+    """``(B, S, in)`` times a head's rows of ``weight (heads, d, in)`` ->
+    ``(B, heads, S, d)``, the product's own result: no ``(B, S, heads *
+    d)`` stands to be transposed."""
+    return _row_major(jnp.einsum("bsr,hdr->bhsd", x, weight))
+
+
+@register("_contrib_mla_qkv", num_outputs=3, aliases=("mla_qkv",))
+def mla_qkv(data, qa_weight, qb_weight, kva_weight, kvb_weight,
+            qnorm_weight, kvnorm_weight, num_heads=1, theta=10000.0,
+            eps=1e-6, **_):
+    """The projections of multi-head latent attention in its training
+    form (DeepSeek-V2, arXiv:2405.04434), from the block's input ``(B, S,
+    units)`` to what ``flash_attention`` reads: ``q``, ``k`` ``(B, heads,
+    S, nope + rope)`` and ``v`` ``(B, heads, S, v)``.
+
+    The weights have the shapes of the published checkpoints:
+    ``qa_weight (q_rank, units)``, ``qb_weight (heads * (nope + rope),
+    q_rank)``, ``kva_weight (kv_rank + rope, units)``, ``kvb_weight (heads
+    * (nope + v), kv_rank)`` and the two latents' norm scales, from which
+    the sizes are read.  A head's rows are taken from the weights, not
+    from a product's result: every product writes ``(B, heads, S, d)``
+    itself, ``v`` is a product and not a slice of ``kv``, ``q``'s rotary
+    lanes are rotated in place of the product's result, and one pass
+    writes ``k`` from its ``nope`` part and the one rotary key that all
+    heads share (the pass that reads ``dk`` sums that key's gradient over
+    the heads)."""
+    heads = int(num_heads)
+    rank = kvnorm_weight.shape[0]
+    rot = kva_weight.shape[0] - rank
+    nope = qb_weight.shape[0] // heads - rot
+    with _xray.scope("mla.proj"):
+        cos, sin = _rotary_tables(data.shape[-2], rot, theta)
+        c_q = rms_norm(jnp.matmul(data, qa_weight.T), qnorm_weight, eps=eps)
+        q = _by_head(c_q, qb_weight.reshape(heads, nope + rot, -1))
+        q = _rotary(q, cos, sin, nope)
+        kva = jnp.matmul(data, kva_weight.T)
+        c_kv = rms_norm(kva[..., :rank], kvnorm_weight, eps=eps)
+        k_rot = _rotary(kva[..., rank:], cos, sin, 0)
+        kvb = kvb_weight.reshape(heads, -1, rank)
+        k_nope = _by_head(c_kv, kvb[:, :nope])
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rot[:, None],
+                                      k_nope.shape[:-1] + (rot,))], axis=-1)
+        v = _by_head(c_kv, kvb[:, nope:])
+        return q, k, v
+
+
+@register("_contrib_mla_out", aliases=("mla_out",))
+def mla_out(data, weight, **_):
+    """Attention's output projection from the kernel's own result:
+    ``data (B, heads, S, v)`` contracted over (head, value) with ``weight
+    (units, heads * v)`` -> ``(B, S, units)``; no transposed copy of
+    ``data``, and its gradient is written ``(B, heads, S, v)``."""
+    _, heads, _, width = data.shape
+    with _xray.scope("mla.proj"):
+        return jnp.einsum("bhsv,uhv->bsu", _row_major(data),
+                          weight.reshape(-1, heads, width))
 
 
 @register("_contrib_moe_route", num_outputs=2, aliases=("moe_route",))
@@ -314,6 +446,9 @@ OP_INPUT_NAMES.update({
     "_contrib_rope": ("data",),
     "_contrib_gated_silu": ("data", "gate_weight", "up_weight",
                             "down_weight"),
+    "_contrib_mla_qkv": ("data", "qa_weight", "qb_weight", "kva_weight",
+                         "kvb_weight", "qnorm_weight", "kvnorm_weight"),
+    "_contrib_mla_out": ("data", "weight"),
     "_contrib_moe_route": ("data", "router_weight", "router_bias"),
     "_contrib_moe_experts": ("data", "expert_ids", "expert_weights",
                              "gate_weight", "up_weight", "down_weight"),

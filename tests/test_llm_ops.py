@@ -109,6 +109,41 @@ def rope_three_axes():
 
 
 @case
+def rope_of_one_pair():
+    x = _rs().randn(3, 5, 2)
+    return (lambda x: llm.rope(x, theta=1e4),
+            lambda x: np_rope(x, 1e4), (x,), (0,))
+
+
+@case
+def rope_at_position_4095_with_a_head_axis():
+    x = _rs().randn(1, 2, 4096, 64)
+    return (lambda x: llm.rope(x, theta=3.2e7),
+            lambda x: np_rope(x, 3.2e7), (x,), (0,))
+
+
+@case
+def rope_at_position_4095_without_a_head_axis():
+    x = _rs().randn(1, 4096, 128)
+    return (lambda x: llm.rope(x, theta=1e4),
+            lambda x: np_rope(x, 1e4), (x,), (0,))
+
+
+@case
+def rope_wider_than_a_lane_tile():
+    x = _rs().randn(2, 5, 384)          # three permutations of 128
+    return (lambda x: llm.rope(x, theta=1e4),
+            lambda x: np_rope(x, 1e4), (x,), (0,))
+
+
+@case
+def rope_of_a_width_no_tile_divides():
+    x = _rs().randn(2, 5, 200)          # two permutations of 100
+    return (lambda x: llm.rope(x, theta=3.2e7),
+            lambda x: np_rope(x, 3.2e7), (x,), (0,))
+
+
+@case
 def gated_silu():
     rs = _rs()
     args = (rs.randn(2, 5, 8), rs.randn(12, 8), rs.randn(12, 8),
@@ -175,13 +210,48 @@ def test_op_against_numpy_with_gradients(name):
             numeric, rel=2e-3, abs=2e-4), (name, i)
 
 
+@pytest.mark.parametrize("shape,theta", [((2, 3, 16, 2), 1e4),
+                                         ((1, 2, 4096, 64), 3.2e7),
+                                         ((1, 4096, 128), 1e4)],
+                         ids=["one_pair", "heads_64", "no_heads_128"])
+def test_rope_of_bfloat16_is_the_float32_result_cast(shape, theta):
+    """The arithmetic is float32 whatever the input's type, and the pair's
+    partner is fetched exactly: bfloat16 in gives the float32 result's
+    cast, to an ulp; traced or eager."""
+    x = jnp.asarray(_rs(4).randn(*shape), jnp.bfloat16)
+    want = llm.rope(x.astype(jnp.float32), theta=theta)
+    np.testing.assert_allclose(want, np_rope(np.asarray(x, np.float64), theta),
+                               rtol=2e-5, atol=2e-5)
+    want = np.asarray(want.astype(jnp.bfloat16), np.float32)
+    # an ulp of the result; where the two terms cancel, the float32
+    # rounding of the terms (a fused multiply-add or not)
+    ulp = np.abs(want) * 2.0 ** -7 + np.abs(np.asarray(x, np.float32)).max() \
+        * 2.0 ** -21
+    for op in (llm.rope, jax.jit(llm.rope, static_argnames="theta")):
+        got = op(x, theta=theta)
+        assert got.dtype == jnp.bfloat16
+        assert (np.abs(np.asarray(got, np.float32) - want) <= ulp).all()
+
+
+def test_the_gradient_of_rope_is_the_rotation_back():
+    """No gather forward, no scatter backward: the lowered gradient is
+    products, selects and elementwise arithmetic."""
+    x = _f(_rs(6).randn(2, 3, 9, 8))
+    back = jax.grad(lambda x, g: jnp.sum(llm.rope(x, theta=1e4) * g))
+    g = _f(_rs(7).randn(2, 3, 9, 8))
+    np.testing.assert_allclose(
+        llm.rope(back(x, g), theta=1e4), g, rtol=1e-5, atol=1e-5)
+    text = jax.jit(back).lower(x, g).as_text()
+    assert "gather" not in text and "scatter" not in text
+
+
 def test_the_ops_are_in_the_contrib_namespaces():
     x = mx.nd.array(_rs().randn(2, 5, 8).astype(np.float32))
     out = mx.nd.contrib.rms_norm(x, mx.nd.ones((8,)))
     np.testing.assert_allclose(
         out.asnumpy(), np_rms_norm(x.asnumpy(), 1.0), rtol=1e-5)
-    for name in ("rope", "gated_silu", "moe_route", "moe_experts",
-                 "linear_cross_entropy"):
+    for name in ("rope", "gated_silu", "mla_qkv", "mla_out", "moe_route",
+                 "moe_experts", "linear_cross_entropy"):
         assert hasattr(mx.nd.contrib, name) and hasattr(mx.sym.contrib, name)
 
 

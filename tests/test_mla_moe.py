@@ -12,7 +12,8 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon
-from mxnet_tpu.gluon.nn import (MLAMoELM, MultiTokenLoss, RoutedExperts)
+from mxnet_tpu.gluon.nn import (MLAMoELM, MLAttention, MultiTokenLoss,
+                                RoutedExperts)
 from mxnet_tpu.parallel.gluon_step import GluonTrainStep
 from mxnet_tpu.parallel.mesh import create_mesh
 
@@ -111,6 +112,109 @@ def test_piece_against_the_reference(piece, net, tokens):
     with jax.default_matmul_precision("highest"):
         got, want = piece(net, named(net), tokens)
     _close(got, want)
+
+
+# ------------------------------- latent attention as it was before PR 31
+
+MLA_SIZES = dict(units=32, num_heads=2, q_lora_rank=24, kv_lora_rank=16,
+                 qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+                 rope_theta=32e6)
+# the parameters of a layer saved by PR 28, by structural name
+MLA_STATE = {"qa_weight": (24, 32), "qb_weight": (2 * (8 + 4), 24),
+             "kva_weight": (16 + 4, 32), "kvb_weight": (2 * (8 + 8), 16),
+             "o_weight": (32, 2 * 8), "qnorm_weight": (24,),
+             "kvnorm_weight": (16,)}
+
+
+def _strided_rope(x, theta):
+    seq, dim = x.shape[-2:]
+    inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    angle = np.arange(seq)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(angle), jnp.float32)
+    sin = jnp.asarray(np.sin(angle), jnp.float32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def _mla_as_it_was(p, x, heads=2, nope=8, rot=4, vd=8, rank=16, theta=32e6):
+    """The formulation ``MLAttention`` had up to PR 30, kept as the plain
+    reference: one product per projection, heads split by reshape and
+    transpose of its result, ``v`` sliced from ``kv``, ``q`` and ``k``
+    concatenated per head, the rotary key broadcast to the heads."""
+    from mxnet_tpu.ops.attention import mha_reference
+    from mxnet_tpu.ops.llm import rms_norm
+
+    def by_head(d, size):
+        return d.reshape(d.shape[0], d.shape[1], heads, size).transpose(
+            0, 2, 1, 3)
+
+    c_q = rms_norm(x @ p["qa_weight"].T, p["qnorm_weight"])
+    q = by_head(c_q @ p["qb_weight"].T, nope + rot)
+    kva = x @ p["kva_weight"].T
+    c_kv = rms_norm(kva[..., :rank], p["kvnorm_weight"])
+    k_r = _strided_rope(kva[..., rank:], theta)
+    kv = by_head(c_kv @ p["kvb_weight"].T, nope + vd)
+    q = jnp.concatenate([q[..., :nope], _strided_rope(q[..., nope:], theta)],
+                        -1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_r[:, None], kv.shape[:3] + (rot,))], -1)
+    o = mha_reference(q, k, kv[..., nope:], causal=True,
+                      sm_scale=(nope + rot) ** -0.5)
+    o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], heads * vd)
+    return o @ p["o_weight"].T
+
+
+def _mla_layer(tmp_path):
+    """A layer whose parameters come from a file with PR 28's names and
+    shapes."""
+    rs = np.random.RandomState(11)
+    state = {name: (rs.randn(*shape) * 0.3 + (len(shape) == 1)).astype(
+        np.float32) for name, shape in MLA_STATE.items()}
+    mx.nd.save(str(tmp_path / "pr28.params"),
+               {name: mx.nd.array(value) for name, value in state.items()})
+    layer = MLAttention(prefix="attn_", **MLA_SIZES)
+    layer.load_parameters(str(tmp_path / "pr28.params"), ctx=mx.cpu())
+    return layer, state
+
+
+MLA_COMPARED = ["output", "input"] + sorted(MLA_STATE)
+
+
+@pytest.fixture(scope="module")
+def mla_both(tmp_path_factory):
+    """{name: (got, want)}: the layer's output, its input's gradient and
+    its seven parameters' gradients, today's and as it was."""
+    layer, state = _mla_layer(tmp_path_factory.mktemp("mla"))
+    assert {n[len(layer.prefix):]: p.shape
+            for n, p in layer.collect_params().items()} == MLA_STATE
+    x = _hidden(4)
+    g = np.random.RandomState(12).randn(2, 16, 32).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        data = mx.nd.array(x)
+        data.attach_grad()
+        with mx.autograd.record():
+            out = layer(data)
+        out.backward(mx.nd.array(g))
+        want_out, vjp = jax.vjp(_mla_as_it_was, {
+            n: jnp.asarray(v) for n, v in state.items()}, jnp.asarray(x))
+        want_params, want_x = vjp(jnp.asarray(g))
+    both = {"output": (out.asnumpy(), want_out),
+            "input": (data.grad.asnumpy(), want_x)}
+    for name, param in layer.collect_params().items():
+        name = name[len(layer.prefix):]
+        both[name] = (param.grad().asnumpy(), want_params[name])
+    return both
+
+
+@pytest.mark.parametrize("name", MLA_COMPARED)
+def test_latent_attention_is_what_it_was_before_pr_31(mla_both, name):
+    """Heads split on the weights, the rotation by a permutation, ``q``
+    and ``k`` assembled once: the same function of the same parameters
+    (``ops/llm.py::mla_qkv``, ``mla_out``), to float32 rounding."""
+    got, want = mla_both[name]
+    assert got.shape == want.shape
+    _close(got, want, tol=1e-6)
 
 
 def test_the_shares_of_a_layer_sum_to_the_uncut_layer():

@@ -546,6 +546,8 @@ ELSEWHERE = {
     "_contrib_rms_norm": ("tests/test_llm_ops.py", "llm.rms_norm"),
     "_contrib_rope": ("tests/test_llm_ops.py", "llm.rope"),
     "_contrib_gated_silu": ("tests/test_llm_ops.py", "llm.gated_silu"),
+    "_contrib_mla_qkv": ("tests/test_mla_moe.py", "mla_qkv"),
+    "_contrib_mla_out": ("tests/test_mla_moe.py", "mla_out"),
     "_contrib_moe_route": ("tests/test_llm_ops.py", "llm.moe_route"),
     "_contrib_moe_experts": ("tests/test_llm_ops.py", "llm.moe_experts"),
     "_contrib_linear_cross_entropy": ("tests/test_llm_ops.py",

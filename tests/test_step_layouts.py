@@ -428,6 +428,36 @@ def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
     assert copies_at_the_entry(orders) == 0
 
 
+# what ``benchmark/harness/attention_cost.py`` counts for the three kernels
+# of the language-model cell (2 x 32 heads x 4,096, 192 / 128, causal)
+_CELLS_KERNEL_FLOPS = [2 * 32 * 4096 * 4096 / 2 * n for n in (640, 1024, 1280)]
+
+
+def _counted_kernel_flops(text):
+    """Sorted ``attention_cost.kernel_flops`` of every ``tpu_custom_call``
+    of a compiled module (None for one the benchmark does not know), each
+    instruction written as the profiler names it: its operands' types
+    inline."""
+    import os
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.harness import attention_cost, hlo_cost
+
+    lines = [hlo_cost.split_instruction(line) for line in text.splitlines()]
+    types = {name: result for name, _, (result, _, _) in lines}
+    flops = []
+    for name, opcode, (result, operands, attrs) in lines:
+        if attention_cost.KERNEL_TARGET in attrs:
+            operands = re.sub(r"%([\w.\-]+)", lambda m: "%s %s" % (
+                types[m.group(1)], m.group(0)), operands)
+            flops.append(attention_cost.kernel_flops("%%%s = %s %s(%s)%s" % (
+                name, result, opcode, operands, attrs)))
+    return sorted(flops)
+
+
 @pytest.mark.parametrize("dtype,blocks", [("bfloat16", (1024, 1024)),
                                           ("float32", (256, 512))])
 def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
@@ -437,19 +467,11 @@ def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
     runs them, float32 as the check does), and their instructions are the
     ones ``benchmark/harness/attention_cost.py`` counts: q, k, v (and do,
     lse, delta), one result for dq, two for the others."""
-    import os
-    import re
-    import sys
-
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from mxnet_tpu.ops import attention as att
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmark.harness import attention_cost, hlo_cost
 
     # traced on the CPU platform the entry would take its XLA branch
     monkeypatch.setattr(att, "pallas_interpret", lambda: False)
@@ -468,18 +490,59 @@ def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
     assert att._block_choices(arg(192), arg(128))[0] == blocks
     text = jax.jit(forward_and_backward).lower(
         arg(192), arg(192), arg(128), arg(128)).compile().as_text()
-    # the profiler names an instruction with its operands' types inline
-    lines = [hlo_cost.split_instruction(line) for line in text.splitlines()]
-    types = {name: result for name, _, (result, _, _) in lines}
-    flops = []
-    for name, opcode, (result, operands, attrs) in lines:
-        if attention_cost.KERNEL_TARGET in attrs:
-            operands = re.sub(r"%([\w.\-]+)", lambda m: "%s %s" % (
-                types[m.group(1)], m.group(0)), operands)
-            flops.append(attention_cost.kernel_flops("%%%s = %s %s(%s)%s" % (
-                name, result, opcode, operands, attrs)))
-    pairs = 2 * 32 * 4096 * 4096 / 2
-    assert sorted(flops) == [pairs * n for n in (640, 1024, 1280)]
+    assert _counted_kernel_flops(text) == _CELLS_KERNEL_FLOPS
+
+
+def test_on_the_v5e_latent_attention_hands_the_kernels_what_they_read(
+        v5e, monkeypatch):
+    """One ``MLAttention`` layer at the language-model cell's shape (2 x
+    4,096 tokens, 2048 / 1536 / 512, 32 heads of 128 + 64 / 128,
+    bfloat16), forward and backward, as the v5e's compiler lays it out:
+    no gather and no scatter (a rotation by strided lanes is both), the
+    three flash kernels the benchmark counts, and no more bytes than
+    today's: a relayout copy of ``q``, ``k`` or their gradients is 0.4 GB,
+    per-head concatenations and the sliced ``v`` brought the layer to 8.95
+    GB (PERF.md, PR 31)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mxnet_tpu.gluon.block import staged_call
+    from mxnet_tpu.ndarray import NDArray
+    from mxnet_tpu.ops import attention as att
+
+    monkeypatch.setattr(att, "pallas_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    layer = nn.MLAttention(2048, num_heads=32, q_lora_rank=1536,
+                           kv_lora_rank=512, qk_nope_head_dim=128,
+                           qk_rope_head_dim=64, v_head_dim=128,
+                           rope_theta=32e6, prefix="v5e_attn_")
+    params = list(layer.collect_params().values())
+
+    def forward(x, values):
+        out, _ = staged_call(
+            layer, {p: NDArray(v) for p, v in zip(params, values)}, None,
+            [NDArray(x)])
+        return out._data
+
+    def forward_and_backward(x, values, g):
+        out, vjp = jax.vjp(forward, x, values)
+        return (out,) + vjp(g)
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    compiled = jax.jit(forward_and_backward).lower(
+        arg((2, 4096, 2048)), [arg(p.shape) for p in params],
+        arg((2, 4096, 2048))).compile()
+    text = compiled.as_text()
+    assert not re.search(r" (gather|scatter)\(", text)
+    assert _counted_kernel_flops(text) == _CELLS_KERNEL_FLOPS
+    # 4.34 GB as this was written, 1.9 GB of it the three kernels'
+    # operands and results
+    assert compiled.cost_analysis()["bytes accessed"] < 4.6e9
 
 
 # ------------------------------------ why orders, and not layouts, are held
