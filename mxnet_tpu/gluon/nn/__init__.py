@@ -6,3 +6,4 @@ from .conv_layers import *  # noqa: F401,F403
 from .activations import *  # noqa: F401,F403
 from .transformer import *  # noqa: F401,F403
 from .mla_moe import *  # noqa: F401,F403
+from .hybrid_lm import *  # noqa: F401,F403

@@ -11,18 +11,25 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   heads share on the key side, scores over ``nope + rope`` and values of
   another head size.
 - :class:`RoutedExperts`: sigmoid-scored top-k routing with a selection
-  bias, the held experts' grouped feed-forward and one shared expert.  The
+  bias, the held experts' grouped feed-forward and, unless told not to,
+  one shared expert.  The
   layer is told which experts it holds (``held_experts=(first, count)``):
   the router keeps the model's width, selection and normalisation run over
   all experts, and the layer computes its own experts' part of the sum:
   one chip's share of an expert-parallel layer, without the exchange.
-- :class:`MLAMoEBlock`: ``h += MLA(norm(h)); h += FFN(norm(h))``, its
-  forward recomputed in the backward pass while a step is staged.
-- :class:`MLAMoELM`: embedding, blocks, final norm, and one multi-token
-  prediction module that shares embedding and head.  It returns the two
-  streams' normed hidden states; ``net.head`` makes logits of them, and
-  :class:`MultiTokenLoss` fuses the head with the loss of both terms over
-  token chunks.
+- :class:`DecoderBlock`: ``h += mixer(norm(h)); h += ffn(norm(h))`` over
+  the token mixer and the feed-forward it is given, its forward recomputed
+  in the backward pass while a step is staged.  The one block class of
+  every decoder model here (``gluon/nn/hybrid_lm.py`` has the other
+  mixers).
+- :class:`DecoderLM`: embedding, blocks, final norm and a head that may be
+  tied to the embedding; it returns the normed hidden states,
+  ``net.head`` makes logits of them, and :class:`NextTokenLoss` fuses the
+  head with the loss over token chunks.
+- :class:`MLAMoELM`: a :class:`DecoderLM` of latent-attention blocks with
+  one multi-token prediction module that shares embedding and head.  It
+  returns the two streams' normed hidden states;
+  :class:`MultiTokenLoss` fuses the head with the loss of both terms.
 
 Named scopes (``xray.scope``): ``mla.proj``, ``mla.attention``,
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
@@ -41,7 +48,8 @@ from ..block import Block, HybridBlock, recomputed, update_aux_state
 from .basic_layers import Dense, Embedding
 
 __all__ = ["RMSNorm", "GatedFFN", "MLAttention", "RoutedExperts",
-           "MLAMoEBlock", "MLAMoELM", "MultiTokenLoss"]
+           "DecoderBlock", "DecoderLM", "MLAMoELM", "NextTokenLoss",
+           "MultiTokenLoss"]
 
 # at a quarter of the scores' spread (0.05) the bias alone made the busiest
 # expert 4-7 times the mean at random weights (PERF.md, PR 28)
@@ -128,7 +136,8 @@ class MLAttention(HybridBlock):
 
 
 class RoutedExperts(HybridBlock):
-    """Routed experts with one shared expert; see the module docstring.
+    """Routed experts, with one shared expert unless ``shared`` is false;
+    see the module docstring.
 
     ``num_experts``: the router's width (the model's experts);
     ``held_experts`` ``(first, count)``: the consecutive expert ids held
@@ -136,11 +145,17 @@ class RoutedExperts(HybridBlock):
     training loop that balances the load owns it); it is drawn N(0,
     ``ROUTER_BIAS_STD``), non-zero so that it takes part in the selection,
     small against the scores' spread (~0.2) so that it does not decide
-    it."""
+    it.  ``route_epsilon``: what ``moe_route`` adds to the selected scores'
+    sum.  ``bias_update_rate``: when not zero, a training step ends with the
+    balancing rule of DeepSeek-V3 section 2.1.2 (auxiliary-loss-free): the
+    bias of an expert that got more than the mean of the batch's choices
+    goes down by the rate, of one that got less up; the new bias leaves the
+    step with its state, like a batch norm's running statistics."""
 
     def __init__(self, units, hidden_size, num_experts, experts_per_token,
                  held_experts=None, routed_scaling_factor=1.0,
-                 weight_std=0.02, **kwargs):
+                 weight_std=0.02, shared=True, route_epsilon=1e-20,
+                 bias_update_rate=0.0, **kwargs):
         super().__init__(**kwargs)
         first, held = held_experts or (0, num_experts)
         if first < 0 or held < 1 or first + held > num_experts:
@@ -148,6 +163,8 @@ class RoutedExperts(HybridBlock):
                              % ((first, held), num_experts))
         self._first, self._held = int(first), int(held)
         self._k, self._scale = experts_per_token, routed_scaling_factor
+        self._route_epsilon = route_epsilon
+        self._experts, self._bias_update_rate = num_experts, bias_update_rate
         init = _init.Normal(weight_std)
         with self.name_scope():
             self.router_weight = self.params.get(
@@ -169,7 +186,7 @@ class RoutedExperts(HybridBlock):
             self.max_load = self.params.get(
                 "max_load", shape=(1,), grad_req="null", init="zeros")
             self.shared = GatedFFN(units, hidden_size, weight_std=weight_std,
-                                   prefix="shared_")
+                                   prefix="shared_") if shared else None
 
     def hybrid_forward(self, F, x, router_weight, router_bias,
                        experts_gate_weight, experts_up_weight,
@@ -178,56 +195,129 @@ class RoutedExperts(HybridBlock):
 
         rows = F.reshape(x, shape=(-1, x.shape[-1]))
         ids, weights = F.contrib.moe_route(
-            rows, router_weight, router_bias, k=self._k, scale=self._scale)
+            rows, router_weight, router_bias, k=self._k, scale=self._scale,
+            eps=self._route_epsilon)
         y, pairs, load = F.contrib.moe_experts(
             rows, ids, weights, experts_gate_weight, experts_up_weight,
             experts_down_weight, first_expert=self._first)
         if autograd.is_training():
             update_aux_state(self.held_pairs, F.reshape(pairs, shape=(1,)))
             update_aux_state(self.max_load, F.reshape(load, shape=(1,)))
-        with _xray.scope("moe.shared"):
-            y = y + self.shared(rows)
+            if self._bias_update_rate:
+                with _xray.scope("moe.route"):
+                    chosen = F.sum(F.one_hot(F.reshape(ids, shape=(-1,)),
+                                             depth=self._experts), axis=0)
+                    update_aux_state(
+                        self.router_bias,
+                        router_bias - self._bias_update_rate * F.sign(
+                            chosen - F.mean(chosen, keepdims=True)))
+        if self.shared is not None:
+            with _xray.scope("moe.shared"):
+                y = y + self.shared(rows)
         return F.reshape(y, shape=x.shape)
 
 
-class MLAMoEBlock(HybridBlock):
-    """One decoder block: ``h += MLA(norm(h)); h += FFN(norm(h))``, the
-    feed-forward dense (``dense_size``) or routed (``moe``: keyword
-    arguments of :class:`RoutedExperts`).  While a step is staged only the
+class DecoderBlock(HybridBlock):
+    """One decoder block: ``h += mixer(norm(h)); h += ffn(norm(h))``.
+    ``mixer`` and ``ffn`` are called without arguments inside the block's
+    name scope and return the token mixer (:class:`MLAttention`, or the
+    grouped-query attention and the short convolution of
+    ``gluon/nn/hybrid_lm.py``) and the feed-forward (:class:`GatedFFN`,
+    :class:`RoutedExperts`).  While a step is staged only the
     block's input and its attention kernel's results are kept for the
     backward pass, which runs the rest of the forward again
     (``gluon.block.recomputed``): a block's activations are thirty times
     its input, and a model of this kind fills a chip with its state."""
 
-    def __init__(self, units, attention, dense_size=None, moe=None,
-                 epsilon=1e-6, weight_std=0.02, **kwargs):
+    def __init__(self, units, mixer, ffn, epsilon=1e-6, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.ln1 = RMSNorm(units, epsilon, prefix="ln1_")
-            self.attn = MLAttention(units, epsilon=epsilon,
-                                    weight_std=weight_std, prefix="attn_",
-                                    **attention)
+            self.mixer = mixer()
             self.ln2 = RMSNorm(units, epsilon, prefix="ln2_")
-            if moe is None:
-                self.ffn = GatedFFN(units, dense_size, weight_std=weight_std,
-                                    prefix="ffn_")
-            else:
-                self.ffn = RoutedExperts(units, weight_std=weight_std,
-                                         prefix="moe_", **moe)
+            self.ffn = ffn()
 
     def _body(self, h):
-        h = h + self.attn(self.ln1(h))
+        h = h + self.mixer(self.ln1(h))
         return h + self.ffn(self.ln2(h))
 
     def hybrid_forward(self, F, h):
         return recomputed(self._body, h)
 
 
-class MLAMoELM(HybridBlock):
-    """Decoder-only language model of :class:`MLAMoEBlock`s with one
-    multi-token prediction module (DeepSeek-V3 section 2.2).
+def feed_forward(units, dense_size=None, moe=None, weight_std=0.02):
+    """What :class:`DecoderBlock` takes as ``ffn``: the dense feed-forward
+    of ``dense_size``, or the routed one (``moe``: keyword arguments of
+    :class:`RoutedExperts`)."""
+    if moe is None:
+        return lambda: GatedFFN(units, dense_size, weight_std=weight_std,
+                                prefix="ffn_")
+    return lambda: RoutedExperts(units, weight_std=weight_std,
+                                 prefix="moe_", **moe)
+
+
+# the parameters that write to the residual stream, by the end of their names
+TO_THE_STREAM = ("o_weight", "out_weight", "down_weight")
+
+
+class DecoderLM(HybridBlock):
+    """Decoder-only language model: embedding, :class:`DecoderBlock`s, final
+    norm, head.
 
     Input ``(batch, seq)`` integer token ids.  Result: the normed hidden
+    states ``(batch, seq, units)``; ``head(hidden)[i]`` are the logits for
+    token ``i + 1`` (:class:`NextTokenLoss` applies the head fused with the
+    loss).  ``blocks``: one ``(mixer, ffn)`` pair of :class:`DecoderBlock`
+    arguments per layer.  ``tie_embedding``: the head reads the embedding's
+    weight, one parameter with two uses.
+
+    Weights are drawn N(0, ``weight_std``), the projections that write to
+    the residual stream (``TO_THE_STREAM``) N(0, ``weight_std / sqrt(2 x
+    stream_blocks)``) as in GPT-2 and Megatron-LM: unscaled, attention's
+    running mean of the values dominates the stream and the tokens of a
+    row route alike."""
+
+    def __init__(self, vocab_size, hidden_size, blocks, epsilon=1e-6,
+                 weight_std=0.02, tie_embedding=False, **kwargs):
+        super().__init__(**kwargs)
+        self._weight_std = weight_std
+        init = _init.Normal(weight_std)
+        with self.name_scope():
+            self.embed = Embedding(vocab_size, hidden_size, prefix="embed_",
+                                   weight_initializer=init)
+            self.blocks = []
+            for i, (mixer, ffn) in enumerate(blocks):
+                blk = DecoderBlock(hidden_size, mixer, ffn, epsilon=epsilon,
+                                   prefix="l%d_" % i)
+                self.register_child(blk, "l%d" % i)
+                self.blocks.append(blk)
+            self.norm = RMSNorm(hidden_size, epsilon, prefix="norm_")
+            self.head = Dense(vocab_size, use_bias=False, flatten=False,
+                              in_units=hidden_size, prefix="head_",
+                              weight_initializer=init,
+                              params=self.embed.params if tie_embedding
+                              else None)
+        self.scale_stream_writers(len(self.blocks))
+
+    def scale_stream_writers(self, stream_blocks):
+        to_the_stream = _init.Normal(
+            self._weight_std / (2 * stream_blocks) ** 0.5)
+        for name, param in self.collect_params().items():
+            if name.endswith(TO_THE_STREAM):
+                param.init = to_the_stream
+
+    def hybrid_forward(self, F, tokens):
+        h = self.embed(tokens)
+        for blk in self.blocks:
+            h = blk(h)
+        return self.norm(h)
+
+
+class MLAMoELM(DecoderLM):
+    """:class:`DecoderLM` of latent-attention blocks with one multi-token
+    prediction module (DeepSeek-V3 section 2.2).
+
+    Result: the normed hidden
     states ``(main, mtp)``, each ``(batch, seq, units)``: ``head(main)[i]``
     are the logits for token ``i + 1``, ``head(mtp)[i]`` for token ``i +
     2``.  The MTP module: ``u_i = W_eh [norm(embed(t_{i+1})); norm(h_i)]``
@@ -237,12 +327,7 @@ class MLAMoELM(HybridBlock):
     causal attention keeps from every other position and the loss leaves
     out.
 
-    Weights are drawn N(0, ``weight_std``), the projections that write to
-    the residual stream (attention's ``o_weight``, every ``down_weight``)
-    N(0, ``weight_std / sqrt(2 x blocks)``) as in GPT-2 and Megatron-LM:
-    unscaled, attention's running mean of the values dominates the stream
-    and the tokens of a row route alike.  The keyword arguments carry the
-    names of the model's ``config.json``.
+    The keyword arguments carry the names of the model's ``config.json``.
     ``held_experts`` ``(first, count)`` gives this chip's share of every
     routed layer."""
 
@@ -254,35 +339,28 @@ class MLAMoELM(HybridBlock):
                  held_experts=None, routed_scaling_factor=1.0,
                  rope_theta=10000.0, rms_norm_eps=1e-6, weight_std=0.02,
                  **kwargs):
-        super().__init__(**kwargs)
-        attention = dict(
-            num_heads=num_attention_heads, q_lora_rank=q_lora_rank,
-            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
-            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
-            rope_theta=rope_theta)
-        moe = dict(
+        def attention():
+            return MLAttention(
+                hidden_size, num_heads=num_attention_heads,
+                q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+                qk_nope_head_dim=qk_nope_head_dim,
+                qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+                rope_theta=rope_theta, epsilon=rms_norm_eps,
+                weight_std=weight_std, prefix="attn_")
+
+        routed = feed_forward(hidden_size, weight_std=weight_std, moe=dict(
             hidden_size=moe_intermediate_size, num_experts=router_outputs,
             experts_per_token=num_experts_per_tok,
             held_experts=held_experts and tuple(held_experts),
-            routed_scaling_factor=routed_scaling_factor)
-        block = dict(units=hidden_size, attention=attention,
-                     epsilon=rms_norm_eps, weight_std=weight_std)
-        init = _init.Normal(weight_std)
+            routed_scaling_factor=routed_scaling_factor))
+        dense = feed_forward(hidden_size, intermediate_size,
+                             weight_std=weight_std)
+        super().__init__(
+            vocab_size, hidden_size,
+            [(attention, dense if i < first_k_dense_replace else routed)
+             for i in range(num_hidden_layers)],
+            epsilon=rms_norm_eps, weight_std=weight_std, **kwargs)
         with self.name_scope():
-            self.embed = Embedding(vocab_size, hidden_size, prefix="embed_",
-                                   weight_initializer=init)
-            self.blocks = []
-            for i in range(num_hidden_layers):
-                dense = i < first_k_dense_replace
-                blk = MLAMoEBlock(
-                    dense_size=intermediate_size if dense else None,
-                    moe=None if dense else moe, prefix="l%d_" % i, **block)
-                self.register_child(blk, "l%d" % i)
-                self.blocks.append(blk)
-            self.norm = RMSNorm(hidden_size, rms_norm_eps, prefix="norm_")
-            self.head = Dense(vocab_size, use_bias=False, flatten=False,
-                              in_units=hidden_size, prefix="head_",
-                              weight_initializer=init)
             self.mtp_enorm = RMSNorm(hidden_size, rms_norm_eps,
                                      prefix="mtp_enorm_")
             self.mtp_hnorm = RMSNorm(hidden_size, rms_norm_eps,
@@ -290,15 +368,13 @@ class MLAMoELM(HybridBlock):
             self.mtp_proj = Dense(hidden_size, use_bias=False, flatten=False,
                                   in_units=2 * hidden_size,
                                   prefix="mtp_proj_",
-                                  weight_initializer=init)
-            self.mtp_blk = MLAMoEBlock(moe=moe, prefix="mtp_blk_", **block)
+                                  weight_initializer=_init.Normal(weight_std))
+            self.mtp_blk = DecoderBlock(hidden_size, attention, routed,
+                                        epsilon=rms_norm_eps,
+                                        prefix="mtp_blk_")
             self.mtp_norm = RMSNorm(hidden_size, rms_norm_eps,
                                     prefix="mtp_norm_")
-        blocks = num_hidden_layers + 1          # the MTP module's too
-        to_the_stream = _init.Normal(weight_std / (2 * blocks) ** 0.5)
-        for name, param in self.collect_params().items():
-            if name.endswith(("o_weight", "down_weight")):
-                param.init = to_the_stream
+        self.scale_stream_writers(num_hidden_layers + 1)    # the MTP's too
 
     def hybrid_forward(self, F, tokens):
         h = self.embed(tokens)
@@ -314,15 +390,51 @@ class MLAMoELM(HybridBlock):
         return self.norm(h), u
 
 
+def _head_loss(F, head, hidden, tokens, ahead, scale=1.0):
+    """``scale * CE(head(hidden_i), t_{i + ahead})``, a mean over the valid
+    positions of a row, the head's product fused with the loss over chunks
+    of tokens (``ops/llm.py::linear_cross_entropy``); one value per row."""
+    batch, seq = tokens.shape
+    # token i + ahead labels position i; the row's last ``ahead``
+    # positions have no label
+    labels = F.concat(
+        F.slice_axis(tokens, axis=1, begin=ahead, end=None),
+        F.full((batch, ahead), -1, dtype=tokens.dtype), dim=1)
+    rows = F.contrib.linear_cross_entropy(
+        F.reshape(hidden, shape=(-1, hidden.shape[-1])), head.weight.data(),
+        F.reshape(labels, shape=(-1,)))
+    return F.sum(F.reshape(rows, shape=(batch, seq)), axis=1) \
+        * (scale / (seq - ahead))
+
+
+class NextTokenLoss(Block):
+    """``CE(head(hidden_i), t_{i+1})``, a mean over a row's valid
+    positions; one value per row.
+
+    ``head``: the model's output projection (``DecoderLM.head``; with a
+    tied head its weight is the embedding's), applied here, fused with the
+    loss over chunks of tokens so that the float32 logits never stand
+    whole.  The labels are the input's own rows of token ids."""
+
+    def __init__(self, head, **kwargs):
+        super().__init__(**kwargs)
+        self.__dict__["_head"] = head       # the model's block, not a child
+
+    def forward(self, hidden, tokens):
+        from ... import ndarray as F
+
+        with _xray.scope("lm_head"):
+            return _head_loss(F, self._head, hidden, tokens, 1)
+
+
 class MultiTokenLoss(Block):
     """``CE(head(main_i), t_{i+1}) + weight * CE(head(mtp_i), t_{i+2})``,
     each a mean over its valid positions of a row; one value per row.
 
     ``head``: the model's output projection (``MLAMoELM.head``), applied
     here, fused with the loss over chunks of tokens, so that the float32
-    logits of neither head stand whole
-    (``ops/llm.py::linear_cross_entropy``).  The labels are the input's
-    own rows of token ids."""
+    logits of neither head stand whole.  The labels are the input's own
+    rows of token ids."""
 
     def __init__(self, head, weight=0.3, **kwargs):
         super().__init__(**kwargs)
@@ -333,21 +445,6 @@ class MultiTokenLoss(Block):
         from ... import ndarray as F
 
         main, mtp = streams
-        batch, seq = tokens.shape
-        weight = self._head.weight.data()
-        total = None
         with _xray.scope("lm_head"):
-            for hidden, ahead, scale in ((main, 1, 1.0),
-                                         (mtp, 2, self._weight)):
-                # token i + ahead labels position i; the row's last
-                # ``ahead`` positions have no label
-                labels = F.concat(
-                    F.slice_axis(tokens, axis=1, begin=ahead, end=None),
-                    F.full((batch, ahead), -1, dtype=tokens.dtype), dim=1)
-                rows = F.contrib.linear_cross_entropy(
-                    F.reshape(hidden, shape=(-1, hidden.shape[-1])), weight,
-                    F.reshape(labels, shape=(-1,)))
-                term = F.sum(F.reshape(rows, shape=(batch, seq)), axis=1) \
-                    * (scale / (seq - ahead))
-                total = term if total is None else total + term
-        return total
+            return _head_loss(F, self._head, main, tokens, 1) \
+                + _head_loss(F, self._head, mtp, tokens, 2, self._weight)

@@ -14,7 +14,11 @@ the kernels do the causal work only: a grid step above the diagonal
 fetches nothing and computes nothing, and a block on the diagonal is
 computed in tiles below it.
 
-Layout: (batch, heads, seq, head_dim) throughout.
+Layout: (batch, heads, seq, head_dim) throughout.  Grouped-query heads:
+``k`` and ``v`` may have ``heads // group`` heads; query head ``h`` reads
+key head ``h // group`` through the kernels' index maps (no copy of a key
+head is made in HBM), and the dk/dv kernel sums over a group's query heads
+in its accumulators.
 
 Public entry points
 -------------------
@@ -61,6 +65,12 @@ from .registry import register
 _WIDE_BLOCKS = (1024, 1024)
 _NARROW_BLOCKS = (256, 512)
 _WIDE_ROW_BYTES = 1024
+# Float32 operands at jax's ``highest`` matmul precision (a benchmark cell's
+# check) are multiplied in six bfloat16 passes over split copies of a tile:
+# at 1,024 / 1,024 the kernels then need 19-22 MiB of scoped VMEM (head
+# sizes 64 / 64 and 128 / 128, compiled for a v5e), so 4-byte operands ask
+# for more than the default 16.
+_FLOAT32_VMEM_BYTES = 32 * 2 ** 20
 _TRIANGLE_TILE = 256
 _NEG_INF = -1e30
 
@@ -73,6 +83,9 @@ def mha_reference(q, k, v, causal=False, sm_scale=None):
     """Unfused attention: softmax(q k^T * scale) v, fp32 accumulation."""
     d = q.shape[-1]
     scale = (1.0 / math.sqrt(d)) if sm_scale is None else sm_scale
+    group = _group(q, k)
+    if group > 1:       # the oracle repeats the key heads; the kernels do not
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -83,6 +96,30 @@ def mha_reference(q, k, v, causal=False, sm_scale=None):
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _scoped_vmem(operand):
+    """``compiler_params`` of a kernel over ``operand``'s dtype: Mosaic's
+    default but for 4-byte operands (``_FLOAT32_VMEM_BYTES``)."""
+    if operand.dtype.itemsize < 4:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_FLOAT32_VMEM_BYTES)
+
+
+def _group(q, k):
+    """Query heads per key head of ``q (b, h, ..)`` and ``k (b, hk, ..)``."""
+    heads, kv_heads = q.shape[1], k.shape[1]
+    if heads % kv_heads:
+        raise MXNetError("flash_attention: %d query heads are not a multiple "
+                         "of %d key heads" % (heads, kv_heads))
+    return heads // kv_heads
+
+
+def _kv_head(z, group):
+    """The row of ``k (b * hk, ..)`` that row ``z`` of ``q (b * h, ..)``
+    reads: ``h = hk * group``, so ``b h + head`` maps to ``b hk + head //
+    group``."""
+    return z if group == 1 else z // group
 
 
 # ---------------------------------------------------------------------------
@@ -235,12 +272,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_ref[:] + jnp.log(l)
 
 
-def _kv_spec(block_k, width, causal, block_q, num_k):
-    """Key / value blocks of the forward and dq kernels' grid."""
+def _kv_spec(block_k, width, causal, block_q, num_k, group):
+    """Key / value blocks of the forward and dq kernels' grid (its rows are
+    query heads)."""
     def index(z, i, j):
         if causal:
             j = _kv_block(i, j, block_q, block_k, num_k)
-        return (z, j, 0)
+        return (_kv_head(z, group), j, 0)
     return pl.BlockSpec((1, block_k, width), index)
 
 
@@ -250,10 +288,10 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     64 x 4,096, kept from the forward to the backward pass)."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    bh = b * h
+    bh, group = b * h, _group(q, k)
     qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, dv)
+    kr = k.reshape(bh // group, sk, d)
+    vr = v.reshape(bh // group, sk, dv)
     num_q = sq // block_q
     num_k = sk // block_k
 
@@ -265,8 +303,8 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         grid=(bh, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
-            _kv_spec(block_k, d, causal, block_q, num_k),
-            _kv_spec(block_k, dv, causal, block_q, num_k),
+            _kv_spec(block_k, d, causal, block_q, num_k, group),
+            _kv_spec(block_k, dv, causal, block_q, num_k, group),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
@@ -281,6 +319,7 @@ def _fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
         ],
+        compiler_params=_scoped_vmem(q),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, h, sq, dv), lse
@@ -320,12 +359,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, sm_scale, causal, block_q, block_k, num_q):
-    """Grid = (bh, num_k, num_q): accumulate dk/dv over Q blocks."""
+                    *, sm_scale, causal, block_q, block_k, num_q, group):
+    """Grid = (b * key heads, num_k, group * num_q): accumulate dk/dv over
+    the Q blocks of every query head of the key head's group, head after
+    head."""
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    step_id = pl.program_id(2)
+    qi = step_id if group == 1 else step_id % num_q
 
-    @pl.when(qi == 0)
+    @pl.when(step_id == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -344,30 +386,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _causal_steps(step, causal, qi, kj, block_q, block_k)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step_id == group * num_q - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd_operands(q, k, v, o, lse, do):
-    """The two backward kernels' operands: (bh, seq, head) each, lse and
-    delta a row a head, (bh, 1, sq)."""
+    """The two backward kernels' operands: (bh, seq, head) each (``k`` and
+    ``v`` with their own, fewer, heads), lse and delta a row a query head,
+    (bh, 1, sq)."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    bh = b * h
+    bh, bhk = b * h, b * k.shape[1]
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise pass, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, sq)
-    return (q.reshape(bh, sq, d), k.reshape(bh, sk, d), v.reshape(bh, sk, dv),
-            do.reshape(bh, sq, dv), lse, delta)
+    return (q.reshape(bh, sq, d), k.reshape(bhk, sk, d),
+            v.reshape(bhk, sk, dv), do.reshape(bh, sq, dv), lse, delta)
 
 
 def _dq_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
     qr, _, vr = operands[:3]
     bh, sq, d = qr.shape
     sk, dv = vr.shape[1:]
-    num_k = sk // block_k
+    num_k, group = sk // block_k, bh // vr.shape[0]
     rows = pl.BlockSpec((1, 1, block_q), lambda z, i, j: (z, 0, i))
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -375,14 +418,15 @@ def _dq_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
         grid=(bh, sq // block_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
-            _kv_spec(block_k, d, causal, block_q, num_k),
-            _kv_spec(block_k, dv, causal, block_q, num_k),
+            _kv_spec(block_k, d, causal, block_q, num_k, group),
+            _kv_spec(block_k, dv, causal, block_q, num_k, group),
             pl.BlockSpec((1, block_q, dv), lambda z, i, j: (z, i, 0)),
             rows, rows,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda z, i, j: (z, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
+        compiler_params=_scoped_vmem(qr),
         interpret=interpret,
     )(*operands)
 
@@ -390,22 +434,29 @@ def _dq_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
 def _dkv_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
     qr, kr, vr = operands[:3]
     bh, sq, d = qr.shape
-    sk, dv = vr.shape[1:]
-    num_q = sq // block_q
+    bhk, sk, dv = vr.shape
+    num_q, group = sq // block_q, bh // bhk
 
-    def q_block(i, j):
+    # grid step (z, j, t): key head z, key block j, and t counts the query
+    # blocks of the group's heads, head after head
+    def q_head(z, t):
+        return z if group == 1 else z * group + t // num_q
+
+    def q_block(t, j):
+        i = t if group == 1 else t % num_q
         return _q_block(i, j, block_q, block_k, num_q) if causal else i
 
     def q_spec(width):
         return pl.BlockSpec((1, block_q, width),
-                            lambda z, j, i: (z, q_block(i, j), 0))
+                            lambda z, j, t: (q_head(z, t), q_block(t, j), 0))
 
     rows = pl.BlockSpec((1, 1, block_q),
-                        lambda z, j, i: (z, 0, q_block(i, j)))
+                        lambda z, j, t: (q_head(z, t), 0, q_block(t, j)))
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q=num_q),
-        grid=(bh, sk // block_k, num_q),
+                          block_q=block_q, block_k=block_k, num_q=num_q,
+                          group=group),
+        grid=(bhk, sk // block_k, group * num_q),
         in_specs=[
             q_spec(d),
             pl.BlockSpec((1, block_k, d), lambda z, j, i: (z, j, 0)),
@@ -418,13 +469,14 @@ def _dkv_pallas(operands, sm_scale, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_k, dv), lambda z, j, i: (z, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), kr.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv), vr.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, d), kr.dtype),
+            jax.ShapeDtypeStruct((bhk, sk, dv), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
+        compiler_params=_scoped_vmem(qr),
         interpret=interpret,
     )(*operands)
 
@@ -493,7 +545,12 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     """Fused attention over (batch, heads, seq, head_dim) arrays.  ``v``
     (and so the output) may have another head size than ``q`` and ``k``
     (latent attention trains with 192 for the scores and 128 for the
-    values); ``sm_scale`` defaults to the scores' head size.
+    values); ``sm_scale`` defaults to the scores' head size.  ``k`` and
+    ``v`` may have fewer heads than ``q`` (grouped-query attention: ``heads
+    // group`` of them, query head ``h`` reads key head ``h // group``);
+    the kernels read a key head once per query head from where it lies,
+    and ``dk`` / ``dv`` come back with the key heads' shape, summed over
+    each group inside the kernel.
 
     The products run in the inputs' dtype with float32 accumulation: for
     bfloat16 inputs the only roundings beyond the reference's are those

@@ -1,10 +1,13 @@
 """Operators of current decoder language models (docs/LLM_OPS.md).
 
-RMS norm, rotary position embedding, the gated-SiLU feed-forward, the
-router and the held-experts layer of a sigmoid-scored mixture of experts
-(DeepSeek-V3, arXiv:2412.19437), and a linear head fused with its
-cross-entropy over token chunks.  The reference framework has none of
-them (its transformer helpers are ``src/operator/contrib/transformer.cc``).
+RMS norm, rotary position embedding (adjacent pairs or halves), the
+gated-SiLU feed-forward, the projections of latent attention and of
+grouped-query attention with a norm on every head, a gated short causal
+convolution over the sequence, the router and the held-experts layer of a
+sigmoid-scored mixture of experts (DeepSeek-V3, arXiv:2412.19437), and a
+linear head fused with its cross-entropy over token chunks.  The reference
+framework has none of them (its transformer helpers are
+``src/operator/contrib/transformer.cc``).
 
 The expert layer is told which experts it holds: the router scores all
 of the model's experts, selection and normalisation run over all of them,
@@ -29,8 +32,9 @@ from jax import lax
 from .. import xray as _xray
 from .registry import OP_INPUT_NAMES, register
 
-__all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "moe_route",
-           "moe_experts", "linear_cross_entropy", "expert_tiles"]
+__all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
+           "gqa_out", "gated_short_conv", "moe_route", "moe_experts",
+           "linear_cross_entropy", "expert_tiles"]
 
 # rows of one expert tile.  A tile costs its expert's three weights read
 # (twice in the backward pass) and their three float32 gradients read and
@@ -51,35 +55,47 @@ def rms_norm(data, gamma, eps=1e-6, **_):
     return (x * scale * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
-def _rotary_tables(seq, dim, theta):
+def _rotary_tables(seq, dim, theta, halves=False):
     """``cos``, ``sin`` of ``p * theta^(-2i/dim)`` for positions ``p <
-    seq``, each repeated over its pair of lanes, the sine negative on a
-    pair's first lane: ``(seq, dim)`` float32 constants from a float64
-    host table (float32 angles at position 4096 and theta 3.2e7 are wrong
-    in the fourth digit)."""
+    seq``, each on both lanes of its pair, the sine negative on a pair's
+    first lane: ``(seq, dim)`` float32 constants from a float64 host table
+    (float32 angles at position 4096 and theta 3.2e7 are wrong in the
+    fourth digit).  A pair is the adjacent lanes ``(2i, 2i + 1)``, or with
+    ``halves`` the lanes ``(i, i + dim / 2)``."""
     f64 = _np.float64  # mxlint: disable=dtype-default -- host table, cast below
     inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
     angle = _np.arange(seq, dtype=f64)[:, None] * inv[None, :]
-    cos = _np.repeat(_np.cos(angle), 2, axis=-1)
-    sin = _np.repeat(_np.sin(angle), 2, axis=-1)
-    sin[:, 0::2] *= -1
+    if halves:
+        cos = _np.tile(_np.cos(angle), 2)
+        sin = _np.tile(_np.sin(angle), 2)
+        sin[:, :dim // 2] *= -1
+    else:
+        cos = _np.repeat(_np.cos(angle), 2, axis=-1)
+        sin = _np.repeat(_np.sin(angle), 2, axis=-1)
+        sin[:, 0::2] *= -1
     return jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
 
 
-def _swap_pairs(x):
-    """``x[..., 2i] <-> x[..., 2i + 1]`` in float32, as a product with a
+def _swap_pairs(x, halves=False):
+    """``x[..., 2i] <-> x[..., 2i + 1]`` (with ``halves``: ``x[..., i] <->
+    x[..., i + dim / 2]``) in float32, as a product with a
     0 / 1 permutation: one term a result, so it is exact (float32
     operands take the MXU's exact passes).  On a v5e XLA makes one fusion
     of it and the arithmetic around it; lanes taken by stride become
     gathers (scatters in the gradient), and rolled by one, four sliced
     float32 copies (PERF.md, PR 31).  The permutation is at most 128
-    wide: a wider last axis is cut into equal parts."""
+    wide: a wider last axis is cut into equal parts (halves wider than
+    that are two slices put back the other way round)."""
     dim = x.shape[-1]
-    width = next(w for w in range(min(dim, 128), 1, -1)
-                 if dim % w == 0 and w % 2 == 0)
+    if halves and dim > 128:
+        return jnp.concatenate([x[..., dim // 2:], x[..., :dim // 2]],
+                               axis=-1).astype(jnp.float32)
+    width = dim if halves else next(
+        w for w in range(min(dim, 128), 1, -1)
+        if dim % w == 0 and w % 2 == 0)
     lane = _np.arange(width, dtype=_np.int32)
     perm = _np.zeros((width, width), dtype=_np.float32)
-    perm[lane, lane ^ 1] = 1
+    perm[lane, (lane + width // 2) % width if halves else lane ^ 1] = 1
     parts = x.reshape(x.shape[:-1] + (dim // width, width))
     out = lax.dot_general(
         parts, jnp.asarray(perm, x.dtype), (((parts.ndim - 1,), (0,)),
@@ -88,44 +104,46 @@ def _swap_pairs(x):
     return out.reshape(x.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rotary(x, cos, sin, start):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rotary(x, cos, sin, start, halves=False):
     """Lanes ``start:`` of ``x (..., seq, dim)`` rotated pair by pair by
-    the tables of ``_rotary_tables``, float32 arithmetic, the lanes before
-    ``start`` left as they are: written in place of ``x`` where the
-    compiler may."""
+    the tables of ``_rotary_tables`` (made with the same ``halves``),
+    float32 arithmetic, the lanes before ``start`` left as they are:
+    written in place of ``x`` where the compiler may."""
     tail = x[..., start:]
-    out = (tail.astype(jnp.float32) * cos + _swap_pairs(tail) * sin).astype(
-        x.dtype)
+    out = (tail.astype(jnp.float32) * cos
+           + _swap_pairs(tail, halves) * sin).astype(x.dtype)
     if not start:
         return out
     return lax.dynamic_update_slice_in_dim(x, out, start, axis=x.ndim - 1)
 
 
-def _rotary_fwd(x, cos, sin, start):
-    return _rotary(x, cos, sin, start), (cos, sin)
+def _rotary_fwd(x, cos, sin, start, halves):
+    return _rotary(x, cos, sin, start, halves), (cos, sin)
 
 
-def _rotary_bwd(start, tables, g):
+def _rotary_bwd(start, halves, tables, g):
     # a rotation's transpose is the rotation back; the lanes before
     # ``start`` pass through
     cos, sin = tables
-    return _rotary(g, cos, -sin, start), None, None
+    return _rotary(g, cos, -sin, start, halves), None, None
 
 
 _rotary.defvjp(_rotary_fwd, _rotary_bwd)
 
 
 @register("_contrib_rope", aliases=("rope",))
-def rope(data, theta=10000.0, **_):
+def rope(data, theta=10000.0, halves=False, **_):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(..., seq, dim)``: position ``p`` rotates the adjacent pair ``(2i,
-    2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``):
+    2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``), or with
+    ``halves`` the pair ``(i, i + dim/2)`` ("rotate half"):
     ``x * cos + swap_pairs(x) * sin`` in float32, the angles constants
     computed in float64 when the op is traced; its gradient is the
     rotation back."""
-    cos, sin = _rotary_tables(data.shape[-2], data.shape[-1], theta)
-    return _rotary(data, cos, sin, 0)
+    halves = bool(halves)
+    cos, sin = _rotary_tables(data.shape[-2], data.shape[-1], theta, halves)
+    return _rotary(data, cos, sin, 0, halves)
 
 
 def _dot(a, b, contract):
@@ -195,10 +213,10 @@ def mla_qkv(data, qa_weight, qb_weight, kva_weight, kvb_weight,
         cos, sin = _rotary_tables(data.shape[-2], rot, theta)
         c_q = rms_norm(jnp.matmul(data, qa_weight.T), qnorm_weight, eps=eps)
         q = _by_head(c_q, qb_weight.reshape(heads, nope + rot, -1))
-        q = _rotary(q, cos, sin, nope)
+        q = _rotary(q, cos, sin, nope, False)
         kva = jnp.matmul(data, kva_weight.T)
         c_kv = rms_norm(kva[..., :rank], kvnorm_weight, eps=eps)
-        k_rot = _rotary(kva[..., rank:], cos, sin, 0)
+        k_rot = _rotary(kva[..., rank:], cos, sin, 0, False)
         kvb = kvb_weight.reshape(heads, -1, rank)
         k_nope = _by_head(c_kv, kvb[:, :nope])
         k = jnp.concatenate(
@@ -208,24 +226,139 @@ def mla_qkv(data, qa_weight, qb_weight, kva_weight, kvb_weight,
         return q, k, v
 
 
+def _heads_out(data, weight):
+    """``data (B, heads, S, v)`` contracted over (head, value) with
+    ``weight (units, heads * v)`` -> ``(B, S, units)``; no transposed copy
+    of ``data``, and its gradient is written ``(B, heads, S, v)``."""
+    _, heads, _, width = data.shape
+    return jnp.einsum("bhsv,uhv->bsu", _row_major(data),
+                      weight.reshape(-1, heads, width))
+
+
 @register("_contrib_mla_out", aliases=("mla_out",))
 def mla_out(data, weight, **_):
-    """Attention's output projection from the kernel's own result:
-    ``data (B, heads, S, v)`` contracted over (head, value) with ``weight
-    (units, heads * v)`` -> ``(B, S, units)``; no transposed copy of
-    ``data``, and its gradient is written ``(B, heads, S, v)``."""
-    _, heads, _, width = data.shape
+    """Latent attention's output projection from the kernel's own result
+    (``_heads_out``), under the scope ``mla.proj``."""
     with _xray.scope("mla.proj"):
-        return jnp.einsum("bhsv,uhv->bsu", _row_major(data),
-                          weight.reshape(-1, heads, width))
+        return _heads_out(data, weight)
+
+
+# ------------------------------------ grouped-query attention, head norms
+
+
+@register("_contrib_gqa_qkv", num_outputs=3, aliases=("gqa_qkv",))
+def gqa_qkv(data, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
+            theta=10000.0, eps=1e-6, **_):
+    """The projections of grouped-query attention with a norm on every
+    head (Ainslie et al., arXiv:2305.13245; the head norms of Dehghani et
+    al., arXiv:2302.05442), from the block's input ``(B, S, units)`` to
+    what ``flash_attention`` reads: ``q (B, heads, S, d)``, ``k`` and ``v``
+    ``(B, kv_heads, S, d)``, the key heads not repeated.
+
+    ``q_weight (heads * d, units)``, ``k_weight`` and ``v_weight
+    (kv_heads * d, units)``; ``qnorm_weight`` and ``knorm_weight`` ``(d,)``:
+    every head of ``q`` and of ``k`` is RMS-normalised over its ``d`` values
+    with the one learned scale, then rotated by halves (pairs ``(i, i + d /
+    2)``).  The head size is read from the norms' scales, the head counts
+    from the weights.  Every product writes ``(B, heads, S, d)`` itself."""
+    d = qnorm_weight.shape[0]
+    with _xray.scope("gqa.proj"):
+        cos, sin = _rotary_tables(data.shape[-2], d, theta, True)
+        q, k, v = (_by_head(data, w.reshape(-1, d, w.shape[-1]))
+                   for w in (q_weight, k_weight, v_weight))
+        q = _rotary(rms_norm(q, qnorm_weight, eps=eps), cos, sin, 0, True)
+        k = _rotary(rms_norm(k, knorm_weight, eps=eps), cos, sin, 0, True)
+        return q, k, v
+
+
+@register("_contrib_gqa_out", aliases=("gqa_out",))
+def gqa_out(data, weight, **_):
+    """Grouped-query attention's output projection from the kernel's own
+    result (``_heads_out``), under the scope ``gqa.proj``."""
+    with _xray.scope("gqa.proj"):
+        return _heads_out(data, weight)
+
+
+# --------------------------------------------- gated short convolution
+
+
+def _behind(u, length):
+    """``[u_{t - (length - 1) + j} for j < length]``: ``u (B, S, C)`` shifted
+    along the sequence, zero before a row's first position.  Channels stay
+    on the lanes: a tap is a shift along the sequence."""
+    seq = u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (length - 1, 0), (0, 0)))
+    return [padded[:, j:j + seq] for j in range(length)]
+
+
+def _gates(bcx):
+    f32 = jnp.float32
+    b, c, x = jnp.split(bcx, 3, axis=-1)
+    return b.astype(f32), c.astype(f32), x.astype(f32)
+
+
+@jax.custom_vjp
+def _gated_conv(bcx, weight):
+    """``c * conv(b * x)``, ``conv_t = sum_j weight[:, j] * u_{t - (L - 1)
+    + j}``: ``bcx (B, S, 3 C)``, ``weight (C, L)``."""
+    b, c, x = _gates(bcx)
+    taps = weight.T.astype(jnp.float32)
+    conv = sum(k * u for k, u in zip(taps, _behind(b * x, len(taps))))
+    return (c * conv).astype(bcx.dtype)
+
+
+def _gated_conv_fwd(bcx, weight):
+    return _gated_conv(bcx, weight), (bcx, weight)
+
+
+def _gated_conv_bwd(res, g):
+    """Nothing but the op's inputs is kept: the gate's product and the
+    convolution are three multiply-adds a value, run again here.  The
+    convolution's transpose is the same taps looking forward."""
+    bcx, weight = res
+    b, c, x = _gates(bcx)
+    taps = weight.T.astype(jnp.float32)
+    length, seq = taps.shape[0], bcx.shape[1]
+    behind = _behind(b * x, length)
+    g = g.astype(jnp.float32)
+    dconv = g * c
+    ahead = jnp.pad(dconv, ((0, 0), (0, length - 1), (0, 0)))
+    du = sum(taps[j] * ahead[:, length - 1 - j:length - 1 - j + seq]
+             for j in range(length))
+    dtaps = jnp.stack([jnp.sum(dconv * u, axis=(0, 1)) for u in behind])
+    conv = sum(k * u for k, u in zip(taps, behind))
+    dbcx = jnp.concatenate([du * x, g * conv, du * b], axis=-1)
+    return dbcx.astype(bcx.dtype), dtaps.T.astype(weight.dtype)
+
+
+_gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+@register("_contrib_gated_short_conv", aliases=("gated_short_conv",))
+def gated_short_conv(data, weight, **_):
+    """Gated short causal convolution over the sequence (the operator of
+    LIV-style hybrid models' convolution layers; Hasani et al.,
+    arXiv:2410.03137 has the family): ``data (B, S, 3 C)`` holds ``[b; c;
+    x]`` side by side on the last axis, ``weight (C, L)`` a depthwise
+    kernel of ``L`` taps; ``u = b * x``; ``conv_t = sum_j weight[:, j] *
+    u_{t - (L - 1) + j}`` with ``u`` zero before a row's first position (a
+    row is one document); result ``c * conv``, ``(B, S, C)``.
+
+    Channels stay on the last axis (the lanes) throughout, a tap is a
+    shift along the sequence: no ``(B, C, S)`` copy.  float32 arithmetic
+    whatever the input's type.  The backward pass keeps the two inputs
+    only and writes the gradient of ``data`` in one piece."""
+    with _xray.scope("shortconv.conv"):
+        return _gated_conv(data, weight)
 
 
 @register("_contrib_moe_route", num_outputs=2, aliases=("moe_route",))
-def moe_route(data, router_weight, router_bias, k=8, scale=1.0, **_):
+def moe_route(data, router_weight, router_bias, k=8, scale=1.0, eps=1e-20,
+              **_):
     """Sigmoid-scored top-``k`` routing with a selection-only bias
     (``noaux_tc`` with one group): ``s = sigmoid(x W_g)`` in float32; the
     ``k`` largest of ``s + bias`` are selected; a selected expert weighs
-    ``s / (sum of the selected s + 1e-20) * scale``: the bias selects, it
+    ``s / (sum of the selected s + eps) * scale``: the bias selects, it
     does not weigh.  -> (expert ids ``(..., k)`` int32, weights ``(..., k)``
     float32).  ``router_weight``: ``(experts, in)``."""
     with _xray.scope("moe.route"):
@@ -236,7 +369,7 @@ def moe_route(data, router_weight, router_bias, k=8, scale=1.0, **_):
             router_bias.astype(jnp.float32)), int(k))
         picked = jnp.take_along_axis(s, ids, axis=-1)
         weights = picked / (jnp.sum(picked, axis=-1, keepdims=True)
-                            + 1e-20) * scale
+                            + float(eps)) * scale
         return ids.astype(jnp.int32), weights
 
 
@@ -449,6 +582,10 @@ OP_INPUT_NAMES.update({
     "_contrib_mla_qkv": ("data", "qa_weight", "qb_weight", "kva_weight",
                          "kvb_weight", "qnorm_weight", "kvnorm_weight"),
     "_contrib_mla_out": ("data", "weight"),
+    "_contrib_gqa_qkv": ("data", "q_weight", "k_weight", "v_weight",
+                         "qnorm_weight", "knorm_weight"),
+    "_contrib_gqa_out": ("data", "weight"),
+    "_contrib_gated_short_conv": ("data", "weight"),
     "_contrib_moe_route": ("data", "router_weight", "router_bias"),
     "_contrib_moe_experts": ("data", "expert_ids", "expert_weights",
                              "gate_weight", "up_weight", "down_weight"),

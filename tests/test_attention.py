@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import mxnet_tpu as mx
 from mxnet_tpu.ops import attention as att
 
 
@@ -21,10 +22,17 @@ def _rand(shape, dtype=np.float32, seed=0):
     return jnp.asarray(rng.normal(size=shape).astype(dtype))
 
 
+# (query heads, key heads): equal, and grouped-query with four a key head
+HEADS = pytest.mark.parametrize("h,hk", [(3, 3), (4, 1)],
+                                ids=["equal_heads", "group_of_4"])
+
+
+@HEADS
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_forward_matches_reference(causal):
-    b, h, s, d = 2, 3, 256, 64
-    q, k, v = (_rand((b, h, s, d), seed=i) for i in range(3))
+def test_flash_forward_matches_reference(causal, h, hk):
+    b, s, d = 2, 256, 64
+    q = _rand((b, h, s, d), seed=0)
+    k, v = (_rand((b, hk, s, d), seed=i) for i in (1, 2))
     ref = att.mha_reference(q, k, v, causal=causal)
     out = att.flash_attention(q, k, v, causal=causal, interpret=True,
                               block_q=128, block_k=128)
@@ -32,10 +40,16 @@ def test_flash_forward_matches_reference(causal):
                                rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("h,hk,d", [(2, 2, 32), (8, 2, 64)],
+                         ids=["equal_heads", "two_groups_of_4"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grads_match_reference(causal):
-    b, h, s, d = 1, 2, 128, 32
-    q, k, v = (_rand((b, h, s, d), seed=10 + i) for i in range(3))
+def test_flash_grads_match_reference(causal, h, hk, d):
+    """Grouped-query: ``mha_reference`` repeats the key heads and autodiff
+    sums their gradients over a group; the dk/dv kernel sums in its
+    accumulators and returns the key heads' shape."""
+    b, s = 1, 128
+    q = _rand((b, h, s, d), seed=10)
+    k, v = (_rand((b, hk, s, d), seed=10 + i) for i in (1, 2))
 
     def loss_flash(q, k, v):
         o = att.flash_attention(q, k, v, causal=causal, interpret=True,
@@ -49,8 +63,29 @@ def test_flash_grads_match_reference(causal):
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g1, g2):
+        assert a.shape == b_.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-3, atol=2e-3)
+
+
+def test_flash_reads_grouped_key_heads_where_they_lie():
+    """No copy of a key head is made for its group's query heads: the three
+    kernels take ``k`` and ``v`` with the key heads' rows (the index maps
+    find a query head's key head), dk and dv leave with them, and heads
+    that do not divide raise."""
+    q, g = (_rand((2, 8, 256, 64), seed=i) for i in (0, 3))
+    k, v = (_rand((2, 2, 256, 64), seed=i) for i in (1, 2))
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _with_grads, att.flash_attention, True, interpret=True,
+        block_q=128, block_k=128))(q, k, v, g).jaxpr
+    kernels = _equations(jaxpr, "pallas_call")
+    assert len(kernels) == 3
+    for kernel in kernels:
+        shapes = [x.aval.shape for x in kernel.invars]
+        assert shapes[:3] == [(16, 256, 64), (4, 256, 64), (4, 256, 64)]
+    assert [x.aval.shape for x in kernels[2].outvars] == [(4, 256, 64)] * 2
+    with pytest.raises(mx.MXNetError, match="not a multiple"):
+        att.flash_attention(q[:, :3], k, v, interpret=True)
 
 
 def test_flash_rectangular_kv():

@@ -243,13 +243,21 @@ def test_runtime_telemetry_example_anatomy():
     (the script asserts misses == trace-miss spans itself)."""
     import json
 
-    from mxnet_tpu import profiler, runtime_stats
+    from mxnet_tpu import (device_memory, histogram, metrics_timeline,
+                           profiler, runtime_stats, stepstats)
 
     try:
         path = _run_example("profiler/runtime_telemetry.py", [])
     finally:
         profiler.set_state("stop")
         profiler._state["events"] = []
+        # the script starts the buffer tracker and turns the timeline on,
+        # which turns on the step attribution and the histograms: a later
+        # file of this worker (test_bench_gate.py) must find them off
+        device_memory.stop()
+        metrics_timeline.disable()
+        stepstats.disable()
+        histogram.disable()
         runtime_stats.reset()
     trace = json.load(open(path))["traceEvents"]
     names = {e["name"] for e in trace}

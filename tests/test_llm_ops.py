@@ -38,6 +38,37 @@ def np_rope(x, theta):
     return out
 
 
+def np_rope_halves(x, theta):
+    """Pairs ``(i, i + dim / 2)``: ``x cos + rotate_half(x) sin``."""
+    seq, dim = x.shape[-2:]
+    inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    angle = np.arange(seq)[:, None] * inv[None, :]
+    cos, sin = np.tile(np.cos(angle), 2), np.tile(np.sin(angle), 2)
+    turned = np.concatenate([-x[..., dim // 2:], x[..., :dim // 2]], -1)
+    return x * cos + turned * sin
+
+
+def np_gated_short_conv(bcx, weight):
+    """``c * conv(b * x)``, the convolution causal over axis 1."""
+    b, c, x = np.split(bcx, 3, axis=-1)
+    taps, seq = weight.shape[1], bcx.shape[1]
+    u = np.pad(b * x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return c * sum(weight[:, j] * u[:, j:j + seq] for j in range(taps))
+
+
+def np_gqa_qkv(x, wq, wk, wv, gq, gk, theta, eps):
+    """q, k and v as one array, heads side by side on axis 1."""
+    d = gq.shape[0]
+
+    def heads(w):
+        return (x @ w.T).reshape(x.shape[0], x.shape[1], -1, d) \
+            .transpose(0, 2, 1, 3)
+
+    q = np_rope_halves(np_rms_norm(heads(wq), gq, eps), theta)
+    k = np_rope_halves(np_rms_norm(heads(wk), gk, eps), theta)
+    return np.concatenate([q, k, heads(wv)], axis=1)
+
+
 def np_silu(x):
     return x / (1 + np.exp(-x))
 
@@ -46,11 +77,11 @@ def np_gated_silu(x, gate, up, down):
     return (np_silu(x @ gate.T) * (x @ up.T)) @ down.T
 
 
-def np_route(x, w, b, k, scale):
+def np_route(x, w, b, k, scale, eps=1e-20):
     s = 1 / (1 + np.exp(-(x @ w.T)))
     ids = np.argsort(-(s + b), axis=-1, kind="stable")[:, :k]
     picked = np.take_along_axis(s, ids, -1)
-    return ids, picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    return ids, picked / (picked.sum(-1, keepdims=True) + eps) * scale
 
 
 def np_experts(x, ids, weights, wg, wu, wd, first):
@@ -144,6 +175,62 @@ def rope_of_a_width_no_tile_divides():
 
 
 @case
+def rope_by_halves_with_a_head_axis():
+    x = _rs().randn(2, 3, 7, 64)
+    return (lambda x: llm.rope(x, theta=1e6, halves=True),
+            lambda x: np_rope_halves(x, 1e6), (x,), (0,))
+
+
+@case
+def rope_by_halves_wider_than_a_lane_tile():
+    x = _rs().randn(2, 5, 256)          # two slices swapped, no product
+    return (lambda x: llm.rope(x, theta=1e4, halves=True),
+            lambda x: np_rope_halves(x, 1e4), (x,), (0,))
+
+
+@case
+def rope_by_halves_at_position_8191():
+    x = _rs().randn(1, 8192, 64)
+    return (lambda x: llm.rope(x, theta=1e6, halves=True),
+            lambda x: np_rope_halves(x, 1e6), (x,), (0,))
+
+
+@case
+def gated_short_conv():
+    rs = _rs()
+    return (llm.gated_short_conv, np_gated_short_conv,
+            (rs.randn(2, 9, 24), rs.randn(8, 3)), (0, 1))
+
+
+@case
+def gated_short_conv_of_four_taps_on_a_short_row():
+    rs = _rs()
+    return (llm.gated_short_conv, np_gated_short_conv,
+            (rs.randn(3, 2, 12), rs.randn(4, 4)), (0, 1))
+
+
+@case
+def gqa_qkv():
+    rs = _rs()
+    args = (rs.randn(2, 5, 12), rs.randn(32, 12), rs.randn(16, 12),
+            rs.randn(16, 12), rs.rand(8) + 0.5, rs.rand(8) + 0.5)
+
+    def op(*a):
+        return jnp.concatenate(llm.gqa_qkv(*a, theta=1e6, eps=1e-5), axis=1)
+
+    return (op, lambda *a: np_gqa_qkv(*a, 1e6, 1e-5), args,
+            (0, 1, 2, 3, 4, 5))
+
+
+@case
+def gqa_out():
+    rs = _rs()
+    return (llm.gqa_out,
+            lambda o, w: o.transpose(0, 2, 1, 3).reshape(2, 5, 32) @ w.T,
+            (rs.randn(2, 4, 5, 8), rs.randn(12, 32)), (0, 1))
+
+
+@case
 def gated_silu():
     rs = _rs()
     args = (rs.randn(2, 5, 8), rs.randn(12, 8), rs.randn(12, 8),
@@ -157,6 +244,15 @@ def moe_route_weights():
     return (lambda x, w: llm.moe_route(x, w, _f(m["bias"]), k=3,
                                        scale=2.5)[1],
             lambda x, w: np_route(x, w, m["bias"], 3, 2.5)[1],
+            (m["x"], m["router"]), (0, 1))
+
+
+@case
+def moe_route_weights_with_its_epsilon():
+    m = _moe_inputs()
+    return (lambda x, w: llm.moe_route(x, w, _f(m["bias"]), k=3, scale=1.0,
+                                       eps=0.25)[1],
+            lambda x, w: np_route(x, w, m["bias"], 3, 1.0, eps=0.25)[1],
             (m["x"], m["router"]), (0, 1))
 
 
@@ -233,6 +329,45 @@ def test_rope_of_bfloat16_is_the_float32_result_cast(shape, theta):
         assert (np.abs(np.asarray(got, np.float32) - want) <= ulp).all()
 
 
+def test_gated_short_conv_is_a_depthwise_causal_convolution():
+    """Against ``lax.conv_general_dilated`` over ``(batch, channels, seq)``
+    with ``L - 1`` zeros before the row (the published module's form),
+    forward and both gradients; bfloat16 in gives the float32 result's
+    cast; and the lowered op never holds channels anywhere but last."""
+    from jax import lax
+
+    rs = _rs(8)
+    bcx, weight = _f(rs.randn(2, 33, 3 * 16)), _f(rs.randn(16, 3))
+    g = _f(rs.randn(2, 33, 16))
+
+    def plain(bcx, weight):
+        b, c, x = jnp.split(bcx, 3, axis=-1)
+        conv = lax.conv_general_dilated(
+            (b * x).transpose(0, 2, 1), weight[:, None, :], (1,), [(2, 0)],
+            feature_group_count=16, dimension_numbers=("NCH", "OIH", "NCH"))
+        return c * conv.transpose(0, 2, 1)
+
+    np.testing.assert_allclose(llm.gated_short_conv(bcx, weight),
+                               plain(bcx, weight), rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(llm.gated_short_conv(*a) * g),
+                   (0, 1))(bcx, weight)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * g), (0, 1))(bcx, weight)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)
+    low = llm.gated_short_conv(bcx.astype(jnp.bfloat16), weight)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(low, np.float32),
+        np.asarray(plain(bcx.astype(jnp.bfloat16).astype(jnp.float32),
+                         weight).astype(jnp.bfloat16), np.float32),
+        rtol=1e-2, atol=1e-2)
+    text = jax.jit(jax.grad(
+        lambda *a: jnp.sum(llm.gated_short_conv(*a) * g), (0, 1))).lower(
+        bcx, weight).as_text()
+    assert "stablehlo.convolution" not in text
+    assert "tensor<2x16x" not in text and "tensor<2x48x" not in text
+
+
 def test_the_gradient_of_rope_is_the_rotation_back():
     """No gather forward, no scatter backward: the lowered gradient is
     products, selects and elementwise arithmetic."""
@@ -250,8 +385,9 @@ def test_the_ops_are_in_the_contrib_namespaces():
     out = mx.nd.contrib.rms_norm(x, mx.nd.ones((8,)))
     np.testing.assert_allclose(
         out.asnumpy(), np_rms_norm(x.asnumpy(), 1.0), rtol=1e-5)
-    for name in ("rope", "gated_silu", "mla_qkv", "mla_out", "moe_route",
-                 "moe_experts", "linear_cross_entropy"):
+    for name in ("rope", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
+                 "gqa_out", "gated_short_conv", "moe_route", "moe_experts",
+                 "linear_cross_entropy"):
         assert hasattr(mx.nd.contrib, name) and hasattr(mx.sym.contrib, name)
 
 

@@ -67,7 +67,7 @@ def _hidden(seed=1):
 
 def _mla(net, p, tokens):
     x = _hidden()
-    return (net.blocks[1].attn(mx.nd.array(x)).asnumpy(),
+    return (net.blocks[1].mixer(mx.nd.array(x)).asnumpy(),
             reference.mla(p, "l1_attn_", jnp.asarray(x), REF_ARCH))
 
 

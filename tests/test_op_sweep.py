@@ -548,6 +548,10 @@ ELSEWHERE = {
     "_contrib_gated_silu": ("tests/test_llm_ops.py", "llm.gated_silu"),
     "_contrib_mla_qkv": ("tests/test_mla_moe.py", "mla_qkv"),
     "_contrib_mla_out": ("tests/test_mla_moe.py", "mla_out"),
+    "_contrib_gqa_qkv": ("tests/test_llm_ops.py", "llm.gqa_qkv"),
+    "_contrib_gqa_out": ("tests/test_llm_ops.py", "llm.gqa_out"),
+    "_contrib_gated_short_conv": ("tests/test_llm_ops.py",
+                                  "llm.gated_short_conv"),
     "_contrib_moe_route": ("tests/test_llm_ops.py", "llm.moe_route"),
     "_contrib_moe_experts": ("tests/test_llm_ops.py", "llm.moe_experts"),
     "_contrib_linear_cross_entropy": ("tests/test_llm_ops.py",

@@ -433,11 +433,9 @@ def test_on_the_v5e_no_state_leaf_is_copied_at_the_programs_edges(v5e):
 _CELLS_KERNEL_FLOPS = [2 * 32 * 4096 * 4096 / 2 * n for n in (640, 1024, 1280)]
 
 
-def _counted_kernel_flops(text):
-    """Sorted ``attention_cost.kernel_flops`` of every ``tpu_custom_call``
-    of a compiled module (None for one the benchmark does not know), each
-    instruction written as the profiler names it: its operands' types
-    inline."""
+def _kernel_instructions(text):
+    """Every ``tpu_custom_call`` of a compiled module, written as the
+    profiler names it: its operands' types inline."""
     import os
     import re
     import sys
@@ -448,25 +446,47 @@ def _counted_kernel_flops(text):
 
     lines = [hlo_cost.split_instruction(line) for line in text.splitlines()]
     types = {name: result for name, _, (result, _, _) in lines}
-    flops = []
+    found = []
     for name, opcode, (result, operands, attrs) in lines:
         if attention_cost.KERNEL_TARGET in attrs:
             operands = re.sub(r"%([\w.\-]+)", lambda m: "%s %s" % (
                 types[m.group(1)], m.group(0)), operands)
-            flops.append(attention_cost.kernel_flops("%%%s = %s %s(%s)%s" % (
-                name, result, opcode, operands, attrs)))
-    return sorted(flops)
+            found.append("%%%s = %s %s(%s)%s" % (name, result, opcode,
+                                                 operands, attrs))
+    return found
 
 
-@pytest.mark.parametrize("dtype,blocks", [("bfloat16", (1024, 1024)),
-                                          ("float32", (256, 512))])
+def _counted_kernel_flops(text):
+    """Sorted ``attention_cost.kernel_flops`` of every ``tpu_custom_call``
+    of a compiled module (None for one the benchmark does not know)."""
+    from benchmark.harness import attention_cost
+
+    return sorted(attention_cost.kernel_flops(instruction)
+                  for instruction in _kernel_instructions(text))
+
+
+@pytest.mark.parametrize("dtype,blocks,kv_heads,seq,d,dv", [
+    ("bfloat16", (1024, 1024), 32, 4096, 192, 128),
+    ("float32", (256, 512), 32, 4096, 192, 128),
+    # what the jaxpr-level tests of tests/test_attention.py and the cell's
+    # own runs show already: outside the tier-1 run
+    pytest.param("bfloat16", (1024, 1024), 8, 8192, 64, 64,
+                 marks=pytest.mark.slow),
+    ("float32", (1024, 1024), 8, 8192, 64, 64)],
+    ids=["latent_bfloat16", "latent_float32", "grouped_query_bfloat16",
+         "grouped_query_float32"])
 def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
-        v5e, monkeypatch, dtype, blocks):
+        v5e, monkeypatch, dtype, blocks, kv_heads, seq, d, dv):
     """Mosaic takes the three flash-attention kernels at the language-model
-    cell's shape (2 x 32 heads x 4,096, 192 / 128; bfloat16 as the step
-    runs them, float32 as the check does), and their instructions are the
-    ones ``benchmark/harness/attention_cost.py`` counts: q, k, v (and do,
-    lse, delta), one result for dq, two for the others."""
+    cells' shapes (2 x 32 heads x 4,096, 192 / 128, bfloat16 as the step
+    runs them and float32 as the check does; 2 x 32 query heads on 8 key
+    heads x 8,192, 64 / 64, in float32 at ``highest`` matmul precision as
+    that cell's check runs them: 19-22 MiB of scoped VMEM at 1,024 / 1,024,
+    which the kernels ask for), and their instructions are the ones the
+    benchmark counts: q, k, v (and do, lse, delta), one result for dq, two
+    for the others (``harness/attention_cost.py``; with grouped-query heads
+    ``harness/gqa_attention_cost.py``, and ``k`` arrives with the key
+    heads' rows, not repeated)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -477,8 +497,8 @@ def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
     monkeypatch.setattr(att, "pallas_interpret", lambda: False)
     chip = SingleDeviceSharding(v5e.devices[0])
 
-    def arg(width):
-        return jax.ShapeDtypeStruct((2, 32, 4096, width), jnp.dtype(dtype),
+    def arg(heads, width):
+        return jax.ShapeDtypeStruct((2, heads, seq, width), jnp.dtype(dtype),
                                     sharding=chip)
 
     def forward_and_backward(q, k, v, g):
@@ -487,10 +507,32 @@ def test_on_the_v5e_the_flash_kernels_compile_and_the_benchmark_counts_them(
             q, k, v)
         return (out,) + vjp(g)
 
-    assert att._block_choices(arg(192), arg(128))[0] == blocks
-    text = jax.jit(forward_and_backward).lower(
-        arg(192), arg(192), arg(128), arg(128)).compile().as_text()
-    assert _counted_kernel_flops(text) == _CELLS_KERNEL_FLOPS
+    assert att._block_choices(arg(32, d), arg(kv_heads, dv))[0] == blocks
+    with jax.default_matmul_precision(
+            "highest" if dtype == "float32" else "default"):
+        text = jax.jit(forward_and_backward).lower(
+            arg(32, d), arg(kv_heads, d), arg(kv_heads, dv),
+            arg(32, dv)).compile().as_text()
+    if kv_heads == 32:
+        assert _counted_kernel_flops(text) == _CELLS_KERNEL_FLOPS
+        return
+    from benchmark.harness import gqa_attention_cost
+
+    shapes = {"rows": 2, "seq": seq, "heads": 32, "kv_heads": kv_heads,
+              "d": d}
+    kernels = _kernel_instructions(text)
+    size = jnp.dtype(dtype).itemsize
+    assert sorted(gqa_attention_cost.kernel_kind(k, shapes)
+                  for k in kernels) == [("dkv", size), ("dq", size),
+                                        ("forward", size)]
+    typed = {"bfloat16": "bf16", "float32": "f32"}[dtype] + "[%d,%d,%d]"
+    for kernel in kernels:
+        operands = kernel[kernel.index("custom-call("):kernel.index(
+            "custom_call_target")]
+        assert operands.count(typed % (2 * kv_heads, seq, d)) == 2
+        assert operands.count(typed % (64, seq, d)) \
+            == (1 if "forward" in str(gqa_attention_cost.kernel_kind(
+                kernel, shapes)) else 2)
 
 
 def test_on_the_v5e_latent_attention_hands_the_kernels_what_they_read(

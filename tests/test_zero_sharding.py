@@ -28,7 +28,8 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, health, optimizer as opt_mod
-from mxnet_tpu import metrics_timeline, perfdoctor, runtime_stats
+from mxnet_tpu import histogram, metrics_timeline, perfdoctor
+from mxnet_tpu import runtime_stats, stepstats
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.parallel.gluon_step import GluonTrainStep
@@ -46,6 +47,8 @@ def _clean():
     health.disable()
     metrics_timeline.disable()
     metrics_timeline.reset()
+    stepstats.disable()     # the timeline's enable() turned both on
+    histogram.disable()
     runtime_stats.reset()
 
 

@@ -8,7 +8,9 @@ forward, 4 d + 2 dv for dq, 4 d + 4 dv for dk/dv: 640 / 1,024 / 1,280 at
 latent attention's 192 / 128).  ``--xla`` adds the unfused reference,
 forward and forward + backward (it materialises the (seq, seq) scores).
 
-The block table of the language-model cell is one command:
+The block table of a language-model cell is one command (latent attention's
+heads; grouped-query heads of 64 with ``--heads 32 --kv-heads 8 --head-dim
+64 --seqs 8192``):
 
     python tools/bench_attention.py --batch 2 --heads 32 --head-dim 192 \\
         --v-head-dim 128 --seqs 4096 --causal \\
@@ -81,6 +83,8 @@ def main(argv=None):
         description=__doc__, formatter_class=argparse.RawTextHelpFormatter)
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--heads", type=int, default=8)
+    p.add_argument("--kv-heads", type=int, default=None,
+                   help="heads of k and v (grouped-query; default: --heads)")
     p.add_argument("--head-dim", type=int, default=64)
     p.add_argument("--v-head-dim", type=int, default=None,
                    help="head size of v and the output (default: --head-dim)")
@@ -105,10 +109,11 @@ def main(argv=None):
                 "dkv": 4 * d + 4 * dv}
     rows = []
     for seq in (int(s) for s in args.seqs.split(",")):
-        q, k = (jnp.asarray(rng.randn(args.batch, args.heads, seq, d), dt)
-                for _ in range(2))
-        v, g = (jnp.asarray(rng.randn(args.batch, args.heads, seq, dv), dt)
-                for _ in range(2))
+        kv_heads = args.kv_heads or args.heads
+        q = jnp.asarray(rng.randn(args.batch, args.heads, seq, d), dt)
+        k = jnp.asarray(rng.randn(args.batch, kv_heads, seq, d), dt)
+        v = jnp.asarray(rng.randn(args.batch, kv_heads, seq, dv), dt)
+        g = jnp.asarray(rng.randn(args.batch, args.heads, seq, dv), dt)
         pairs = args.batch * args.heads * seq * seq / (2.0 if args.causal
                                                        else 1.0)
         for block_q, block_k in blocks or att._block_choices(q, v)[:1]:

@@ -117,6 +117,11 @@ def canonical_spec(name):
                               ((6, 8), f), ((16, 4), f), ((6,), f),
                               ((4,), f)], {"num_heads": 2, "theta": 1e4}),
         "_contrib_mla_out": ([((2, 2, 5, 4), f), ((8, 8), f)], {}),
+        "_contrib_gqa_qkv": ([((2, 5, 8), f), ((16, 8), f), ((8, 8), f),
+                              ((8, 8), f), ((4,), f), ((4,), f)],
+                             {"theta": 1e4}),
+        "_contrib_gqa_out": ([((2, 4, 5, 4), f), ((8, 16), f)], {}),
+        "_contrib_gated_short_conv": ([((2, 5, 24), f), ((8, 3), f)], {}),
         "_contrib_moe_route": ([((6, 8), f), ((12, 8), f), ((12,), f)],
                                {"k": 3, "scale": 2.5}),
         "_contrib_moe_experts": ([((6, 8), f), ((6, 3), i32), ((6, 3), f),
