@@ -28,8 +28,11 @@ import jax
 import jax.numpy as jnp
 import numpy as _np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import xray as _xray
+from ..util import pallas_interpret
 from .registry import OP_INPUT_NAMES, register
 
 __all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
@@ -43,6 +46,7 @@ __all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
 # 0.72 us a pair (PERF.md, PR 28); 512 halves it.
 DEFAULT_EXPERT_TILE = 512
 DEFAULT_LOSS_CHUNK = 1024
+LANES = 128
 
 
 @register("_contrib_rms_norm", aliases=("rms_norm",))
@@ -415,15 +419,131 @@ def expert_tiles(ids, first, held, tile):
 
 
 def _tile_rows(t, row_pair, weights_flat, k, tile):
-    """Tokens and routing weights of tile ``t``'s rows (padding: token 0,
-    weight 0) and the rows' pair indices."""
+    """Tile ``t``'s rows, the real ones first: how many are real, the token
+    each reads (padding: token 0), where each adds into a ``(tokens, ...)``
+    and into the flat ``(tokens * k,)`` sum (padding: past the end, so it
+    adds nothing: :func:`_add_rows`), and the routing weights (padding:
+    0)."""
     with _xray.scope("moe.dispatch"):
         pairs = lax.dynamic_slice(row_pair, (t * tile,), (tile,))
         n = weights_flat.shape[0]
         real = pairs < n
         safe = jnp.where(real, pairs, 0)
         gate = jnp.where(real, jnp.take(weights_flat, safe), 0.0)
-        return safe // k, gate, pairs
+        tok = safe // k
+        return (jnp.sum(real, dtype=jnp.int32), tok,
+                jnp.where(real, tok, n // k), pairs, gate)
+
+
+def _kernel_adds_rows(units, tile):
+    """Whether the loops add a tile's rows with ``_add_rows_kernel``: on an
+    accelerator, where a row is whole lane tiles, a tile's rows whole
+    sublane tiles, and they fit the kernel's VMEM twice (8 MB at 512 x
+    2,048, of the 32 it asks for).  Elsewhere XLA's scatter-add does (on
+    the CPU platform always: the kernel would run through the
+    interpreter)."""
+    return not pallas_interpret() and units % LANES == 0 \
+        and tile % 8 == 0 and tile * units <= 2 ** 21
+
+
+def _zeros_to_add_rows_into(tokens, units, tile):
+    """The loops' float32 ``(tokens, units)`` sum.  For the kernel it is
+    held ``(tokens, units / 128, 128)``: a token is then whole ``(8, 128)``
+    tiles of the array, 8 KB in one piece at 2,048 units, which a copy can
+    address; a row of the 2-D array is one sublane of 16 tiles, and Mosaic
+    copies no slice that is not aligned to the tiling."""
+    if _kernel_adds_rows(units, tile):
+        return jnp.zeros((tokens, units // LANES, LANES), jnp.float32)
+    return jnp.zeros((tokens, units), jnp.float32)
+
+
+def _add_rows_kernel(at_ref, n_ref, _, rows_hbm, total_hbm, held, rows,
+                     sem_in, sem_rows, sem_out):
+    """``total[at[i]] += rows[i]`` for the tile's first ``n`` rows, the sum
+    left in HBM and updated in place (``total_hbm`` is the aliased result):
+    the rows of ``total`` are copied into ``held`` one by one, all of them
+    in flight at once, and the tile's ``rows`` in one piece; added; and
+    copied back the same way.  Every real row of a tile is another row of
+    ``total`` (``moe_experts``' precondition), so no copy waits for
+    another.  What bounds it on a v5e is issuing the 2 x ``n`` copies, not
+    their bytes: starting the next rows' reads under the add and the
+    writes (chunks of 16 to 256 rows) changed nothing (PERF.md, PR 33)."""
+    def every_real_row(fn):
+        def body(i, carry):
+            fn(i)
+            return carry
+
+        lax.fori_loop(0, n_ref[0], body, 0)
+
+    def copy_in(i):
+        return pltpu.make_async_copy(total_hbm.at[at_ref[i]], held.at[i],
+                                     sem_in)
+
+    def copy_out(i):
+        return pltpu.make_async_copy(held.at[i], total_hbm.at[at_ref[i]],
+                                     sem_out)
+
+    copy_rows = pltpu.make_async_copy(rows_hbm, rows, sem_rows)
+    copy_rows.start()
+    every_real_row(lambda i: copy_in(i).start())
+    copy_rows.wait()
+    every_real_row(lambda i: copy_in(i).wait())
+    held[...] = held[...] + rows[...].reshape(held.shape)
+    every_real_row(lambda i: copy_out(i).start())
+    every_real_row(lambda i: copy_out(i).wait())
+
+
+def _add_rows(total, n, at, rows):
+    """``total[at[i]] += rows[i]`` for one tile's rows, in place: ``total``
+    is a loop's carry.  The first ``n`` rows are real and no two of them
+    name the same row of ``total``; the others name a row past its end and
+    add nothing.  A 3-D ``total`` (``_zeros_to_add_rows_into``) takes the
+    kernel, which keeps all the rows' copies in flight; XLA's scatter-add,
+    which may assume nothing about the rows, finishes one row's read, add
+    and write before it starts the next (PERF.md, PR 33)."""
+    with _xray.scope("moe.combine"):
+        if total.ndim < 3:
+            return total.at[at].add(rows, mode="drop")
+        tile = rows.shape[0]
+        in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+        return pl.pallas_call(
+            _add_rows_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(1,), in_specs=[in_hbm, in_hbm],
+                out_specs=in_hbm,
+                scratch_shapes=[
+                    pltpu.VMEM((tile,) + total.shape[1:], jnp.float32),
+                    pltpu.VMEM(rows.shape, jnp.float32)]
+                + [pltpu.SemaphoreType.DMA(())] * 3),
+            out_shape=jax.ShapeDtypeStruct(total.shape, total.dtype),
+            input_output_aliases={2: 0},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=32 * 1024 * 1024),
+            name="moe_add_rows", interpret=pallas_interpret(),
+        )(at, n.reshape(1), total, rows)
+
+
+def _summed_rows(total, like):
+    """A loop's finished sum as ``(tokens, units)`` of ``like``'s type.
+    The kernel's 3-D sum is brought back by a kernel too, one pass over
+    it: XLA converts first and then moves the result twice."""
+    with _xray.scope("moe.combine"):
+        tokens = total.shape[0]
+        block = math.gcd(tokens, 256)
+        if total.ndim < 3 or block % 8:
+            return total.reshape(like.shape).astype(like.dtype)
+
+        def kernel(total_ref, out_ref):
+            out_ref[...] = total_ref[...].reshape(out_ref.shape).astype(
+                out_ref.dtype)
+
+        return pl.pallas_call(
+            kernel, grid=(tokens // block,),
+            in_specs=[pl.BlockSpec((block,) + total.shape[1:],
+                                   lambda i: (i, 0, 0))],
+            out_specs=pl.BlockSpec((block, like.shape[1]), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct(like.shape, like.dtype),
+            name="moe_summed_rows", interpret=pallas_interpret())(total)
 
 
 def _expert_forward(xt, wg, wu, wd):
@@ -446,20 +566,21 @@ def _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles,
 
     def body(carry):
         t, out = carry
-        tok, gate, _ = _tile_rows(t, row_pair, flat, k, tile)
+        n, tok, at, _, gate = _tile_rows(t, row_pair, flat, k, tile)
         e = tile_expert[t]
         with _xray.scope("moe.dispatch"):
             xt = jnp.take(x, tok, axis=0)
         with _xray.scope("moe.experts"):
             yt = _expert_forward(xt, wg[e], wu[e], wd[e])[3]
         with _xray.scope("moe.combine"):
-            out = out.at[tok].add(gate[:, None] * yt)
-        return t + 1, out
+            yt = gate[:, None] * yt
+        return t + 1, _add_rows(out, n, at, yt)
 
-    _, out = lax.while_loop(lambda c: c[0] < n_tiles, body,
-                            (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
-    return out.astype(x.dtype), (x, weights, wg, wu, wd, row_pair,
-                                 tile_expert, n_tiles)
+    _, out = lax.while_loop(
+        lambda c: c[0] < n_tiles, body,
+        (jnp.int32(0), _zeros_to_add_rows_into(*x.shape, tile)))
+    return _summed_rows(out, x), (x, weights, wg, wu, wd, row_pair,
+                                  tile_expert, n_tiles)
 
 
 def _routed_sum_bwd(k, tile, res, dy):
@@ -471,7 +592,7 @@ def _routed_sum_bwd(k, tile, res, dy):
 
     def body(carry):
         t, dx, dflat, dwg, dwu, dwd = carry
-        tok, gate, pairs = _tile_rows(t, row_pair, flat, k, tile)
+        n, tok, at, pairs, gate = _tile_rows(t, row_pair, flat, k, tile)
         e = tile_expert[t]
         with _xray.scope("moe.dispatch"):
             xt = jnp.take(x, tok, axis=0)
@@ -489,21 +610,19 @@ def _routed_sum_bwd(k, tile, res, dy):
             dwg = dwg.at[e].add(_dot(xt, dg, ((0,), (0,))))
             dwu = dwu.at[e].add(_dot(xt, du, ((0,), (0,))))
             dwd = dwd.at[e].add(_dot(h, dyt, ((0,), (0,))))
-        with _xray.scope("moe.combine"):
-            dx = dx.at[tok].add(dxt)
-            dflat = dflat.at[pairs].add(dgate, mode="drop")
-        return t + 1, dx, dflat, dwg, dwu, dwd
+        return (t + 1, _add_rows(dx, n, at, dxt),
+                _add_rows(dflat, n, pairs, dgate), dwg, dwu, dwd)
 
-    init = (jnp.int32(0), jnp.zeros(x.shape, f32), jnp.zeros(flat.shape, f32),
-            jnp.zeros(wg.shape, f32), jnp.zeros(wu.shape, f32),
-            jnp.zeros(wd.shape, f32))
+    init = (jnp.int32(0), _zeros_to_add_rows_into(*x.shape, tile),
+            jnp.zeros(flat.shape, f32), jnp.zeros(wg.shape, f32),
+            jnp.zeros(wu.shape, f32), jnp.zeros(wd.shape, f32))
     _, dx, dflat, dwg, dwu, dwd = lax.while_loop(
         lambda c: c[0] < n_tiles, body, init)
 
     def no_gradient(a):
         return _np.zeros(a.shape, dtype=jax.dtypes.float0)
 
-    return (dx.astype(x.dtype), dflat.reshape(weights.shape).astype(
+    return (_summed_rows(dx, x), dflat.reshape(weights.shape).astype(
         weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
         dwd.astype(wd.dtype), no_gradient(row_pair),
         no_gradient(tile_expert), no_gradient(n_tiles))
@@ -527,7 +646,19 @@ def moe_experts(data, expert_ids, expert_weights, gate_weight, up_weight,
     loop runs over the row tiles in use (``expert_tiles``), so the work
     follows the pairs actually routed here.  -> (y, pairs routed to held
     experts, largest held expert's pairs over their mean), the two
-    counters float32 scalars."""
+    counters float32 scalars.
+
+    **Precondition: a token's ``k`` ids are distinct** (``moe_route``'s
+    are, being a ``top_k``'s).  A tile's real rows are one expert's pairs
+    in flat pair order (``expert_tiles`` sorts stably), so with distinct
+    ids no two of them are the same token, and on an accelerator the loops
+    add a tile into the float32 sum with a kernel that keeps many rows'
+    read-add-write in flight at once (:func:`_add_rows`).  With an id
+    repeated within a token two rows of a tile meet and one addend is
+    lost; XLA's scatter-add, the CPU platform's path, adds them one after
+    the other, so only a chip shows it.  Nothing checks the ids (they are
+    traced values).  The kernel cannot sit in a program that GSPMD
+    partitions over several chips (as ``flash_attention``'s cannot)."""
     held = gate_weight.shape[0]
     k = expert_ids.shape[-1]
     expert_ids = expert_ids.astype(jnp.int32)   # ids may arrive as floats

@@ -256,17 +256,35 @@ def moe_route_weights_with_its_epsilon():
             (m["x"], m["router"]), (0, 1))
 
 
-@case
-def moe_experts():
-    m = _moe_inputs()
+def _moe_experts_case(tokens, tile):
+    m = _moe_inputs(tokens=tokens)
     ids, weights = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
 
     def op(x, weights, wg, wu, wd):
         return llm.moe_experts(x, jnp.asarray(ids, jnp.int32), weights, wg,
-                               wu, wd, first_expert=4, tile=4)[0]
+                               wu, wd, first_expert=4, tile=tile)[0]
 
     return (op, lambda x, w, wg, wu, wd: np_experts(x, ids, w, wg, wu, wd, 4),
             (m["x"], weights, m["wg"], m["wu"], m["wd"]), (0, 1, 2, 3, 4))
+
+
+@case
+def moe_experts():
+    return _moe_experts_case(tokens=37, tile=4)
+
+
+@case
+def moe_experts_with_tiles_that_are_mostly_padding():
+    """6 tokens, 18 pairs over 12 experts: a tile of 64 rows holds one or
+    two real rows, the rest add nothing anywhere."""
+    return _moe_experts_case(tokens=6, tile=64)
+
+
+@case
+def moe_experts_with_loads_that_cross_a_tile():
+    """~9 pairs a held expert against tiles of 8: a full tile, then one
+    that is nearly all padding."""
+    return _moe_experts_case(tokens=37, tile=8)
 
 
 @case
@@ -454,6 +472,117 @@ def test_the_loop_runs_over_the_tiles_in_use_not_over_the_capacity():
         pairs = rows[t * 8:(t + 1) * 8]
         pairs = pairs[pairs < ids.size]
         assert (ids.reshape(-1)[pairs] == 4 + int(tile_expert[t])).all()
+
+
+def _routing(name, tokens=50):
+    """(ids, weights) over 12 experts, 3 a token; experts 4..7 are held."""
+    m = _moe_inputs(tokens=tokens, held=12)
+    if name == "one_held_expert":
+        return np.tile(np.array([[6, 1, 11]]), (tokens, 1)), \
+            _rs(3).rand(tokens, 3)
+    bias = m["bias"].copy()
+    if name == "an_idle_held_expert":
+        bias[5] = -10.0                         # never among the largest
+    ids, weights = llm.moe_route(_f(m["x"]), _f(m["router"]), _f(bias), k=3,
+                                 scale=2.5)
+    return np.asarray(ids), np.asarray(weights)
+
+
+ROUTINGS = pytest.mark.parametrize(
+    "routing", ["random", "one_held_expert", "an_idle_held_expert"])
+
+
+@ROUTINGS
+@pytest.mark.parametrize("tile", [4, 16, 64])
+def test_what_the_combine_relies_on_is_true(routing, tile):
+    """``_add_rows_kernel`` keeps many rows' read-add-write in flight at
+    once, which is right only while no two of them name the same row of
+    the sum (a CPU run cannot tell: XLA's scatter-add, the path there,
+    adds rows that meet one after the other).  In every tile in use the
+    real rows come first, ``n`` of them, their tokens strictly increase,
+    so no two meet, and the padding rows name a row past the end; the same
+    holds for the flat pair indices the routing weights' gradient is
+    scattered with."""
+    ids, weights = _routing(routing)
+    tokens, k = ids.shape
+    row_pair, tile_expert, n_tiles, counts = llm.expert_tiles(
+        jnp.asarray(ids, jnp.int32), 4, 4, tile)
+    if routing == "an_idle_held_expert":
+        assert np.asarray(counts)[1] == 0 and np.asarray(counts).sum() > 0
+    assert int(n_tiles) == sum(-(-c // tile) for c in np.asarray(counts))
+    flat = _f(weights).reshape(-1)
+    seen = []
+    for t in range(int(n_tiles)):
+        n, tok, at, pairs, gate = (np.asarray(a) for a in llm._tile_rows(
+            t, row_pair, flat, k, tile))
+        real = np.arange(tile) < n
+        assert 0 < n <= tile
+        for index, size in ((at, tokens), (pairs, tokens * k)):
+            assert (np.diff(index[real]) > 0).all()     # no two rows meet
+            assert (index[real] >= 0).all() and (index[real] < size).all()
+            assert (index[~real] >= size).all()         # dropped
+        np.testing.assert_array_equal(at[real], pairs[real] // k)
+        np.testing.assert_array_equal(tok[real], at[real])
+        assert (tok[~real] == 0).all() and (gate[~real] == 0).all()
+        assert (ids.reshape(-1)[pairs[real]] == 4 + int(tile_expert[t])).all()
+        seen.extend(pairs[real])
+    held = (ids.reshape(-1) >= 4) & (ids.reshape(-1) < 8)
+    assert sorted(seen) == list(np.flatnonzero(held))   # every pair, once
+
+
+@pytest.mark.parametrize("tokens,tile", [(40, 8), (8, 64), (37, 16)],
+                         ids=["loads_cross_a_tile", "mostly_padding",
+                              "tokens_no_block_divides"])
+def test_the_kernel_adds_what_the_scatter_adds(monkeypatch, tokens, tile):
+    """The accelerator's path through the interpreter (``_add_rows_kernel``
+    on a ``(tokens, units / 128, 128)`` sum; the CPU platform otherwise
+    takes XLA's scatter-add): the op's value and its five gradients are
+    the scatter path's to rounding, with tiles that fill, tiles the padding
+    cuts short, and tiles that are nearly all padding;
+    the finished sum comes back through ``_summed_rows``' kernel, or,
+    where no block of 8 tokens divides it, through XLA's reshape."""
+    m = _moe_inputs(tokens=tokens, hidden=256, width=8)
+    ids, weights = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
+    args = [_f(a) for a in (m["x"], weights, m["wg"], m["wu"], m["wd"])]
+    proj = _f(_rs(9).randn(tokens, 256))
+
+    def value_and_gradients():
+        def op(*a):
+            return llm.moe_experts(a[0], jnp.asarray(ids, jnp.int32), *a[1:],
+                                   first_expert=4, tile=tile)[0]
+
+        return (op(*args),) + jax.grad(
+            lambda *a: jnp.sum(op(*a) * proj), argnums=(0, 1, 2, 3, 4))(*args)
+
+    want = value_and_gradients()
+    monkeypatch.setattr(llm, "_kernel_adds_rows", lambda units, tile: True)
+    lowered = jax.jit(lambda x: llm.moe_experts(
+        x, jnp.asarray(ids, jnp.int32), *args[1:], first_expert=4,
+        tile=tile)[0]).lower(args[0]).as_text()
+    assert "x2x128xf32" in lowered              # the sum, three axes
+    for got, w in zip(value_and_gradients(), want):
+        np.testing.assert_allclose(got, w, rtol=1e-4, atol=1e-4)
+
+
+def _distinct_within_a_token(ids):
+    ids = np.sort(np.asarray(ids), axis=-1)
+    return bool((np.diff(ids, axis=-1) != 0).all())
+
+
+@ROUTINGS
+def test_a_token_chooses_an_expert_at_most_once(routing):
+    """``moe_experts``' precondition (its docstring): ``moe_route``'s ids
+    meet it, being a ``top_k``'s, also where scores tie; so do the ids
+    every test of this file (the ones tests/test_op_sweep.py names for
+    ``_contrib_moe_experts``) hands the op."""
+    assert _distinct_within_a_token(_routing(routing)[0])
+    tied, _ = llm.moe_route(jnp.zeros((5, 8)), jnp.zeros((12, 8)),
+                            jnp.zeros(12), k=3)
+    assert _distinct_within_a_token(tied)
+    m = _moe_inputs()
+    assert _distinct_within_a_token(
+        np_route(m["x"], m["router"], m["bias"], 3, 2.5)[0])
+    assert not _distinct_within_a_token([[1, 4, 1]])
 
 
 def test_the_bias_changes_the_selection_and_not_the_weights():

@@ -587,6 +587,74 @@ def test_on_the_v5e_latent_attention_hands_the_kernels_what_they_read(
     assert compiled.cost_analysis()["bytes accessed"] < 4.6e9
 
 
+def test_on_the_v5e_the_routed_sum_adds_a_tiles_rows_in_place(
+        v5e, monkeypatch):
+    """``moe_experts`` forward and backward as the v5e's compiler emits
+    them (a quarter of the cells' tokens; the cells' row width, tile and
+    float32 sums): Mosaic takes ``_add_rows_kernel`` (a token's 8 KB copied
+    row by row; a row of a 2-D sum it refuses) and ``_summed_rows``'; the
+    two loops add into ``(tokens, 16, 128)`` sums through it, in place:
+    each call's result aliases its operand and no instruction copies a
+    sum; the one scatter-add left is the flat gradient of the routing
+    weights; and every one of these instructions lies under the
+    ``moe.combine`` scope that ``benchmark/harness/scope_time.py`` reads.
+    The guard a CPU run cannot give: there XLA's scatter-add is the path."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mxnet_tpu.ops import llm
+
+    # traced on the CPU platform the loops would take XLA's scatter-add
+    monkeypatch.setattr(llm, "pallas_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e.devices[0])
+    tokens, units, k, held, width = 4096, 2048, 4, 4, 256
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def forward_and_backward(x, ids, weights, wg, wu, wd, g):
+        out, vjp = jax.vjp(
+            lambda x, weights, wg, wu, wd: llm.moe_experts(
+                x, ids, weights, wg, wu, wd)[0], x, weights, wg, wu, wd)
+        return (out,) + vjp(g)
+
+    text = jax.jit(forward_and_backward).lower(
+        arg((tokens, units)), arg((tokens, k), jnp.int32),
+        arg((tokens, k), jnp.float32), arg((held, units, width)),
+        arg((held, units, width)), arg((held, width, units)),
+        arg((tokens, units))).compile().as_text()
+    lines = text.splitlines()
+    the_sum = re.escape("f32[%d,%d,128]" % (tokens, units // 128))
+
+    def instructions(opcode, result):
+        return [line for line in lines
+                if re.search(r" = %s\S* %s\(" % (result, opcode), line)]
+
+    def kernels(name, result):
+        return [line for line in instructions("custom-call", result)
+                if "tpu_custom_call" in line
+                and line.lstrip().startswith("%" + name)]
+
+    adds = kernels("moe_add_rows", the_sum)
+    assert len(adds) == 2                       # forward's, backward's
+    for line in adds:
+        assert "output_to_operand_aliasing={{}: (2, {})}" in line, line
+    sums = kernels("moe_summed_rows", r"bf16\[%d,%d\]" % (tokens, units))
+    assert len(sums) == 2
+    scatters = [line for line in lines if re.search(r" = f32\S* scatter\(",
+                                                    line)]
+    assert [line.split(" = ")[1].split("{")[0] for line in scatters] \
+        == ["f32[%d]" % (tokens * k)]
+    for line in adds + sums + scatters:    # as ``scope_time._under`` reads
+        assert re.search(r'op_name="[^"]*[/(]moe\.combine[/)]', line), \
+            line[:300]
+    assert not instructions("copy", the_sum)
+    assert not re.search(r" = f32\[%d,%d\]" % (tokens, units), text)
+
+
 # ------------------------------------ why orders, and not layouts, are held
 
 _ASKS_FOR_A_LAYOUT = """
