@@ -417,13 +417,15 @@ def staged_call(block, override, seed, args, train=True):
 
 
 def recomputed(fn, x):
-    """``fn(x)`` (NDArray -> NDArray) with its forward recomputed in the
+    """``fn(x)`` (NDArray -> NDArray, or a tuple of them: a block's stream
+    and what it hands on beside it) with its forward recomputed in the
     backward pass (``jax.checkpoint``) while a step is being staged; plain
     ``fn(x)`` otherwise.  Of the activations inside, only the flash
     attention kernel's results are kept (``ops/attention.py`` names them:
     a small part of what a block holds, and the kernel then runs once).
-    The auxiliary-state updates made inside leave the recomputed region as
-    results and are posted to the staging scope outside it."""
+    Every result leaves the recomputed region as a value of the program,
+    with its gradient.  The auxiliary-state updates made inside leave it as
+    results too and are posted to the staging scope outside it."""
     import jax
 
     from ..ops.attention import FLASH_RESIDUALS
@@ -433,16 +435,20 @@ def recomputed(fn, x):
         return fn(x)
     keys = []
 
+    def is_array(a):
+        return isinstance(a, NDArray)
+
     def pure(value):
         with _StagingScope() as inner:
             out = fn(NDArray(value))
         keys[:] = list(inner.aux_updates)
-        return out._data, tuple(inner.aux_updates[k] for k in keys)
+        return (jax.tree.map(lambda a: a._data, out, is_leaf=is_array),
+                tuple(inner.aux_updates[k] for k in keys))
 
     policy = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
     out, updates = jax.checkpoint(pure, policy=policy)(x._data)
     outer.aux_updates.update(zip(keys, updates))
-    return NDArray(out)
+    return jax.tree.map(NDArray, out)
 
 
 def update_aux_state(param, new_value):

@@ -1,22 +1,29 @@
-"""Decoder language model whose layers mix tokens by a gated short
-convolution or by grouped-query attention, as a list of layer types says,
-with dense and routed feed-forwards (the block of LFM2-style hybrid
-models): the token mixers, and the model built of
-``gluon/nn/mla_moe.py``'s :class:`DecoderBlock`s.  Docs: docs/LLM_OPS.md.
+"""Decoder language model whose layers mix tokens as a list of layer types
+says: by a gated short convolution, by grouped-query attention over the
+whole row, or by the same attention inside a sliding window, with dense and
+routed feed-forwards (the blocks of LFM2-style hybrids and of
+Qwen3-MoE-style models with windowed layers): the token mixers, and the
+model built of ``gluon/nn/mla_moe.py``'s :class:`DecoderBlock`s.  Docs:
+docs/LLM_OPS.md.
 
 - :class:`GQAttention`: causal grouped-query attention with an RMS norm
   on every head of ``q`` and ``k`` before the rotation (by halves):
   ``gqa_qkv`` (``ops/llm.py``), the flash kernels reading each key head
-  from where it lies, ``gqa_out``.
+  from where it lies, ``gqa_out``.  ``window``: a query sees that many
+  keys, itself the last (the kernels skip what lies behind).  ``rotary``:
+  the layer's rotary scaling (a ``config.json``'s rope parameters, yarn's
+  among them), handed to the operator as frequencies and an amplitude.
 - :class:`ShortConv`: ``[b; c; x] = W_in h``; a depthwise causal
   convolution of ``b * x`` over ``kernel_size`` positions; ``W_out (c *
   conv)``.
-- :class:`ConvAttentionMoELM`: a :class:`DecoderLM` whose layer ``i`` mixes
-  by ``layer_types[i]``, the first ``num_dense_layers`` with a dense
-  feed-forward and the rest with routed experts and no shared one; the
-  head tied to the embedding.
+- :class:`LayerTypesMoELM`: a :class:`DecoderLM` whose layer ``i`` mixes
+  by ``layer_types[i]`` (``"conv"``, ``"full_attention"``,
+  ``"sliding_attention"``), the first ``num_dense_layers`` with a dense
+  feed-forward and the rest with routed experts and no shared one.
+  ``ConvAttentionMoELM`` is its name from before it knew windows.
 
-Named scopes (``xray.scope``): ``gqa.proj``, ``gqa.attention``,
+Named scopes (``xray.scope``): ``gqa.proj``, ``gqa.attention`` (a layer
+over the whole row), ``swa.attention`` (a layer with a window),
 ``shortconv.proj``, ``shortconv.conv``, and the ``moe.*`` and ``lm_head``
 of ``mla_moe.py``.
 """
@@ -25,10 +32,12 @@ from __future__ import annotations
 
 from ... import initializer as _init
 from ... import xray as _xray
+from ...ops.llm import rotary_frequencies
 from ..block import HybridBlock
 from .mla_moe import DecoderLM, feed_forward
 
-__all__ = ["GQAttention", "ShortConv", "ConvAttentionMoELM"]
+__all__ = ["GQAttention", "ShortConv", "LayerTypesMoELM",
+           "ConvAttentionMoELM"]
 
 
 class GQAttention(HybridBlock):
@@ -36,14 +45,22 @@ class GQAttention(HybridBlock):
     ``num_kv_heads`` key / value heads of ``head_dim``; query head ``h``
     reads key head ``h // (num_heads // num_kv_heads)``.  The parameters
     have the shapes of the published checkpoints' ``q_proj`` .. ``out_proj``
-    and ``q_layernorm`` / ``k_layernorm``."""
+    and ``q_layernorm`` / ``k_layernorm``.  ``window``: query ``i`` sees the
+    keys ``i - window < j <= i`` (sliding-window attention).  ``rotary``: a
+    dict of :func:`~mxnet_tpu.ops.llm.rotary_frequencies`' arguments under
+    their ``config.json`` names (``rope_type``, ``rope_theta``, ``factor``,
+    ...) in place of ``rope_theta``."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  rope_theta=10000.0, epsilon=1e-6, weight_std=0.02,
-                 **kwargs):
+                 window=None, rotary=None, **kwargs):
         super().__init__(**kwargs)
         head_dim = head_dim or units // num_heads
-        self._theta, self._epsilon = rope_theta, epsilon
+        self._epsilon, self._window = epsilon, window
+        self._rotary = {"theta": rope_theta}
+        if rotary is not None:
+            inv_freq, amplitude = rotary_frequencies(head_dim, **rotary)
+            self._rotary = {"inv_freq": inv_freq, "amplitude": amplitude}
         self._sm_scale = head_dim ** -0.5
         init = _init.Normal(weight_std)
         shapes = {
@@ -65,10 +82,12 @@ class GQAttention(HybridBlock):
                        qnorm_weight, knorm_weight):
         q, k, v = F.contrib.gqa_qkv(
             x, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
-            theta=self._theta, eps=self._epsilon)
-        with _xray.scope("gqa.attention"):
+            eps=self._epsilon, **self._rotary)
+        with _xray.scope("swa.attention" if self._window
+                         else "gqa.attention"):
             o = F.contrib.flash_attention(q, k, v, causal=True,
-                                          sm_scale=self._sm_scale)
+                                          sm_scale=self._sm_scale,
+                                          window=self._window)
         return F.contrib.gqa_out(o, o_weight)
 
 
@@ -100,34 +119,50 @@ class ShortConv(HybridBlock):
                                     num_hidden=self._units, flatten=False)
 
 
-class ConvAttentionMoELM(DecoderLM):
-    """:class:`DecoderLM` of short-convolution and grouped-query attention
-    blocks.  Layer ``i`` mixes tokens by :class:`GQAttention` where
-    ``layer_types[i] == "full_attention"``, by :class:`ShortConv` where it
-    is ``"conv"``; its feed-forward is dense (``intermediate_size``) for ``i
-    < num_dense_layers``, else :class:`RoutedExperts` without a shared
-    expert.  The head is tied to the embedding unless ``tie_embedding`` is
-    false.
+class LayerTypesMoELM(DecoderLM):
+    """:class:`DecoderLM` whose layer ``i`` mixes tokens by
+    ``layer_types[i]``: ``"conv"`` a :class:`ShortConv`,
+    ``"full_attention"`` a :class:`GQAttention`, ``"sliding_attention"``
+    one with ``sliding_window``; its feed-forward is dense
+    (``intermediate_size``) for ``i < num_dense_layers``, else
+    :class:`RoutedExperts` without a shared expert.  The head is tied to
+    the embedding unless ``tie_embedding`` is false.
 
     The keyword arguments carry the names of the model's ``config.json``;
     ``router_outputs`` is its ``num_experts`` (the router's width), and
     ``held_experts`` ``(first, count)`` gives this chip's share of every
-    routed layer, ``bias_update_rate`` is :class:`RoutedExperts`' (the
-    model's ``use_expert_bias``: a training loop keeps the experts' loads
-    level through the selection bias)."""
+    routed layer.  ``rope_parameters``: per layer type, the rotary scaling
+    of its attention layers (:class:`GQAttention`'s ``rotary``);
+    ``rope_theta`` serves the types it does not name.  ``scoring_func`` and
+    ``router_aux_loss_coef`` are :class:`RoutedExperts`' ``scoring`` and
+    ``balance_loss_weight`` (a softmax router trains under the auxiliary
+    balancing loss), ``router_trained_by`` its option of that name;
+    ``bias_update_rate`` is :class:`RoutedExperts`' (the
+    model's ``use_expert_bias``: a training loop keeps a sigmoid router's
+    loads level through the selection bias)."""
 
-    def __init__(self, vocab_size, hidden_size, layer_types, num_dense_layers,
-                 intermediate_size, moe_intermediate_size, router_outputs,
-                 num_experts_per_tok, num_attention_heads,
-                 num_key_value_heads, conv_L_cache=3, held_experts=None,
-                 routed_scaling_factor=1.0, route_epsilon=1e-6,
-                 bias_update_rate=0.0, rope_theta=10000.0, norm_eps=1e-5,
-                 weight_std=0.02, tie_embedding=True, **kwargs):
-        mixers = {
-            "full_attention": lambda: GQAttention(
+    def __init__(self, vocab_size, hidden_size, layer_types,
+                 moe_intermediate_size, router_outputs, num_experts_per_tok,
+                 num_attention_heads, num_key_value_heads,
+                 num_dense_layers=0, intermediate_size=None, head_dim=None,
+                 sliding_window=None, rope_parameters=None, conv_L_cache=3,
+                 held_experts=None, routed_scaling_factor=1.0,
+                 route_epsilon=1e-6, bias_update_rate=0.0,
+                 scoring_func="sigmoid", router_aux_loss_coef=0.0,
+                 router_trained_by="loss",
+                 rope_theta=10000.0, norm_eps=1e-5, weight_std=0.02,
+                 tie_embedding=True, **kwargs):
+        def attention(kind, **how):
+            return lambda: GQAttention(
                 hidden_size, num_attention_heads, num_key_value_heads,
-                rope_theta=rope_theta, epsilon=norm_eps,
-                weight_std=weight_std, prefix="attn_"),
+                head_dim=head_dim, rope_theta=rope_theta, epsilon=norm_eps,
+                weight_std=weight_std, prefix="attn_",
+                rotary=(rope_parameters or {}).get(kind), **how)
+
+        mixers = {
+            "full_attention": attention("full_attention"),
+            "sliding_attention": attention("sliding_attention",
+                                           window=sliding_window),
             "conv": lambda: ShortConv(hidden_size, conv_L_cache,
                                       weight_std=weight_std, prefix="conv_"),
         }
@@ -137,7 +172,9 @@ class ConvAttentionMoELM(DecoderLM):
             held_experts=held_experts and tuple(held_experts),
             routed_scaling_factor=routed_scaling_factor, shared=False,
             route_epsilon=route_epsilon,
-            bias_update_rate=bias_update_rate))
+            bias_update_rate=bias_update_rate, scoring=scoring_func,
+            balance_loss_weight=router_aux_loss_coef,
+            router_trained_by=router_trained_by))
         dense = feed_forward(hidden_size, intermediate_size,
                              weight_std=weight_std)
         super().__init__(
@@ -146,3 +183,7 @@ class ConvAttentionMoELM(DecoderLM):
              for i, kind in enumerate(layer_types)],
             epsilon=norm_eps, weight_std=weight_std,
             tie_embedding=tie_embedding, **kwargs)
+
+
+# the name an accepted configuration file of the benchmark builds it by
+ConvAttentionMoELM = LayerTypesMoELM

@@ -10,9 +10,10 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   norm on each latent, rotary embedding on a part of each head that all
   heads share on the key side, scores over ``nope + rope`` and values of
   another head size.
-- :class:`RoutedExperts`: sigmoid-scored top-k routing with a selection
-  bias, the held experts' grouped feed-forward and, unless told not to,
-  one shared expert.  The
+- :class:`RoutedExperts`: top-k routing over sigmoid scores with a
+  selection bias or over softmax scores without one, the held experts'
+  grouped feed-forward and, unless told not to, one shared expert; a
+  softmax router may hand its balancing term to the loss.  The
   layer is told which experts it holds (``held_experts=(first, count)``):
   the router keeps the model's width, selection and normalisation run over
   all experts, and the layer computes its own experts' part of the sum:
@@ -21,11 +22,18 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   the token mixer and the feed-forward it is given, its forward recomputed
   in the backward pass while a step is staged.  The one block class of
   every decoder model here (``gluon/nn/hybrid_lm.py`` has the other
-  mixers).
+  mixers).  **A side term for the loss**: while training, a feed-forward
+  may return ``(y, term)``, ``term`` a scalar that belongs in the loss
+  (a router's balancing term, already weighted); the block then returns
+  ``(h, term)`` through its recomputation, the model sums the blocks'
+  terms and returns ``(hidden, side)``, and the loss block adds ``side``
+  to every row's loss: a value of the program from the block to the loss,
+  with its gradient.
 - :class:`DecoderLM`: embedding, blocks, final norm and a head that may be
-  tied to the embedding; it returns the normed hidden states,
-  ``net.head`` makes logits of them, and :class:`NextTokenLoss` fuses the
-  head with the loss over token chunks.
+  tied to the embedding; it returns the normed hidden states (with the
+  blocks' side term where they give one), ``net.head`` makes logits of
+  them, and :class:`NextTokenLoss` fuses the head with the loss over token
+  chunks.
 - :class:`MLAMoELM`: a :class:`DecoderLM` of latent-attention blocks with
   one multi-token prediction module that shares embedding and head.  It
   returns the two streams' normed hidden states;
@@ -33,11 +41,14 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
 
 Named scopes (``xray.scope``): ``mla.proj``, ``mla.attention``,
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
-``moe.shared``, ``mtp``, ``lm_head``.  Counters: every :class:`RoutedExperts`
-keeps ``held_pairs`` (pairs routed to its held experts in the last step)
-and ``max_load`` (the largest held expert's pairs over their mean) as
-parameters that take no gradient, updated by the step like batch-norm
-statistics, so they leave the step program with its state.
+``moe.shared``, ``moe.aux``, ``mtp``, ``lm_head``.  Counters: every
+:class:`RoutedExperts` keeps ``held_pairs`` (pairs routed to its held
+experts in the last step) and ``max_load`` (the largest held expert's
+pairs over their mean), and one with a balancing loss ``balance_term``
+(its router's last term, unweighted: 1 when the experts are chosen and
+scored alike), as parameters that take no gradient, updated by the step
+like batch-norm statistics, so they leave the step program with its
+state.
 """
 
 from __future__ import annotations
@@ -141,7 +152,24 @@ class RoutedExperts(HybridBlock):
 
     ``num_experts``: the router's width (the model's experts);
     ``held_experts`` ``(first, count)``: the consecutive expert ids held
-    here, all of them by default.  ``router_bias`` takes no gradient (the
+    here, all of them by default.  ``scoring``: ``"sigmoid"`` scores with a
+    selection bias, or ``"softmax"`` scores over all the experts and no
+    bias (the layer then has no ``router_bias`` parameter).
+    ``balance_loss_weight``: when not zero, a training forward returns
+    ``(y, balance_loss_weight x the router's balancing term)``
+    (``ops/llm.py::moe_route(balance=True)``: ``experts x sum_e f_e P_e``
+    over this call's tokens), which :class:`DecoderBlock` carries to the
+    loss, and keeps the term in ``balance_term``.
+    ``router_trained_by``: ``"loss"``, the routing weights carry the
+    loss's gradient to the router; or ``"balance"``, they reach the
+    experts' sum as constants and ``router_weight`` takes no weight decay
+    (``wd_mult`` 0), so the balancing term alone moves the router.  That
+    is what one chip's share of an expert-parallel layer computes without
+    the exchange: the loss's gradient on a routing weight is the stream's
+    gradient times that expert's output, which for an expert held
+    elsewhere is not here, and the held experts' part alone pulls every
+    token towards them.
+    ``router_bias`` takes no gradient (the
     training loop that balances the load owns it); it is drawn N(0,
     ``ROUTER_BIAS_STD``), non-zero so that it takes part in the selection,
     small against the scores' spread (~0.2) so that it does not decide
@@ -155,12 +183,24 @@ class RoutedExperts(HybridBlock):
     def __init__(self, units, hidden_size, num_experts, experts_per_token,
                  held_experts=None, routed_scaling_factor=1.0,
                  weight_std=0.02, shared=True, route_epsilon=1e-20,
-                 bias_update_rate=0.0, **kwargs):
+                 bias_update_rate=0.0, scoring="sigmoid",
+                 balance_loss_weight=0.0, router_trained_by="loss",
+                 **kwargs):
         super().__init__(**kwargs)
         first, held = held_experts or (0, num_experts)
         if first < 0 or held < 1 or first + held > num_experts:
             raise ValueError("held_experts %r do not lie in 0..%d"
                              % ((first, held), num_experts))
+        if scoring != "sigmoid" and bias_update_rate:
+            raise ValueError("a %s router has no selection bias to update"
+                             % scoring)
+        if router_trained_by not in ("loss", "balance") or (
+                router_trained_by == "balance" and not balance_loss_weight):
+            raise ValueError("router_trained_by %r: 'loss', or 'balance' "
+                             "with a balance_loss_weight"
+                             % (router_trained_by,))
+        self._scoring, self._balance = scoring, balance_loss_weight
+        self._by_balance = router_trained_by == "balance"
         self._first, self._held = int(first), int(held)
         self._k, self._scale = experts_per_token, routed_scaling_factor
         self._route_epsilon = route_epsilon
@@ -168,10 +208,16 @@ class RoutedExperts(HybridBlock):
         init = _init.Normal(weight_std)
         with self.name_scope():
             self.router_weight = self.params.get(
-                "router_weight", shape=(num_experts, units), init=init)
-            self.router_bias = self.params.get(
-                "router_bias", shape=(num_experts,), grad_req="null",
-                init=_init.Normal(ROUTER_BIAS_STD))
+                "router_weight", shape=(num_experts, units), init=init,
+                wd_mult=0.0 if self._by_balance else 1.0)
+            if scoring == "sigmoid":
+                self.router_bias = self.params.get(
+                    "router_bias", shape=(num_experts,), grad_req="null",
+                    init=_init.Normal(ROUTER_BIAS_STD))
+            if balance_loss_weight:
+                self.balance_term = self.params.get(
+                    "balance_term", shape=(1,), grad_req="null",
+                    init="zeros")
             self.experts_gate_weight = self.params.get(
                 "experts_gate_weight", shape=(held, units, hidden_size),
                 init=init)
@@ -188,15 +234,24 @@ class RoutedExperts(HybridBlock):
             self.shared = GatedFFN(units, hidden_size, weight_std=weight_std,
                                    prefix="shared_") if shared else None
 
-    def hybrid_forward(self, F, x, router_weight, router_bias,
-                       experts_gate_weight, experts_up_weight,
-                       experts_down_weight, held_pairs, max_load):
+    def hybrid_forward(self, F, x, router_weight, experts_gate_weight,
+                       experts_up_weight, experts_down_weight, held_pairs,
+                       max_load, router_bias=None, balance_term=None):
         from ... import autograd
 
         rows = F.reshape(x, shape=(-1, x.shape[-1]))
-        ids, weights = F.contrib.moe_route(
-            rows, router_weight, router_bias, k=self._k, scale=self._scale,
-            eps=self._route_epsilon)
+        to_the_loss = bool(self._balance) and autograd.is_training()
+        how = dict(k=self._k, scale=self._scale, eps=self._route_epsilon)
+        if self._scoring != "sigmoid":
+            how["scoring"] = self._scoring
+        if to_the_loss:
+            ids, weights, term = F.contrib.moe_route(
+                rows, router_weight, router_bias, balance=True, **how)
+        else:
+            ids, weights = F.contrib.moe_route(rows, router_weight,
+                                               router_bias, **how)
+        if self._by_balance:
+            weights = F.stop_gradient(weights)
         y, pairs, load = F.contrib.moe_experts(
             rows, ids, weights, experts_gate_weight, experts_up_weight,
             experts_down_weight, first_expert=self._first)
@@ -214,7 +269,11 @@ class RoutedExperts(HybridBlock):
         if self.shared is not None:
             with _xray.scope("moe.shared"):
                 y = y + self.shared(rows)
-        return F.reshape(y, shape=x.shape)
+        y = F.reshape(y, shape=x.shape)
+        if not to_the_loss:
+            return y
+        update_aux_state(self.balance_term, F.reshape(term, shape=(1,)))
+        return y, self._balance * term
 
 
 class DecoderBlock(HybridBlock):
@@ -239,7 +298,10 @@ class DecoderBlock(HybridBlock):
 
     def _body(self, h):
         h = h + self.mixer(self.ln1(h))
-        return h + self.ffn(self.ln2(h))
+        y = self.ffn(self.ln2(h))
+        if isinstance(y, tuple):        # with a side term for the loss
+            return h + y[0], y[1]
+        return h + y
 
     def hybrid_forward(self, F, h):
         return recomputed(self._body, h)
@@ -267,7 +329,8 @@ class DecoderLM(HybridBlock):
     Input ``(batch, seq)`` integer token ids.  Result: the normed hidden
     states ``(batch, seq, units)``; ``head(hidden)[i]`` are the logits for
     token ``i + 1`` (:class:`NextTokenLoss` applies the head fused with the
-    loss).  ``blocks``: one ``(mixer, ffn)`` pair of :class:`DecoderBlock`
+    loss).  Where blocks give a side term for the loss (training only: the
+    module docstring), the result is ``(hidden, the sum of the terms)``.  ``blocks``: one ``(mixer, ffn)`` pair of :class:`DecoderBlock`
     arguments per layer.  ``tie_embedding``: the head reads the embedding's
     weight, one parameter with two uses.
 
@@ -307,10 +370,14 @@ class DecoderLM(HybridBlock):
                 param.init = to_the_stream
 
     def hybrid_forward(self, F, tokens):
-        h = self.embed(tokens)
+        h, side = self.embed(tokens), None
         for blk in self.blocks:
             h = blk(h)
-        return self.norm(h)
+            if isinstance(h, tuple):
+                h, term = h
+                side = term if side is None else side + term
+        hidden = self.norm(h)
+        return hidden if side is None else (hidden, side)
 
 
 class MLAMoELM(DecoderLM):
@@ -414,7 +481,9 @@ class NextTokenLoss(Block):
     ``head``: the model's output projection (``DecoderLM.head``; with a
     tied head its weight is the embedding's), applied here, fused with the
     loss over chunks of tokens so that the float32 logits never stand
-    whole.  The labels are the input's own rows of token ids."""
+    whole.  The labels are the input's own rows of token ids.  A model that
+    returns ``(hidden, side)`` has its blocks' side term added to every
+    row's value (so to the mean over rows, once)."""
 
     def __init__(self, head, **kwargs):
         super().__init__(**kwargs)
@@ -423,8 +492,12 @@ class NextTokenLoss(Block):
     def forward(self, hidden, tokens):
         from ... import ndarray as F
 
+        side = None
+        if isinstance(hidden, tuple):
+            hidden, side = hidden
         with _xray.scope("lm_head"):
-            return _head_loss(F, self._head, hidden, tokens, 1)
+            rows = _head_loss(F, self._head, hidden, tokens, 1)
+        return rows if side is None else rows + side
 
 
 class MultiTokenLoss(Block):

@@ -1,11 +1,14 @@
 """Operators of current decoder language models (docs/LLM_OPS.md).
 
-RMS norm, rotary position embedding (adjacent pairs or halves), the
-gated-SiLU feed-forward, the projections of latent attention and of
-grouped-query attention with a norm on every head, a gated short causal
-convolution over the sequence, the router and the held-experts layer of a
-sigmoid-scored mixture of experts (DeepSeek-V3, arXiv:2412.19437), and a
-linear head fused with its cross-entropy over token chunks.  The reference
+RMS norm, rotary position embedding (adjacent pairs or halves, its
+frequencies and amplitude data of the layer: ``rotary_frequencies`` has
+yarn's), the gated-SiLU feed-forward, the projections of latent attention
+and of grouped-query attention with a norm on every head, a gated short
+causal convolution over the sequence, the router (sigmoid scores with a
+selection bias, DeepSeek-V3, arXiv:2412.19437, or softmax scores, with the
+balancing term of Switch Transformer, arXiv:2101.03961) and the
+held-experts layer of a mixture of experts, and a linear head fused with
+its cross-entropy over token chunks.  The reference
 framework has none of them (its transformer helpers are
 ``src/operator/contrib/transformer.cc``).
 
@@ -35,7 +38,7 @@ from .. import xray as _xray
 from ..util import pallas_interpret
 from .registry import OP_INPUT_NAMES, register
 
-__all__ = ["rms_norm", "rope", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
+__all__ = ["rms_norm", "rope", "rotary_frequencies", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
            "gqa_out", "gated_short_conv", "moe_route", "moe_experts",
            "linear_cross_entropy", "expert_tiles"]
 
@@ -59,15 +62,69 @@ def rms_norm(data, gamma, eps=1e-6, **_):
     return (x * scale * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
-def _rotary_tables(seq, dim, theta, halves=False):
+def rotary_frequencies(dim, rope_theta=10000.0, rope_type="default",
+                       factor=1.0, original_max_position_embeddings=None,
+                       beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                       **_):
+    """A layer's rotary scaling as data: ``(inv_freq, amplitude)``, the
+    ``dim / 2`` angles a position advances each pair by (a tuple of floats,
+    computed in float64) and what ``cos`` and ``sin`` are multiplied by.
+    The arguments carry the names of a ``config.json``'s rope parameters.
+
+    ``"default"``: ``theta^(-2i/dim)``, amplitude 1.  ``"yarn"`` (Peng et
+    al., arXiv:2309.00071): a pair that turns more than ``beta_fast`` times
+    over the ``original_max_position_embeddings`` keeps its frequency, one
+    that turns less than ``beta_slow`` times has it divided by ``factor``,
+    the pairs between are blended linearly over their index: with ``c(r) =
+    dim ln(original / (2 pi r)) / (2 ln theta)``, ``low = max(floor(c(
+    beta_fast)), 0)``, ``high = min(ceil(c(beta_slow)), dim - 1)``,
+    ``ramp_i = clip((i - low) / (high - low), 0, 1)``: ``inv_freq_i = theta^(-2i/dim) (ramp_i / factor + 1 -
+    ramp_i)``.  The amplitude is ``attention_factor``, by default ``0.1 ln
+    factor + 1``."""
+    f64 = _np.float64  # mxlint: disable=dtype-default -- host table
+    inv = float(rope_theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
+    if rope_type == "default":
+        return tuple(float(v) for v in inv), 1.0
+    if rope_type != "yarn":
+        raise ValueError("rotary_frequencies: rope_type %r is not "
+                         "'default' or 'yarn'" % (rope_type,))
+
+    def pair_that_turns(times):
+        return dim * math.log(original_max_position_embeddings
+                              / (times * 2 * math.pi)) \
+            / (2 * math.log(rope_theta))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = _np.clip((_np.arange(dim // 2, dtype=f64) - low) / (high - low),
+                    0, 1)
+    inv = inv / factor * ramp + inv * (1 - ramp)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return tuple(float(v) for v in inv), float(attention_factor)
+
+
+def _rotary_tables(seq, dim, theta, halves=False, inv_freq=None,
+                   amplitude=1.0):
     """``cos``, ``sin`` of ``p * theta^(-2i/dim)`` for positions ``p <
     seq``, each on both lanes of its pair, the sine negative on a pair's
     first lane: ``(seq, dim)`` float32 constants from a float64 host table
     (float32 angles at position 4096 and theta 3.2e7 are wrong in the
     fourth digit).  A pair is the adjacent lanes ``(2i, 2i + 1)``, or with
-    ``halves`` the lanes ``(i, i + dim / 2)``."""
+    ``halves`` the lanes ``(i, i + dim / 2)``.  ``inv_freq`` (``dim / 2``
+    values) takes the place of ``theta``'s frequencies and both tables are
+    multiplied by ``amplitude``: a layer's rotary scaling
+    (``rotary_frequencies``)."""
     f64 = _np.float64  # mxlint: disable=dtype-default -- host table, cast below
-    inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
+    if inv_freq is None:
+        inv = float(theta) ** (-_np.arange(0, dim, 2, dtype=f64) / dim)
+    else:
+        inv = _np.asarray(inv_freq, dtype=f64)
+        if inv.shape != (dim // 2,):
+            raise ValueError("rotary tables: %d frequencies for %d lanes"
+                             % (inv.size, dim))
     angle = _np.arange(seq, dtype=f64)[:, None] * inv[None, :]
     if halves:
         cos = _np.tile(_np.cos(angle), 2)
@@ -77,6 +134,8 @@ def _rotary_tables(seq, dim, theta, halves=False):
         cos = _np.repeat(_np.cos(angle), 2, axis=-1)
         sin = _np.repeat(_np.sin(angle), 2, axis=-1)
         sin[:, 0::2] *= -1
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     return jnp.asarray(cos, jnp.float32), jnp.asarray(sin, jnp.float32)
 
 
@@ -137,16 +196,20 @@ _rotary.defvjp(_rotary_fwd, _rotary_bwd)
 
 
 @register("_contrib_rope", aliases=("rope",))
-def rope(data, theta=10000.0, halves=False, **_):
+def rope(data, theta=10000.0, halves=False, inv_freq=None, amplitude=1.0,
+         **_):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(..., seq, dim)``: position ``p`` rotates the adjacent pair ``(2i,
     2i+1)`` by ``p * theta^(-2i/dim)`` (``rope_interleave``), or with
     ``halves`` the pair ``(i, i + dim/2)`` ("rotate half"):
     ``x * cos + swap_pairs(x) * sin`` in float32, the angles constants
     computed in float64 when the op is traced; its gradient is the
-    rotation back."""
+    rotation back.  ``inv_freq`` and ``amplitude``: a layer's rotary
+    scaling in place of ``theta`` (``rotary_frequencies``); with an
+    amplitude the op is a rotation times that number."""
     halves = bool(halves)
-    cos, sin = _rotary_tables(data.shape[-2], data.shape[-1], theta, halves)
+    cos, sin = _rotary_tables(data.shape[-2], data.shape[-1], theta, halves,
+                              inv_freq, float(amplitude))
     return _rotary(data, cos, sin, 0, halves)
 
 
@@ -252,7 +315,7 @@ def mla_out(data, weight, **_):
 
 @register("_contrib_gqa_qkv", num_outputs=3, aliases=("gqa_qkv",))
 def gqa_qkv(data, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
-            theta=10000.0, eps=1e-6, **_):
+            theta=10000.0, eps=1e-6, inv_freq=None, amplitude=1.0, **_):
     """The projections of grouped-query attention with a norm on every
     head (Ainslie et al., arXiv:2305.13245; the head norms of Dehghani et
     al., arXiv:2302.05442), from the block's input ``(B, S, units)`` to
@@ -264,10 +327,13 @@ def gqa_qkv(data, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
     every head of ``q`` and of ``k`` is RMS-normalised over its ``d`` values
     with the one learned scale, then rotated by halves (pairs ``(i, i + d /
     2)``).  The head size is read from the norms' scales, the head counts
-    from the weights.  Every product writes ``(B, heads, S, d)`` itself."""
+    from the weights.  Every product writes ``(B, heads, S, d)`` itself.
+    ``inv_freq`` / ``amplitude``: the layer's rotary scaling in place of
+    ``theta``, as in :func:`rope`."""
     d = qnorm_weight.shape[0]
     with _xray.scope("gqa.proj"):
-        cos, sin = _rotary_tables(data.shape[-2], d, theta, True)
+        cos, sin = _rotary_tables(data.shape[-2], d, theta, True, inv_freq,
+                                  float(amplitude))
         q, k, v = (_by_head(data, w.reshape(-1, d, w.shape[-1]))
                    for w in (q_weight, k_weight, v_weight))
         q = _rotary(rms_norm(q, qnorm_weight, eps=eps), cos, sin, 0, True)
@@ -356,25 +422,57 @@ def gated_short_conv(data, weight, **_):
         return _gated_conv(data, weight)
 
 
-@register("_contrib_moe_route", num_outputs=2, aliases=("moe_route",))
-def moe_route(data, router_weight, router_bias, k=8, scale=1.0, eps=1e-20,
-              **_):
-    """Sigmoid-scored top-``k`` routing with a selection-only bias
-    (``noaux_tc`` with one group): ``s = sigmoid(x W_g)`` in float32; the
-    ``k`` largest of ``s + bias`` are selected; a selected expert weighs
-    ``s / (sum of the selected s + eps) * scale``: the bias selects, it
-    does not weigh.  -> (expert ids ``(..., k)`` int32, weights ``(..., k)``
-    float32).  ``router_weight``: ``(experts, in)``."""
+def _flag(value):
+    """An on / off attribute, which a symbol hands over as a string."""
+    return str(value).lower() in ("true", "1")
+
+
+@register("_contrib_moe_route", aliases=("moe_route",),
+          num_outputs=lambda attrs: 3 if _flag(attrs.get("balance")) else 2)
+def moe_route(data, router_weight, router_bias=None, k=8, scale=1.0,
+              eps=1e-20, scoring="sigmoid", balance=False, **_):
+    """Top-``k`` routing over float32 scores ``s`` of ``x W_g``:
+    ``scoring`` ``"sigmoid"`` (``noaux_tc`` with one group) or
+    ``"softmax"`` over all the experts.  The ``k`` largest of ``s`` are
+    selected, of ``s + router_bias`` where a selection bias is given; a
+    selected expert weighs ``s / (sum of the selected s + eps) * scale``:
+    the bias selects, it does not weigh.  -> (expert ids ``(..., k)``
+    int32, weights ``(..., k)`` float32).  ``router_weight``: ``(experts,
+    in)``.
+
+    ``balance``: a third result, the router's term of the auxiliary
+    balancing loss (Switch Transformer, arXiv:2101.03961, as the
+    softmax-routed families train with it): ``experts x sum_e f_e P_e``,
+    ``f_e`` the share of the ``tokens x k`` pairs that chose expert ``e``
+    (a count: no gradient), ``P_e`` the mean of ``s_e`` over the tokens; a
+    float32 scalar, 1 when every expert is chosen and scored alike."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError("moe_route: scoring %r is not 'sigmoid' or "
+                         "'softmax'" % (scoring,))
     with _xray.scope("moe.route"):
-        s = jax.nn.sigmoid(_dot(data.astype(jnp.float32),
-                                router_weight.astype(jnp.float32),
-                                ((data.ndim - 1,), (1,))))
-        _, ids = lax.top_k(s + lax.stop_gradient(
-            router_bias.astype(jnp.float32)), int(k))
+        logits = _dot(data.astype(jnp.float32),
+                      router_weight.astype(jnp.float32),
+                      ((data.ndim - 1,), (1,)))
+        s = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        chosen_by = s if router_bias is None else s + lax.stop_gradient(
+            router_bias.astype(jnp.float32))
+        _, ids = lax.top_k(chosen_by, int(k))
         picked = jnp.take_along_axis(s, ids, axis=-1)
         weights = picked / (jnp.sum(picked, axis=-1, keepdims=True)
                             + float(eps)) * scale
+    if not _flag(balance):
         return ids.astype(jnp.int32), weights
+    with _xray.scope("moe.aux"):
+        experts = s.shape[-1]
+        # a compare and a sum, fused; a scatter-add of ones costs the
+        # v5e 0.24 us a pair (PERF.md, PR 33)
+        chosen = jnp.sum(ids.reshape(-1)[:, None] == jnp.arange(experts),
+                         axis=0, dtype=jnp.float32)
+        share = lax.stop_gradient(chosen / ids.size)
+        mean_score = jnp.mean(s.reshape(-1, experts), axis=0)
+        return (ids.astype(jnp.int32), weights,
+                experts * jnp.sum(share * mean_score))
 
 
 # ------------------------------------------------------ the held experts

@@ -165,10 +165,12 @@ class _OptimizerUpdate(_UpdateRule):
     recomputed host-side each step by :meth:`host_scalars` and enter
     the jitted program as traced arguments via ``scalar_feed``, so
     schedules never recompile and eager vs functional numerics agree
-    to the bit.
+    to the bit.  An optimizer that knows no parameters yet is given
+    ``params`` as ``gluon.Trainer`` gives them, so a Parameter's
+    ``lr_mult`` and ``wd_mult`` count in those scalars.
     """
 
-    def __init__(self, optimizer, dtypes):
+    def __init__(self, optimizer, dtypes, params):
         import jax.numpy as jnp
 
         from ..compiled_step import _state_leaves
@@ -180,6 +182,8 @@ class _OptimizerUpdate(_UpdateRule):
                 "host-scalar math in update()) — see compiled_step.py "
                 "for the supported set" % type(optimizer).__name__)
         self.opt = optimizer
+        if not optimizer.param_dict:
+            optimizer.param_dict = dict(enumerate(params))
         self.templates = []        # per-index probe state tree
         self.leaf_dtypes = []      # per-index [leaf dtype, ...]
         for i, dt in enumerate(dtypes):
@@ -725,7 +729,8 @@ class GluonTrainStep:
         # is held between steps: everything below is written once
         self._rule = (_FusedSGD(lr, momentum, wd, dtypes)
                       if optimizer is None
-                      else _OptimizerUpdate(optimizer, dtypes))
+                      else _OptimizerUpdate(optimizer, dtypes,
+                                            self.trainable))
         self._form = (_FlatShards(self.mesh) if zero
                       else _InCompilersOrder(self.mesh, param_spec_fn))
         self._compute_dtype = compute_dtype

@@ -307,7 +307,7 @@ def test_causal_index_maps_against_brute_force(block_q, block_k, sq, sk):
         for j in range(num_k):
             assert bool(att._runs(i, j, block_q, block_k)) == runs[i, j]
             if runs[i, j]:
-                assert bool(att._crosses_diagonal(
+                assert bool(att._crosses_an_edge(
                     i, j, block_q, block_k)) == crosses[i, j]
 
     assert runs[:, 0].all()                 # key block 0 runs for every i
@@ -340,7 +340,7 @@ def test_the_tiles_of_a_diagonal_block_cover_its_lower_half(block, whole):
     anyway, and no pair is computed twice."""
     count = np.zeros((block, block), int)             # (query, key)
     kept = np.zeros((block, block), bool)
-    for qs, ks, (row0, col0) in att._step_tiles(True, 3, 3, block, block,
+    for qs, ks, (row0, col0) in att._step_tiles(0, 3, 3, block, block,
                                                 whole):
         count[qs, ks] += 1
         rows = np.arange(block)[qs][:, None] - np.arange(block)[qs][0] + row0
@@ -351,7 +351,7 @@ def test_the_tiles_of_a_diagonal_block_cover_its_lower_half(block, whole):
     if block % att._TRIANGLE_TILE == 0 and block > att._TRIANGLE_TILE:
         n = block // att._TRIANGLE_TILE
         assert count.sum() == block * block * (n + 1) // (2 * n)
-    assert att._step_tiles(False, 3, 2, block, block, whole) == [
+    assert att._step_tiles(None, 3, 2, block, block, whole) == [
         (slice(None), slice(None), None)]
 
 
@@ -368,3 +368,190 @@ def test_flash_diagonal_blocks_cut_into_tiles_match_the_reference():
     np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
     for a, w in zip(got[1:], want[1:]):
         np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------------- a sliding window (PR 34)
+
+
+def _band(sq, sk, window):
+    rows, cols = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    return (cols <= rows) & (rows - cols < window)
+
+
+# (sequence, window, (block_q, block_k), query heads a key head): a window
+# below, equal to, not a multiple of and above a block; equal blocks that
+# are cut into tiles (512 = two tiles a side) and blocks that are not
+WINDOWED = [(512, 64, (128, 128), 1), (512, 128, (128, 128), 2),
+            (512, 200, (128, 128), 4), (512, 300, (128, 256), 1),
+            (768, 256, (256, 128), 2), (1024, 512, (512, 512), 1),
+            (1024, 300, (512, 512), 2), (1024, 700, (512, 512), 1),
+            (2048, 1024, (512, 512), 1)]
+
+
+@pytest.mark.parametrize("seq,window,blocks,group", WINDOWED,
+                         ids=lambda v: str(v).replace(" ", ""))
+def test_flash_with_a_window_matches_the_reference(seq, window, blocks,
+                                                   group):
+    """The three kernels through the interpreter against
+    ``mha_reference(window=)``: forward and all gradients."""
+    heads = 2 * group
+    q, g = (_rand((1, heads, seq, 32), seed=70 + i) for i in range(2))
+    k, v = (_rand((1, 2, seq, 32), seed=72 + i) for i in range(2))
+    got = _with_grads(att.flash_attention, True, q, k, v, g, interpret=True,
+                      block_q=blocks[0], block_k=blocks[1], window=window)
+    with jax.default_matmul_precision("highest"):
+        want = _with_grads(att.mha_reference, True, q, k, v, g,
+                           window=window)
+        causal = att.mha_reference(q, k, v, causal=True)
+    assert np.abs(np.asarray(want[0]) - np.asarray(causal)).max() > 1e-2
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for a, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, w, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [256, 300])
+def test_a_window_that_reaches_the_first_key_is_causal_to_the_last_bit(
+        window):
+    q, g = (_rand((1, 4, 256, 32), seed=80 + i) for i in range(2))
+    k, v = (_rand((1, 2, 256, 32), seed=82 + i) for i in range(2))
+    how = dict(interpret=True, block_q=128, block_k=128)
+    with_window = _with_grads(att.flash_attention, True, q, k, v, g,
+                              window=window, **how)
+    causal = _with_grads(att.flash_attention, True, q, k, v, g, **how)
+    for a, b in zip(with_window, causal):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the CPU platform's path takes the window too
+    np.testing.assert_array_equal(
+        np.asarray(att.flash_attention(q, k, v, causal=True, window=window)),
+        np.asarray(att.mha_reference(q, k, v, causal=True)))
+
+
+def test_a_window_takes_causal_self_attention():
+    q, k = _rand((1, 2, 256, 32)), _rand((1, 2, 128, 32))
+    with pytest.raises(mx.base.MXNetError, match="window"):
+        att.flash_attention(q, q, q, causal=False, window=64)
+    with pytest.raises(mx.base.MXNetError, match="window"):
+        att.flash_attention(q, k, k, causal=True, window=64)
+
+
+@pytest.mark.parametrize("window", [1, 100, 256, 384, 512, 1000, 1024, 3000])
+@pytest.mark.parametrize("block_q,block_k",
+                         [(256, 512), (512, 512), (128, 128), (512, 256),
+                          (1024, 1024)])
+def test_window_index_maps_against_brute_force(block_q, block_k, window):
+    """The block rule's counts against a brute-force count: the steps that
+    run and the steps that mask are the blocks the band touches and the
+    blocks an edge crosses; the inner grid axes are as long as the most
+    blocks a row or column of the band runs; every step that runs names its
+    own block, the others a block already fetched, so the blocks fetched
+    are the blocks that run."""
+    seq = 4096
+    num = seq // block_q, seq // block_k
+    num_q, num_k = num
+    seen = _band(seq, seq, window).reshape(num_q, block_q, num_k, block_k)
+    runs = seen.any(axis=(1, 3))
+    crosses = runs & ~seen.all(axis=(1, 3))
+    ran = masked = 0
+    for i in range(num_q):
+        for j in range(num_k):
+            run = bool(att._runs(i, j, block_q, block_k, window))
+            assert run == runs[i, j]
+            ran += run
+            if run:
+                mask = bool(att._crosses_an_edge(i, j, block_q, block_k,
+                                                 window))
+                assert mask == crosses[i, j]
+                masked += mask
+    assert (ran, masked) == (runs.sum(), crosses.sum())
+    key_steps, query_steps = att._band_blocks(block_q, block_k, num_q, num_k,
+                                              window)
+    assert key_steps == runs.sum(axis=1).max()
+    assert query_steps == runs.sum(axis=0).max()
+    assert key_steps <= (block_q + window - 2) // block_k + 2
+
+    # forward and dq: grid step (i, at) stands for key block first + at
+    for i in range(num_q):
+        first = int(att._first_key_block(i, block_q, block_k, window))
+        assert first == np.flatnonzero(runs[i])[0]
+        named = [int(att._kv_block(i, at, block_q, block_k, num_k, window))
+                 for at in range(key_steps)]
+        own = [first + at for at in range(key_steps)]
+        for at in range(key_steps):
+            inside = own[at] < num_k and runs[i, own[at]]
+            assert inside == bool(
+                own[at] < num_k and att._runs(i, own[at], block_q, block_k,
+                                              window))
+            assert named[at] == (own[at] if inside else named[at - 1])
+        assert len(set(named)) == runs[i].sum()
+    # dk/dv: grid step (j, at) stands for query block first + at
+    for j in range(num_k):
+        first = int(att._first_query_block(j, block_q, block_k, num_q))
+        assert first == np.flatnonzero(runs[:, j])[0]
+        named = [int(att._q_block(at, j, block_q, block_k, num_q, window))
+                 for at in range(query_steps)]
+        for at in range(query_steps):
+            inside = first + at < num_q and runs[first + at, j]
+            assert named[at] == (first + at if inside else named[at - 1])
+        assert len(set(named)) == runs[:, j].sum()
+
+
+@pytest.mark.parametrize("whole", ["keys", "queries"])
+@pytest.mark.parametrize("block,window", [
+    (1024, 1024), (512, 1024), (512, 300), (512, 700), (1024, 100),
+    (1024, 1500), (256, 300), (384, 500)])
+def test_the_tiles_of_a_block_an_edge_crosses_cover_the_band(block, window,
+                                                             whole):
+    """What ``_step_tiles`` leaves out of a masked block is masked anyway,
+    no pair is computed twice, and every masked offset is one of
+    ``_masked_offsets`` (where the tiles depend on it)."""
+    seq = 4 * block + (block if window > 2 * block else 0)
+    num = seq // block
+    band = _band(seq, seq, window).reshape(num, block, num, block)
+    offsets = att._masked_offsets(block, block, window)
+    for i in range(num):
+        for j in range(num):
+            part = band[i, :, j, :]
+            if not part.any() or part.all():
+                continue
+            offset = i - j if att._cuts_tiles(block, block) else 0
+            assert offset in offsets
+            count = np.zeros((block, block), int)
+            kept = np.zeros((block, block), bool)
+            for qs, ks, (row0, col0) in att._step_tiles(
+                    offset, i, j, block, block, whole, window):
+                count[qs, ks] += 1
+                rows = np.arange(block)[qs][:, None] \
+                    - np.arange(block)[qs][0] + row0
+                cols = np.arange(block)[ks][None, :] \
+                    - np.arange(block)[ks][0] + col0
+                kept[qs, ks] |= (cols <= rows) & (rows - cols < window)
+            assert (kept == part).all() and count.max() == 1
+    if (block, window) == (1024, 1024):
+        # both blocks of a query block half masked: 10 tiles of 16 each
+        assert offsets == [0, 1]
+        for offset in offsets:
+            tiles = att._step_tiles(offset, 5, 5 - offset, block, block,
+                                    whole, window)
+            assert sum(len(range(*qs.indices(block)))
+                       * len(range(*ks.indices(block)))
+                       for qs, ks, _ in tiles) == 10 * 256 * 256
+
+
+def test_a_window_layers_grid_holds_the_band_only():
+    """At the cell's shape (16,384, window 1,024, blocks 1,024 / 1,024) the
+    three kernels' inner grid axes are 2 long where causal attention has
+    16: what lies behind the window is not in the grid."""
+    grids = {}
+
+    def shapes(q, window):
+        return jax.make_jaxpr(lambda q: jax.vjp(
+            lambda q: att._flash(q, q, q, 1.0, True, window, 1024, 1024,
+                                 False), q)[1](q))(q)
+
+    q = jax.ShapeDtypeStruct((1, 2, 16384, 128), jnp.bfloat16)
+    for window in (None, 1024):
+        grids[window] = sorted(
+            eqn.params["grid_mapping"].grid
+            for eqn in _equations(shapes(q, window).jaxpr, "pallas_call"))
+    assert grids[None] == [(2, 16, 16)] * 3
+    assert grids[1024] == [(2, 16, 2)] * 3

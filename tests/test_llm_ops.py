@@ -661,3 +661,156 @@ def test_flash_attention_at_the_timed_blocks_and_head_sizes():
                         (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+# ------------------------- rotary scaling as data, a softmax router (PR 34)
+
+
+def _yarn_closed_form(dim, theta, factor, original, fast, slow):
+    """ISSUE 34's closed form, written again here."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extrap = theta ** (-2 * i / dim)
+
+    def c(r):
+        return dim * np.log(original / (2 * np.pi * r)) / (2 * np.log(theta))
+
+    low, high = max(np.floor(c(fast)), 0), min(np.ceil(c(slow)), dim - 1)
+    ramp = np.clip((i - low) / (high - low), 0, 1)
+    return extrap / factor * ramp + extrap * (1 - ramp), (low, high)
+
+
+def test_yarns_table_is_the_closed_form_past_the_original_length():
+    """The published full layers' numbers: the ramp runs over the pairs 18
+    .. 35 of 64, and at 8 positions past 8,192 ``cos`` and ``sin`` are the
+    closed form's times the amplitude, on both lanes of a pair."""
+    how = dict(rope_type="yarn", rope_theta=500000, factor=16,
+               original_max_position_embeddings=8192, beta_fast=32,
+               beta_slow=1, attention_factor=1.2772588722239782)
+    inv, amplitude = llm.rotary_frequencies(128, **how)
+    want, (low, high) = _yarn_closed_form(128, 5e5, 16, 8192, 32, 1)
+    assert (low, high) == (18, 35)
+    np.testing.assert_allclose(inv, want, rtol=1e-14)
+    assert inv[:19] == tuple(5e5 ** (-2 * np.arange(19) / 128))
+    np.testing.assert_allclose(inv[35:], 5e5 ** (-2 * np.arange(35, 64) / 128)
+                               / 16, rtol=1e-14)
+    assert amplitude == pytest.approx(0.1 * np.log(16) + 1, rel=1e-15)
+    assert llm.rotary_frequencies(128, **dict(how, attention_factor=None))[1] \
+        == pytest.approx(amplitude, rel=1e-15)
+    positions = 8192 + np.array([1, 2, 3, 5, 8, 13, 21, 34])
+    cos, sin = llm._rotary_tables(8192 + 35, 128, None, True, inv, amplitude)
+    angle = positions[:, None] * want[None, :]
+    for half in (slice(0, 64), slice(64, 128)):
+        np.testing.assert_allclose(np.asarray(cos)[positions, half],
+                                   amplitude * np.cos(angle), atol=2e-7)
+    np.testing.assert_allclose(np.asarray(sin)[positions, :64],
+                               -amplitude * np.sin(angle), atol=2e-7)
+    np.testing.assert_allclose(np.asarray(sin)[positions, 64:],
+                               amplitude * np.sin(angle), atol=2e-7)
+    # the default type is theta's frequencies, and theta alone builds them
+    plain, one = llm.rotary_frequencies(128, rope_type="default",
+                                        rope_theta=500000)
+    assert one == 1.0
+    for a, b in zip(llm._rotary_tables(64, 128, 500000, True),
+                    llm._rotary_tables(64, 128, None, True, plain, one)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="rope_type"):
+        llm.rotary_frequencies(128, rope_type="linear")
+    with pytest.raises(ValueError, match="frequencies"):
+        llm._rotary_tables(8, 128, None, True, inv[:5])
+
+
+def test_rope_and_gqa_qkv_take_the_layers_frequencies_and_amplitude():
+    """``rope(inv_freq=, amplitude=)`` is the rotation by those angles
+    times the amplitude, its gradient the rotation back times the same;
+    ``gqa_qkv`` hands them to the same table builder."""
+    rng = np.random.RandomState(3)
+    x = _f(rng.randn(2, 40, 16))
+    inv, amplitude = llm.rotary_frequencies(
+        16, rope_type="yarn", rope_theta=1e4, factor=4,
+        original_max_position_embeddings=32, beta_fast=2, beta_slow=0.25)
+    angle = np.arange(40)[:, None] * np.asarray(inv)[None, :]
+    a, b = np.asarray(x)[..., :8], np.asarray(x)[..., 8:]
+    want = amplitude * np.concatenate(
+        [a * np.cos(angle) - b * np.sin(angle),
+         b * np.cos(angle) + a * np.sin(angle)], axis=-1)
+    got = llm.rope(x, halves=True, inv_freq=inv, amplitude=amplitude)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert np.abs(np.asarray(llm.rope(x, theta=1e4, halves=True))
+                  - want).max() > 0.1
+    g = _f(rng.randn(2, 40, 16))
+    back = jax.vjp(lambda x: llm.rope(x, halves=True, inv_freq=inv,
+                                      amplitude=amplitude), x)[1](g)[0]
+    ga, gb = np.asarray(g)[..., :8], np.asarray(g)[..., 8:]
+    np.testing.assert_allclose(back, amplitude * np.concatenate(
+        [ga * np.cos(angle) + gb * np.sin(angle),
+         gb * np.cos(angle) - ga * np.sin(angle)], axis=-1),
+        rtol=2e-5, atol=2e-6)
+    w = [_f(0.3 * rng.randn(n, 16)) for n in (32, 16, 16)]
+    ones = jnp.ones(16)
+    q, k, _ = llm.gqa_qkv(x, *w, ones, ones, inv_freq=inv,
+                          amplitude=amplitude)
+    q0, k0, _ = llm.gqa_qkv(x, *w, ones, ones, theta=1e4)
+    np.testing.assert_allclose(q[:, :, 0], amplitude * q0[:, :, 0],
+                               rtol=1e-5, atol=1e-6)   # position 0: no angle
+    assert np.abs(np.asarray(k) - amplitude * np.asarray(k0)).max() > 0.05
+
+
+def _softmax_route(x, w, k):
+    """Plain: probabilities over all experts, the k largest, normalised;
+    the balancing term experts x sum_e f_e P_e."""
+    experts = w.shape[0]
+    p = jax.nn.softmax(x @ w.T, axis=-1)
+    picked, ids = jax.lax.top_k(p, k)
+    f = jnp.mean(jax.nn.one_hot(ids.reshape(-1), experts), axis=0)
+    term = experts * jnp.sum(jax.lax.stop_gradient(f) * jnp.mean(p, axis=0))
+    return ids, picked / jnp.sum(picked, axis=-1, keepdims=True), term
+
+
+def test_a_softmax_router_and_its_balancing_term():
+    """``moe_route(scoring="softmax")`` without a bias: ids, weights and,
+    with ``balance``, the term's value and its gradient (through ``P``
+    alone: ``f`` is a count) against the plain computation."""
+    rng = np.random.RandomState(11)
+    x, w = _f(rng.randn(48, 16)), _f(0.5 * rng.randn(12, 16))
+    ids, weights, term = llm.moe_route(x, w, k=3, eps=0.0, scoring="softmax",
+                                       balance=True)
+    want_ids, want_weights, want_term = _softmax_route(x, w, 3)
+    np.testing.assert_array_equal(ids, want_ids)
+    assert ids.dtype == jnp.int32 and term.shape == ()
+    np.testing.assert_allclose(weights, want_weights, rtol=1e-6)
+    np.testing.assert_allclose(np.sum(weights, axis=-1), 1.0, rtol=1e-6)
+    assert float(term) == pytest.approx(float(want_term), rel=1e-6)
+    assert 1.0 < float(term) < 2.0          # 1 when the experts are level
+    two = llm.moe_route(x, w, k=3, eps=0.0, scoring="softmax")
+    assert len(two) == 2
+    np.testing.assert_array_equal(two[0], ids)
+
+    def value(route):
+        def f(x, w):
+            _, weights, term = route(x, w)
+            return term + jnp.sum(weights[:, 0])
+        return jax.grad(f, argnums=(0, 1))(x, w)
+
+    got = value(lambda x, w: llm.moe_route(x, w, k=3, eps=0.0,
+                                           scoring="softmax", balance=True))
+    want = value(lambda x, w: _softmax_route(x, w, 3))
+    for a, b in zip(got, want):
+        assert np.abs(np.asarray(b)).max() > 1e-3
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+    # the term alone moves the router: popular experts' rows go down
+    alone = jax.grad(lambda w: llm.moe_route(
+        x, w, k=3, eps=0.0, scoring="softmax", balance=True)[2])(w)
+    assert np.abs(np.asarray(alone)).max() > 1e-4
+    # level experts: every expert chosen and scored alike gives 1
+    level = llm.moe_route(jnp.tile(jnp.eye(12), (4, 1)), 5 * jnp.eye(12),
+                          k=1, scoring="softmax", balance=True)[2]
+    assert float(level) == pytest.approx(1.0, rel=1e-6)
+    # a sigmoid router's term uses its scores; an unknown scoring raises
+    assert len(llm.moe_route(x, w, jnp.zeros(12), k=3, balance=True)) == 3
+    with pytest.raises(ValueError, match="scoring"):
+        llm.moe_route(x, w, k=3, scoring="tanh")
+    op = mx.nd.contrib.moe_route(mx.nd.array(np.asarray(x)),
+                                 mx.nd.array(np.asarray(w)), None, k=3,
+                                 eps=0.0, scoring="softmax", balance=True)
+    assert len(op) == 3
+    assert float(op[2].asnumpy()) == pytest.approx(float(want_term), rel=1e-6)

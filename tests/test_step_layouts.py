@@ -670,6 +670,137 @@ print(out.format.layout.major_to_minor)
 """
 
 
+def test_on_the_v5e_a_window_layers_kernels_run_the_band_only(v5e,
+                                                              monkeypatch):
+    """A small ``LayerTypesMoELM`` (a window layer and a full one, a softmax
+    router under its balancing loss) through ``GluonTrainStep`` with Adam in
+    bfloat16, one row of 4,096 tokens, compiled for the described chip:
+    Mosaic takes the three kernels with a window inside the step program,
+    and their grids hold the band only: at blocks 1,024 / 1,024 and a
+    window of 256 a query block runs 2 key blocks and a key block 2 query
+    blocks, where the full layer's grids count all 4."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.gluon.nn import LayerTypesMoELM, NextTokenLoss
+    from mxnet_tpu.ops import attention as att, llm
+
+    monkeypatch.setattr(att, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(llm, "pallas_interpret", lambda: False)
+    mx.random.seed(7)
+    net = LayerTypesMoELM(
+        vocab_size=512, hidden_size=256,
+        layer_types=["sliding_attention", "full_attention"],
+        moe_intermediate_size=128, router_outputs=8, held_experts=(0, 4),
+        num_experts_per_tok=2, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=128, sliding_window=256, scoring_func="softmax",
+        router_aux_loss_coef=0.001, route_epsilon=0.0, tie_embedding=False,
+        prefix="swa_")
+    net.initialize(ctx=mx.cpu())
+    step = GluonTrainStep(
+        net, NextTokenLoss(net.head),
+        mesh=create_mesh({"dp": 1}, devices=jax.devices()[:1]),
+        compute_dtype="bfloat16",
+        optimizer=opt_mod.create("adam", learning_rate=1e-5))
+    mesh = Mesh(np.array(v5e.devices[:1]), ("dp",))
+    repl, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    step._repl, step._rest_in = repl, (batch, batch, repl, repl)
+    step._form.shards = jax.tree.map(lambda _s: repl, step._form.shards)
+    step._orders = tuple(tuple(tuple(range(v.ndim)) for v in tree)
+                         for tree in step._held)
+    held = [tuple(jax.ShapeDtypeStruct(v.shape, v.dtype) for v in tree)
+            for tree in step._held]
+    x = jax.ShapeDtypeStruct((1, 4096), jnp.int32)
+    rest = [jax.ShapeDtypeStruct((2,), jnp.uint32),
+            tuple(jax.ShapeDtypeStruct((), jnp.float32)
+                  for _ in step._rule.slots)]
+
+    def grids(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else [value]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        grids(sub, found)
+        return found
+
+    found = set(grids(jax.make_jaxpr(step._step_py)(*held, x, x,
+                                                    *rest).jaxpr, []))
+    assert {(2, 4, 4), (1, 4, 8)} <= found          # the full layer's
+    assert {(2, 4, 2), (1, 4, 4)} <= found          # the window layer's
+    text = step._jit().lower(*held, x, x, *rest).compile().as_text()
+    from benchmark.harness import gqa_attention_cost
+
+    shapes = {"rows": 1, "seq": 4096, "heads": 2, "kv_heads": 1, "d": 128}
+    kinds = sorted(str(gqa_attention_cost.kernel_kind(k, shapes))
+                   for k in _kernel_instructions(text))
+    assert kinds.count("('forward', 2)") == kinds.count("('dq', 2)") \
+        == kinds.count("('dkv', 2)") == 2
+
+
+@pytest.mark.parametrize("part", ["prefix", "sliding_attention",
+                                  "full_attention"])
+def test_on_the_v5e_the_references_timed_rows_fit_beside_the_steps_state(
+        v5e, part):
+    """``mellum2_moe_train_seq16k``'s reference computes its rows of the
+    timed shape, 1 x 16,384 in float32 at highest precision, on the chip
+    after the window, where the step's state still lies (7.6 GB of 16):
+    each of its three programs compiles for the described chip at the real
+    widths and takes, arguments, results and temporaries together, under
+    6 GB (the prefix's loss over chunks 1.7, the window layer 3.0, the
+    full layer 4.8; whole logits alone would be 4.8 of temporaries)."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness import manifest
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2_12b_ep4.json")) as f:
+        config = json.load(f)
+    arch = config["architecture"]
+    reference = manifest.load_module(os.path.join(root, config["reference"]))
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def of(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    hid, vocab, d = arch["hidden_size"], arch["vocab_size"], arch["head_dim"]
+    row = of(1, config["input"]["shape"][0], dtype=jnp.int32)
+    prefix_row, attention_row = reference.timed_programs(arch)
+    with jax.default_matmul_precision("highest"):
+        if part == "prefix":
+            lowered = prefix_row.lower(
+                {"embed_weight": of(vocab, hid), "norm_weight": of(hid),
+                 "head_weight": of(vocab, hid)}, row)
+        else:
+            pre = "l%d_" % reference.timed_layer(arch, part)
+            wide = arch["num_attention_heads"] * d
+            narrow = arch["num_key_value_heads"] * d
+            layer = {pre + "attn_q_weight": of(wide, hid),
+                     pre + "attn_k_weight": of(narrow, hid),
+                     pre + "attn_v_weight": of(narrow, hid),
+                     pre + "attn_o_weight": of(hid, wide),
+                     pre + "attn_qnorm_weight": of(d),
+                     pre + "attn_knorm_weight": of(d)}
+            rest = {"embed_weight": of(vocab, hid),
+                    pre + "ln1_weight": of(hid)}
+            assert set(layer) | set(rest) <= \
+                reference.timed_parameters(arch)
+            lowered = attention_row[part].lower(layer, rest, row)
+        memory = lowered.compile().memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        + memory.output_size_in_bytes < 6e9
+
+
 def test_a_program_from_the_compile_cache_forgets_its_result_layout(
         tmp_path):
     """The toolchain's behaviour this design stands on (jax 0.9.0; the same

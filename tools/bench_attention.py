@@ -10,7 +10,8 @@ forward and forward + backward (it materialises the (seq, seq) scores).
 
 The block table of a language-model cell is one command (latent attention's
 heads; grouped-query heads of 64 with ``--heads 32 --kv-heads 8 --head-dim
-64 --seqs 8192``):
+64 --seqs 8192``; a sliding window with ``--window 1024``, the operations
+then the band's, ``S W - W (W - 1) / 2`` pairs a head):
 
     python tools/bench_attention.py --batch 2 --heads 32 --head-dim 192 \\
         --v-head-dim 128 --seqs 4096 --causal \\
@@ -63,9 +64,9 @@ def bench(fn, args, iters):
     return best / iters
 
 
-def kernel_times(q, k, v, g, causal, block_q, block_k, iters):
+def kernel_times(q, k, v, g, causal, block_q, block_k, iters, window=None):
     """{"fwd", "dq", "dkv"}: seconds a call of each kernel."""
-    how = (1.0 / np.sqrt(q.shape[-1]), causal, block_q, block_k,
+    how = (1.0 / np.sqrt(q.shape[-1]), causal, window, block_q, block_k,
            att.pallas_interpret())
     out, lse = att._fwd_pallas(q, k, v, *how)
     operands = att._bwd_operands(q, k, v, out, lse, g)
@@ -94,6 +95,9 @@ def main(argv=None):
                         "pair flash_attention takes)")
     p.add_argument("--iters", type=int, default=50)
     p.add_argument("--causal", action="store_true")
+    p.add_argument("--window", type=int, default=None,
+                   help="sliding window (with --causal): a query sees that "
+                        "many keys, itself the last")
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--xla", action="store_true",
                    help="time the unfused reference too")
@@ -116,10 +120,14 @@ def main(argv=None):
         g = jnp.asarray(rng.randn(args.batch, args.heads, seq, dv), dt)
         pairs = args.batch * args.heads * seq * seq / (2.0 if args.causal
                                                        else 1.0)
+        window = args.window if args.window and args.window < seq else None
+        if window:
+            pairs = args.batch * args.heads * (
+                seq * window - window * (window - 1) / 2.0)
         for block_q, block_k in blocks or att._block_choices(q, v)[:1]:
             block_q, block_k = min(block_q, seq), min(block_k, seq)
             t = kernel_times(q, k, v, g, args.causal, block_q, block_k,
-                             args.iters)
+                             args.iters, window)
             rows.append((seq, block_q, block_k, t))
             print("seq %5d blocks %4d/%-4d | " % (seq, block_q, block_k)
                   + "  ".join("%s %7.3f ms (%5.1f TFLOP/s)"
