@@ -18,8 +18,9 @@ and the layer computes the part of the routed sum that its own experts
 give (what expert parallelism asks of one chip; on one chip without the
 exchange).  No pair routed to a held expert is dropped at any imbalance,
 and the work follows the rows actually routed: the pairs are sorted by
-expert into row tiles, and a loop whose trip count is the number of tiles
-in use gathers a tile's rows, runs its expert and scatters the result.
+expert into row tiles, and loops whose trip counts follow the tiles in use
+gather a step's rows (one tile, or up to four of one expert in the
+backward pass), run their expert and scatter the result.
 """
 
 from __future__ import annotations
@@ -42,12 +43,27 @@ __all__ = ["rms_norm", "rope", "rotary_frequencies", "gated_silu", "mla_qkv", "m
            "gqa_out", "gated_short_conv", "moe_route", "moe_experts",
            "linear_cross_entropy", "expert_tiles"]
 
-# rows of one expert tile.  A tile costs its expert's three weights read
-# (twice in the backward pass) and their three float32 gradients read and
-# written, whatever its rows: at 256 rows that fixed part was two thirds of
-# a tile's time on a v5e, and the step time followed the routed pairs at
-# 0.72 us a pair (PERF.md, PR 28); 512 halves it.
+# rows of one expert tile: what an expert's rows are padded to.  A step of
+# the loops costs its expert's three weights read (twice in the backward
+# pass) and, in the backward pass, their three float32 gradients read, added
+# to and written, whatever its rows: at 256 rows that fixed part was two
+# thirds of a tile's time on a v5e, and the step time followed the routed
+# pairs at 0.72 us a pair (PERF.md, PR 28); 512 halves it.
 DEFAULT_EXPERT_TILE = 512
+# tiles of one expert that a step of the backward loops takes while the
+# expert has that many left (a big step); what is left of it goes one tile a
+# step, so an expert with fewer tiles costs what it did and padding stays a
+# tile's.  At 512 rows a weight gradient's write is bound by its slice's
+# bytes (0.107 ms for 0.057 ms of product at LFM2's widths), at 4 x 512 by
+# its product; 2 leaves the writes at twice the bytes, 8 needs experts of
+# 4,096 rows (PERF.md, PR 35).
+EXPERT_TILES_A_STEP = 4
+# tiles of a big step whose rows go through the expert together (a run):
+# what is computed row by row (the recomputed forward, dh, dx) keeps its
+# float32 intermediates in the v5e's fast memory at 2 x 512 rows of LFM2's
+# width and not at 4 x 512; the weight gradients contract all of the big
+# step's rows whatever the run (PERF.md, PR 35).
+EXPERT_TILES_A_RUN = 2
 DEFAULT_LOSS_CHUNK = 1024
 LANES = 128
 
@@ -516,14 +532,46 @@ def expert_tiles(ids, first, held, tile):
             (ends[-1] // tile).astype(jnp.int32), counts)
 
 
-def _tile_rows(t, row_pair, weights_flat, k, tile):
-    """Tile ``t``'s rows, the real ones first: how many are real, the token
+def _expert_steps(counts, tile, slots):
+    """The loops' steps from the pairs per held expert (``expert_tiles``'
+    ``counts``; ``slots``: its capacity in tiles): with ``most`` =
+    ``EXPERT_TILES_A_STEP`` an expert with ``n`` tiles gives ``n // most``
+    *big steps* of ``most`` consecutive tiles, all its own, and ``n %
+    most`` single tiles.  -> (``big`` int32: the
+    first tile of every big step; how many there are; ``single`` int32: the
+    tile of every single step; how many there are).  Every tile in use is
+    in one step; past their counts the lists hold no step."""
+    most = EXPERT_TILES_A_STEP
+    held = counts.shape[0]
+    tiles = (counts + tile - 1) // tile
+    first = jnp.cumsum(tiles) - tiles
+    big = tiles // most
+
+    def listed(per_expert, size):
+        """Step ``i`` of ``size``: whose it is, and which of its own."""
+        ends = jnp.cumsum(per_expert)
+        i = jnp.arange(size, dtype=jnp.int32)
+        e = jnp.minimum(jnp.searchsorted(ends, i, side="right"), held - 1)
+        return e, i - (ends - per_expert)[e], ends[-1].astype(jnp.int32)
+
+    e, j, n_big = listed(big, slots // most)
+    big_first = first[e] + most * j
+    e, j, n_single = listed(tiles - most * big,
+                            min(slots, held * (most - 1)))
+    single = first[e] + most * big[e] + j
+    return (big_first.astype(jnp.int32), n_big, single.astype(jnp.int32),
+            n_single)
+
+
+def _tile_rows(t, row_pair, weights_flat, k, tile, tiles=1):
+    """The rows of the ``tiles`` tiles from tile ``t`` on (one expert's),
+    the real ones first: how many are real, the token
     each reads (padding: token 0), where each adds into a ``(tokens, ...)``
     and into the flat ``(tokens * k,)`` sum (padding: past the end, so it
     adds nothing: :func:`_add_rows`), and the routing weights (padding:
     0)."""
     with _xray.scope("moe.dispatch"):
-        pairs = lax.dynamic_slice(row_pair, (t * tile,), (tile,))
+        pairs = lax.dynamic_slice(row_pair, (t * tile,), (tiles * tile,))
         n = weights_flat.shape[0]
         real = pairs < n
         safe = jnp.where(real, pairs, 0)
@@ -591,18 +639,25 @@ def _add_rows_kernel(at_ref, n_ref, _, rows_hbm, total_hbm, held, rows,
     every_real_row(lambda i: copy_out(i).wait())
 
 
-def _add_rows(total, n, at, rows):
-    """``total[at[i]] += rows[i]`` for one tile's rows, in place: ``total``
+def _add_rows(total, n, at, rows, tile=None):
+    """``total[at[i]] += rows[i]`` for one step's rows, in place: ``total``
     is a loop's carry.  The first ``n`` rows are real and no two of them
     name the same row of ``total``; the others name a row past its end and
     add nothing.  A 3-D ``total`` (``_zeros_to_add_rows_into``) takes the
     kernel, which keeps all the rows' copies in flight; XLA's scatter-add,
     which may assume nothing about the rows, finishes one row's read, add
-    and write before it starts the next (PERF.md, PR 33)."""
+    and write before it starts the next (PERF.md, PR 33).  A big step's
+    rows are added ``tile`` at a time, on every platform."""
+    tile = tile or rows.shape[0]
+    if rows.shape[0] > tile:
+        for j in range(rows.shape[0] // tile):
+            rows_j = slice(j * tile, (j + 1) * tile)
+            total = _add_rows(total, jnp.clip(n - j * tile, 0, tile),
+                              at[rows_j], rows[rows_j])
+        return total
     with _xray.scope("moe.combine"):
         if total.ndim < 3:
             return total.at[at].add(rows, mode="drop")
-        tile = rows.shape[0]
         in_hbm = pl.BlockSpec(memory_space=pl.ANY)
         return pl.pallas_call(
             _add_rows_kernel,
@@ -651,15 +706,18 @@ def _expert_forward(xt, wg, wu, wd):
     return g, u, h, _dot(h, wd, ((1,), (0,)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _routed_sum(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles, k,
-                tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _routed_sum(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles,
+                counts, k, tile):
     return _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert,
-                           n_tiles, k, tile)[0]
+                           n_tiles, counts, k, tile)[0]
 
 
 def _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles,
-                    k, tile):
+                    counts, k, tile):
+    """The forward pass tile by tile.  It takes no big steps: a tile's
+    three products already run near the MXU's rate, and with them the
+    step program's temporaries packed 130 MB worse (PERF.md, PR 35)."""
     flat = weights.reshape(-1)
 
     def body(carry):
@@ -678,20 +736,36 @@ def _routed_sum_fwd(x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles,
         lambda c: c[0] < n_tiles, body,
         (jnp.int32(0), _zeros_to_add_rows_into(*x.shape, tile)))
     return _summed_rows(out, x), (x, weights, wg, wu, wd, row_pair,
-                                  tile_expert, n_tiles)
+                                  tile_expert, counts)
 
 
 def _routed_sum_bwd(k, tile, res, dy):
-    """The backward pass tile by tile, the tile's forward recomputed:
-    nothing but the layer's input is kept between the passes."""
-    x, weights, wg, wu, wd, row_pair, tile_expert, n_tiles = res
+    """The backward pass step by step (``_expert_steps``), the rows'
+    forward recomputed: nothing but the layer's input is kept between the
+    passes.  Two loops carry the same sums: over the big steps, then over
+    the single tiles.  A big step sends its rows through the expert in
+    runs of ``EXPERT_TILES_A_RUN`` tiles (what a single step does to one:
+    forward again, ``dh``, the rows' share of ``dx`` and of the routing
+    weights' gradient added ``tile`` rows at a time) and keeps, for all of
+    its ``EXPERT_TILES_A_STEP`` tiles, only the five operands of the three
+    weight gradients in the data's type (``xt``, ``dg``, ``du``, ``h``,
+    ``dyt``: 39 MB at LFM2's widths); each weight gradient is then one
+    product that contracts all the rows and one read, add and write of the
+    expert's float32 ``(in, width)`` slice of the carried sum.  Nothing is
+    kept from one step to the next but the carried sums."""
+    x, weights, wg, wu, wd, row_pair, tile_expert, counts = res
     flat = weights.reshape(-1)
     f32 = jnp.float32
+    with _xray.scope("moe.dispatch"):
+        big, n_big, single, n_single = _expert_steps(
+            counts, tile, tile_expert.shape[0])
 
-    def body(carry):
-        t, dx, dflat, dwg, dwu, dwd = carry
-        n, tok, at, pairs, gate = _tile_rows(t, row_pair, flat, k, tile)
-        e = tile_expert[t]
+    def rows_backward(t, e, tiles, dx, dflat):
+        """What goes row by row for ``tiles`` tiles from ``t`` on: their
+        share of ``dx`` and ``dflat`` added, and the operands of the three
+        weight gradients."""
+        n, tok, at, pairs, gate = _tile_rows(t, row_pair, flat, k, tile,
+                                             tiles)
         with _xray.scope("moe.dispatch"):
             xt = jnp.take(x, tok, axis=0)
             dyt = jnp.take(dy, tok, axis=0)
@@ -705,17 +779,41 @@ def _routed_sum_bwd(k, tile, res, dy):
             du = (dh * g * sig).astype(x.dtype)
             dxt = _dot(dg, wg[e], ((1,), (1,))) + _dot(du, wu[e],
                                                        ((1,), (1,)))
-            dwg = dwg.at[e].add(_dot(xt, dg, ((0,), (0,))))
-            dwu = dwu.at[e].add(_dot(xt, du, ((0,), (0,))))
-            dwd = dwd.at[e].add(_dot(h, dyt, ((0,), (0,))))
-        return (t + 1, _add_rows(dx, n, at, dxt),
-                _add_rows(dflat, n, pairs, dgate), dwg, dwu, dwd)
+        return (_add_rows(dx, n, at, dxt, tile),
+                _add_rows(dflat, n, pairs, dgate, tile), (xt, dg, du, h, dyt))
 
-    init = (jnp.int32(0), _zeros_to_add_rows_into(*x.shape, tile),
+    def looped(sums, firsts, n, tiles):
+        """``sums`` after the ``n`` steps of ``tiles`` tiles that start at
+        the tiles ``firsts``."""
+        run = min(tiles, EXPERT_TILES_A_RUN)
+        assert tiles % run == 0, (tiles, run)
+
+        def step(carry):
+            i, (dx, dflat, dwg, dwu, dwd) = carry
+            t = firsts[i]
+            e = tile_expert[t]
+            kept = []
+            for first in range(0, tiles, run):
+                dx, dflat, operands = rows_backward(t + first, e, run, dx,
+                                                    dflat)
+                kept.append(operands)
+            xt, dg, du, h, dyt = (jnp.concatenate(a) for a in zip(*kept))
+            with _xray.scope("moe.experts"), _xray.scope("moe.wgrad"):
+                dwg = dwg.at[e].add(_dot(xt, dg, ((0,), (0,))))
+                dwu = dwu.at[e].add(_dot(xt, du, ((0,), (0,))))
+                dwd = dwd.at[e].add(_dot(h, dyt, ((0,), (0,))))
+            return i + 1, (dx, dflat, dwg, dwu, dwd)
+
+        if not firsts.size:     # fewer rows than such a step takes
+            return sums
+        return lax.while_loop(lambda c: c[0] < n, step,
+                              (jnp.int32(0), sums))[1]
+
+    sums = (_zeros_to_add_rows_into(*x.shape, tile),
             jnp.zeros(flat.shape, f32), jnp.zeros(wg.shape, f32),
             jnp.zeros(wu.shape, f32), jnp.zeros(wd.shape, f32))
-    _, dx, dflat, dwg, dwu, dwd = lax.while_loop(
-        lambda c: c[0] < n_tiles, body, init)
+    sums = looped(sums, big, n_big, EXPERT_TILES_A_STEP)
+    dx, dflat, dwg, dwu, dwd = looped(sums, single, n_single, 1)
 
     def no_gradient(a):
         return _np.zeros(a.shape, dtype=jax.dtypes.float0)
@@ -723,7 +821,8 @@ def _routed_sum_bwd(k, tile, res, dy):
     return (_summed_rows(dx, x), dflat.reshape(weights.shape).astype(
         weights.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
         dwd.astype(wd.dtype), no_gradient(row_pair),
-        no_gradient(tile_expert), no_gradient(n_tiles))
+        no_gradient(tile_expert), no_gradient(counts[0]),   # n_tiles
+        no_gradient(counts))
 
 
 _routed_sum.defvjp(_routed_sum_fwd, _routed_sum_bwd)
@@ -767,8 +866,8 @@ def moe_experts(data, expert_ids, expert_weights, gate_weight, up_weight,
         load = jnp.max(counts).astype(jnp.float32) * held \
             / jnp.maximum(routed, 1.0)
     y = _routed_sum(data, expert_weights, gate_weight, up_weight,
-                    down_weight, row_pair, tile_expert, n_tiles, int(k),
-                    int(tile))
+                    down_weight, row_pair, tile_expert, n_tiles, counts,
+                    int(k), int(tile))
     return y, routed, load
 
 

@@ -2,6 +2,8 @@
 flash-attention kernels with a value head size that differs from the
 scores' (interpret mode).  Docs: docs/LLM_OPS.md."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -287,6 +289,55 @@ def moe_experts_with_loads_that_cross_a_tile():
     return _moe_experts_case(tokens=37, tile=8)
 
 
+LOADS = {    # tiles on the three held experts, rows beyond them
+    "no_rows": ((0, 0, 0), (0, 0, 0)),
+    "one_row": ((0, 0, 0), (1, 0, 0)),
+    "exactly_four_tiles": ((0, 4, 0), (0, 0, 0)),
+    "four_tiles_and_a_row": ((0, 4, 0), (0, 1, 0)),
+    "nine_tiles": ((1, 0, 8), (2, 0, 3)),
+    "one_held_expert": ((0, 0, 6), (0, 0, 3)),
+    "an_expert_absent": ((5, 0, 2), (0, 0, 1)),
+    "four_and_five_tiles": ((4, 3, 4), (0, 3, 1)),
+}
+
+
+def _loaded_ids(load, tile, first=4, experts=8):
+    """(tokens, 2) ids: a token's first choice is a held expert (``first``
+    on) as ``LOADS[load]`` counts them, or expert 0, which is absent, for
+    a few more; its second choice is absent."""
+    tiles, more = LOADS[load]
+    counts = [t * tile + m for t, m in zip(tiles, more)]
+    chosen = np.concatenate([np.full(c, first + j) for j, c in
+                             enumerate(counts)] + [np.zeros(3, np.int64)])
+    chosen = _rs(11).permutation(chosen)
+    return np.stack([chosen, np.full(len(chosen), experts - 1)], -1), counts
+
+
+def _loaded_args(ids):
+    """x, routing weights and three held experts' weights for those ids."""
+    rs = _rs(7)
+    return (rs.randn(len(ids), 8), rs.rand(len(ids), 2), rs.randn(3, 8, 6),
+            rs.randn(3, 8, 6), rs.randn(3, 6, 8))
+
+
+def _loaded_case(load, tile):
+    ids, _ = _loaded_ids(load, tile)
+    x, weights, wg, wu, wd = _loaded_args(ids)
+
+    def op(x, weights, wg, wu, wd):
+        return llm.moe_experts(x, jnp.asarray(ids, jnp.int32), weights, wg,
+                               wu, wd, first_expert=4, tile=tile)[0]
+
+    return (op, lambda x, w, wg, wu, wd: np_experts(x, ids, w, wg, wu, wd, 4),
+            (x, weights, wg, wu, wd), (0, 1, 2, 3, 4))
+
+
+for _load in LOADS:
+    for _tile in (4, 8):
+        CASES["moe_experts_with_%s_of_%d_rows" % (_load, _tile)] = \
+            functools.partial(_loaded_case, _load, _tile)
+
+
 @case
 def linear_cross_entropy():
     rs = _rs()
@@ -474,6 +525,129 @@ def test_the_loop_runs_over_the_tiles_in_use_not_over_the_capacity():
         assert (ids.reshape(-1)[pairs] == 4 + int(tile_expert[t])).all()
 
 
+@pytest.mark.parametrize("tile", [4, 8])
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_the_steps_take_every_tile_in_use_once(load, tile):
+    """``_expert_steps``: an expert with ``n`` tiles gives ``n // 4`` big
+    steps, each four consecutive tiles of its own, and ``n % 4`` single
+    tiles; together they are the tiles in use, each once."""
+    ids, counts = _loaded_ids(load, tile)
+    row_pair, tile_expert, n_tiles, got_counts = llm.expert_tiles(
+        jnp.asarray(ids, jnp.int32), 4, 3, tile)
+    assert list(np.asarray(got_counts)) == counts
+    slots = len(tile_expert)
+    big, n_big, single, n_single = (np.asarray(a) for a in llm._expert_steps(
+        got_counts, tile, slots))
+    most = llm.EXPERT_TILES_A_STEP
+    tiles = [-(-c // tile) for c in counts]
+    assert int(n_big) == sum(n // most for n in tiles)
+    assert int(n_single) == sum(n % most for n in tiles)
+    assert most * int(n_big) + int(n_single) == int(n_tiles)
+    taken = []
+    for t in big[:int(n_big)]:
+        step = list(range(t, t + most))
+        assert len(set(np.asarray(tile_expert)[step])) == 1     # one expert's
+        taken += step
+    taken += list(single[:int(n_single)])
+    assert sorted(taken) == list(range(int(n_tiles)))           # each once
+    # a big step's rows: real ones first, one expert's, only its last tile
+    # may end in padding
+    rows = np.asarray(row_pair)
+    for t in big[:int(n_big)]:
+        pairs = rows[t * tile:(t + most) * tile]
+        real = pairs < ids.size
+        assert real[:(most - 1) * tile].all()
+        assert not real[np.argmin(real):].any() or real.all()
+        assert (ids.reshape(-1)[pairs[real]]
+                == 4 + int(tile_expert[t])).all()
+
+
+def test_both_loops_run_over_the_steps_in_use_not_over_the_capacity():
+    """The lists have the capacity's size (every pair on one expert), the
+    trip counts what the loads give: 200 tokens x 3 choices over 12
+    experts leave ~50 pairs on each of the four held: 6-7 tiles of 8, one
+    big step and two or three single tiles an expert."""
+    m = _moe_inputs(tokens=200)
+    ids, _ = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
+    _, tile_expert, n_tiles, counts = llm.expert_tiles(
+        jnp.asarray(ids, jnp.int32), 4, 4, 8)
+    slots = len(tile_expert)
+    big, n_big, single, n_single = llm._expert_steps(counts, 8, slots)
+    tiles = [-(-c // 8) for c in np.asarray(counts)]
+    assert (int(n_big), int(n_single)) == (sum(n // 4 for n in tiles),
+                                           sum(n % 4 for n in tiles))
+    assert 4 * int(n_big) + int(n_single) == int(n_tiles) < slots / 2
+    assert len(big) == slots // 4 and len(single) == min(slots, 4 * 3)
+    assert int(n_big) < len(big) / 2 and 0 < int(n_single) < len(single)
+    # fewer slots than a big step takes: no big loop at all
+    big, n_big, single, n_single = llm._expert_steps(
+        jnp.asarray([2, 0, 1, 0], jnp.int32), 8, 3)
+    assert len(big) == 0 and int(n_single) == 2 and list(
+        np.asarray(single)[:2]) == [0, 1]
+
+
+def _value_and_gradients(ids, args, tile):
+    proj = _f(_rs(9).randn(*args[0].shape))
+
+    def op(*a):
+        return llm.moe_experts(a[0], jnp.asarray(ids, jnp.int32), *a[1:],
+                               first_expert=4, tile=tile)[0]
+
+    return (op(*args),) + jax.grad(
+        lambda *a: jnp.sum(op(*a) * proj), argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("tile", [4, 8])
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_big_steps_give_what_single_tiles_give(monkeypatch, load, tile):
+    """The op's value and its five gradients with big steps against the
+    same loads taken one tile a step: a weight gradient's rows are summed
+    inside one float32 product in place of four partial sums, so to
+    rounding."""
+    ids, _ = _loaded_ids(load, tile)
+    args = [_f(a) for a in _loaded_args(ids)]
+    got = _value_and_gradients(ids, args, tile)
+    monkeypatch.setattr(llm, "EXPERT_TILES_A_STEP", 1)
+    for g, w in zip(got, _value_and_gradients(ids, args, tile)):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
+
+
+def _equations(jaxpr, name):
+    """Every equation of that primitive, the loops' bodies included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, name)
+
+
+def test_a_big_step_adds_its_rows_a_tile_at_a_time():
+    """On the CPU platform the loops add through XLA's scatter-add; a big
+    step's rows go ``tile`` at a time, as on a chip, where
+    ``_kernel_adds_rows`` goes by the tile: no scatter-add of the backward
+    pass takes more rows, and a big step's four are there for ``dx`` and
+    for the routing weights' gradient."""
+    tile = 8
+    ids, _ = _loaded_ids("four_and_five_tiles", tile)
+    args = [_f(a) for a in _loaded_args(ids)]
+    jaxpr = jax.make_jaxpr(
+        lambda *a: _value_and_gradients(ids, a, tile))(*args).jaxpr
+    added = [eqn.invars[1].aval.shape for eqn in
+             _equations(jaxpr, "scatter-add")]
+    rows = [shape[0] for shape in added if len(shape) == 2]
+    # forward (once in the value, once under the gradient): one a tile;
+    # backward: a single tile's two and a big step's four times two
+    assert rows == [tile] * len(rows)
+    assert len(rows) == 2 * 1 + 2 + 2 * llm.EXPERT_TILES_A_STEP
+    # and a step of either kind writes each weight gradient once
+    assert len([shape for shape in added if shape == (1,)]) == 3 + 3
+    assert len(list(_equations(jaxpr, "while"))) == 2 + 2
+
+
 def _routing(name, tokens=50):
     """(ids, weights) over 12 experts, 3 a token; experts 4..7 are held."""
     m = _moe_inputs(tokens=tokens, held=12)
@@ -530,37 +704,31 @@ def test_what_the_combine_relies_on_is_true(routing, tile):
     assert sorted(seen) == list(np.flatnonzero(held))   # every pair, once
 
 
-@pytest.mark.parametrize("tokens,tile", [(40, 8), (8, 64), (37, 16)],
+@pytest.mark.parametrize("tokens,tile", [(40, 8), (8, 64), (37, 16),
+                                         (200, 8)],
                          ids=["loads_cross_a_tile", "mostly_padding",
-                              "tokens_no_block_divides"])
+                              "tokens_no_block_divides",
+                              "big_steps_and_single_tiles"])
 def test_the_kernel_adds_what_the_scatter_adds(monkeypatch, tokens, tile):
     """The accelerator's path through the interpreter (``_add_rows_kernel``
     on a ``(tokens, units / 128, 128)`` sum; the CPU platform otherwise
     takes XLA's scatter-add): the op's value and its five gradients are
     the scatter path's to rounding, with tiles that fill, tiles the padding
-    cuts short, and tiles that are nearly all padding;
+    cuts short, tiles that are nearly all padding, and big steps, which
+    call the kernel once a tile of theirs (200 tokens: 6-7 tiles of 8 an
+    expert, one big step and two or three single tiles);
     the finished sum comes back through ``_summed_rows``' kernel, or,
     where no block of 8 tokens divides it, through XLA's reshape."""
     m = _moe_inputs(tokens=tokens, hidden=256, width=8)
     ids, weights = np_route(m["x"], m["router"], m["bias"], 3, 2.5)
     args = [_f(a) for a in (m["x"], weights, m["wg"], m["wu"], m["wd"])]
-    proj = _f(_rs(9).randn(tokens, 256))
-
-    def value_and_gradients():
-        def op(*a):
-            return llm.moe_experts(a[0], jnp.asarray(ids, jnp.int32), *a[1:],
-                                   first_expert=4, tile=tile)[0]
-
-        return (op(*args),) + jax.grad(
-            lambda *a: jnp.sum(op(*a) * proj), argnums=(0, 1, 2, 3, 4))(*args)
-
-    want = value_and_gradients()
+    want = _value_and_gradients(ids, args, tile)
     monkeypatch.setattr(llm, "_kernel_adds_rows", lambda units, tile: True)
     lowered = jax.jit(lambda x: llm.moe_experts(
         x, jnp.asarray(ids, jnp.int32), *args[1:], first_expert=4,
         tile=tile)[0]).lower(args[0]).as_text()
     assert "x2x128xf32" in lowered              # the sum, three axes
-    for got, w in zip(value_and_gradients(), want):
+    for got, w in zip(_value_and_gradients(ids, args, tile), want):
         np.testing.assert_allclose(got, w, rtol=1e-4, atol=1e-4)
 
 

@@ -593,12 +593,17 @@ def test_on_the_v5e_the_routed_sum_adds_a_tiles_rows_in_place(
     them (a quarter of the cells' tokens; the cells' row width, tile and
     float32 sums): Mosaic takes ``_add_rows_kernel`` (a token's 8 KB copied
     row by row; a row of a 2-D sum it refuses) and ``_summed_rows``'; the
-    two loops add into ``(tokens, 16, 128)`` sums through it, in place:
+    loops add into ``(tokens, 16, 128)`` sums through it, in place:
     each call's result aliases its operand and no instruction copies a
-    sum; the one scatter-add left is the flat gradient of the routing
-    weights; and every one of these instructions lies under the
+    sum; a big step of the backward pass calls it once a tile of its
+    four; the one kind of scatter-add left is the flat gradient of the
+    routing weights; and every one of these instructions lies under the
     ``moe.combine`` scope that ``benchmark/harness/scope_time.py`` reads.
-    The guard a CPU run cannot give: there XLA's scatter-add is the path."""
+    A step of either backward loop writes each weight gradient once: an
+    output fusion under ``moe.wgrad`` that ends in the update of the
+    expert's slice of the float32 carry, and no instruction copies a
+    carry.  The guard a CPU run cannot give: there XLA's scatter-add is
+    the path."""
     import re
 
     import jax
@@ -638,8 +643,10 @@ def test_on_the_v5e_the_routed_sum_adds_a_tiles_rows_in_place(
                 if "tpu_custom_call" in line
                 and line.lstrip().startswith("%" + name)]
 
+    big = llm.EXPERT_TILES_A_STEP
     adds = kernels("moe_add_rows", the_sum)
-    assert len(adds) == 2                       # forward's, backward's
+    # the forward loop's, a single tile's and a big step's of the backward
+    assert len(adds) == 1 + 1 + big
     for line in adds:
         assert "output_to_operand_aliasing={{}: (2, {})}" in line, line
     sums = kernels("moe_summed_rows", r"bf16\[%d,%d\]" % (tokens, units))
@@ -647,12 +654,25 @@ def test_on_the_v5e_the_routed_sum_adds_a_tiles_rows_in_place(
     scatters = [line for line in lines if re.search(r" = f32\S* scatter\(",
                                                     line)]
     assert [line.split(" = ")[1].split("{")[0] for line in scatters] \
-        == ["f32[%d]" % (tokens * k)]
+        == ["f32[%d]" % (tokens * k)] * (1 + big)
     for line in adds + sums + scatters:    # as ``scope_time._under`` reads
         assert re.search(r'op_name="[^"]*[/(]moe\.combine[/)]', line), \
             line[:300]
     assert not instructions("copy", the_sum)
     assert not re.search(r" = f32\[%d,%d\]" % (tokens, units), text)
+    carries = r"f32\[%d,(%d,%d|%d,%d)\]" % (held, units, width, width, units)
+    writes = [line for line in instructions("fusion", carries)
+              if re.search(r'op_name="[^"]*[/(]moe\.wgrad[/)]', line)]
+    assert len(writes) == 3 + 3                 # a big step's, a tile's
+    for line in writes:
+        assert re.search(r'[/(]moe\.experts/moe\.wgrad[/)]', line), line[:300]
+        assert "kind=kOutput" in line, line[:300]
+        body = text.split(re.search(r"calls=(%[\w.\-]+)", line).group(1)
+                          + " (", 1)[1].split("\n}", 1)[0]
+        assert re.search(r"ROOT \S+ = %s\S* dynamic-update-slice\(" % carries,
+                         body), line[:300]
+        assert " convolution(" in body          # the product, in the fusion
+    assert not instructions("copy", carries)
 
 
 # ------------------------------------ why orders, and not layouts, are held
