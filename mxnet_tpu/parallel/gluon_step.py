@@ -758,7 +758,13 @@ class GluonTrainStep:
         self._repl = repl
         self._rest_in = (x_shard, y_shard, repl, repl)  # ..., key, scalars
 
-        self._held = self._form.place(self.trainable, self.aux, self._rule)
+        with _profiler.boundary_span("mxtpu.setup.place",
+                                     keep=True) as placed:
+            self._held = self._form.place(self.trainable, self.aux,
+                                          self._rule)
+            placed.stats.update(
+                leaves=sum(len(tree) for tree in self._held),
+                bytes=sum(v.nbytes for tree in self._held for v in tree))
         # un-jitted.  self._step is built by _adopt_orders() at the first
         # call: the order the state is held in (class docstring) needs
         # the batch's shape to be learned
@@ -858,12 +864,15 @@ class GluonTrainStep:
     def _learned_orders(self, x, y, rest):
         """The compiler's order of every state leaf: what an earlier
         process learned, else learned now and kept."""
-        path = self._orders_path(x, y)
-        orders = path and _read_orders(path, self._held)
-        if not orders:
-            orders = self._compilers_orders(x, y, rest)
-            if path:
-                _write_orders(path, orders)
+        with _profiler.boundary_span("mxtpu.setup.orders.learn",
+                                     keep=True) as learn:
+            path = self._orders_path(x, y)
+            orders = path and _read_orders(path, self._held)
+            learn.stats["source"] = "file" if orders else "compiled"
+            if not orders:
+                orders = self._compilers_orders(x, y, rest)
+                if path:
+                    _write_orders(path, orders)
         return orders
 
     def _adopt_orders(self, x, y, rest):
@@ -872,26 +881,33 @@ class GluonTrainStep:
         that takes and returns it so."""
         import jax
 
-        shards = self._form.shards
-        self._orders = orders = self._form.orders(
-            self._held, functools.partial(self._learned_orders, x, y, rest))
-        # one program moves the leaves whose order is not the model's
-        moving = [(i, j) for i, tree in enumerate(orders)
-                  for j, order in enumerate(tree) if not _is_models(order)]
-        if moving:
-            moved = dict(zip(moving, jax.jit(
-                lambda *leaves: tuple(
-                    _in_order(v, orders[i][j])
-                    for v, (i, j) in zip(leaves, moving)),
-                out_shardings=tuple(
-                    _shard_in_order(shards[i][j], orders[i][j])
-                    for i, j in moving))(
-                *(self._held[i][j] for i, j in moving))))
-            self._held = [
-                tuple(moved.get((i, j), v) for j, v in enumerate(tree))
-                for i, tree in enumerate(self._held)]
-        self._relaid = len(moving)
-        self._step = self._jit()
+        span = _profiler.boundary_span
+        with span("mxtpu.setup.orders", keep=True):
+            shards = self._form.shards
+            self._orders = orders = self._form.orders(
+                self._held,
+                functools.partial(self._learned_orders, x, y, rest))
+            moving = [(i, j) for i, tree in enumerate(orders)
+                      for j, order in enumerate(tree)
+                      if not _is_models(order)]
+            if moving:
+                # one program moves the leaves whose order is not the
+                # model's
+                with span("mxtpu.setup.orders.relay", keep=True,
+                          relaid_leaves=len(moving)):
+                    moved = dict(zip(moving, jax.jit(
+                        lambda *leaves: tuple(
+                            _in_order(v, orders[i][j])
+                            for v, (i, j) in zip(leaves, moving)),
+                        out_shardings=tuple(
+                            _shard_in_order(shards[i][j], orders[i][j])
+                            for i, j in moving))(
+                        *(self._held[i][j] for i, j in moving))))
+                self._held = [
+                    tuple(moved.get((i, j), v) for j, v in enumerate(tree))
+                    for i, tree in enumerate(self._held)]
+            self._relaid = len(moving)
+            self._step = self._jit()
 
     def _jit(self):
         """The step jitted on the state as it is held: donated, each leaf
@@ -940,7 +956,9 @@ class GluonTrainStep:
         import jax
 
         span = _profiler.boundary_span
-        with span("mxtpu.step", step_num=self._calls):
+        # call 0 is kept with what opens inside it: there the step program
+        # is traced, lowered and compiled or loaded (profiler.kept_spans)
+        with span("mxtpu.step", step_num=self._calls, keep=not self._calls):
             self._calls += 1
             if not isinstance(x, jax.Array):
                 with span("mxtpu.step.put_batch"):

@@ -17,7 +17,13 @@ is a ``jax.profiler.TraceAnnotation``, so it lies on the device
 trace's own clock beside the XLA ops of whatever jax profiler session
 is on, and while recorder (1) runs it is also an "X" event under the
 same name.  :func:`span` stays the guard-first tool of per-op loops and
-reaches recorder (1) only.
+reaches recorder (1) only.  A boundary span that happens once (a
+``GluonTrainStep``'s set-up, its first call) is *kept* besides: a record
+in a bounded in-process list on that same clock (:func:`kept_spans`),
+next to the compile log (:func:`compile_log`), jax's own report of every
+program this process traced, lowered, compiled or loaded from the
+persistent cache.  Nothing has to be on for either, so what happened
+before the first step can be read after it.
 
 Distributed telemetry (PR 7): under a ``tools/launch.py`` job every
 event carries a rank-tagged pid (worker rank, or 10000 + shard id for
@@ -32,6 +38,7 @@ renders in a single viewer.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -216,7 +223,87 @@ class _BothClocks:
         return self.recorded.__exit__(*exc)
 
 
-def boundary_span(name, step_num=None, **stats):
+class _Kept:
+    """A bounded list of records: past ``bound`` the oldest goes, and is
+    counted in ``dropped``."""
+
+    def __init__(self, bound):
+        self._records = collections.deque(maxlen=bound)
+        self._lock = threading.Lock()
+        self.dropped = 0
+
+    def add(self, record):
+        with self._lock:
+            if len(self._records) == self._records.maxlen:
+                self.dropped += 1
+            self._records.append(record)
+
+    def records(self):
+        with self._lock:
+            return list(self._records)
+
+    def clear(self):
+        with self._lock:
+            self._records.clear()
+            self.dropped = 0
+
+
+# what a process builds before its first step, with room: a language cell
+# of the benchmark leaves some five hundred records of a few hundred bytes
+KEPT_BOUND = 4096
+
+KeptSpan = collections.namedtuple(
+    "KeptSpan", "name start_ns end_ns parent thread stats")
+CompileRecord = collections.namedtuple(
+    "CompileRecord", "kind fun_name start_ns end_ns thread span retrieval_s")
+
+_kept_spans = _Kept(KEPT_BOUND)
+_compile_log = _Kept(KEPT_BOUND)
+
+
+class _PerThread(threading.local):
+    """What is open on the calling thread."""
+
+    def __init__(self):
+        self.spans = []     # names of the kept spans, innermost last
+        self.building = []  # starts of jax's traces, lowerings, compiles
+        # seconds a load from the persistent cache took whose backend
+        # record is still to come, else None
+        self.hit = None
+
+
+_thread = _PerThread()
+
+
+class _KeptSpan:
+    """A boundary span that also leaves a :class:`KeptSpan` record.
+    ``stats`` may be added to until the span closes; what is added inside
+    the span reaches the record and not the annotation."""
+
+    __slots__ = ("name", "stats", "inner", "start_ns")
+
+    def __init__(self, name, stats, inner):
+        self.name, self.stats, self.inner = name, stats, inner
+
+    def __enter__(self):
+        # stamped outside the annotation: the record encloses it
+        self.start_ns = time.time_ns()
+        _thread.spans.append(self.name)
+        self.inner.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.inner.__exit__(*exc)
+        open_here = _thread.spans
+        open_here.pop()
+        _kept_spans.add(KeptSpan(
+            self.name, self.start_ns, time.time_ns(),
+            open_here[-1] if open_here else None, threading.get_ident(),
+            dict(self.stats)))
+        return False
+
+
+def boundary_span(name, step_num=None, keep=False, **stats):
     """Span at a layer boundary (once per step or rarer), on the jax
     profiler's clock.
 
@@ -232,6 +319,14 @@ def boundary_span(name, step_num=None, **stats):
     running the span is also recorded there as the usual "X" event
     under the same name (category ``boundary``).
 
+    ``keep=True``, for a span that happens once (never one of every
+    step): it also leaves a :class:`KeptSpan` in :func:`kept_spans`,
+    whatever is or is not on, and so does every boundary span opened on
+    its thread while it is open.  The record's times are
+    ``time.time_ns()``, the clock the profiler stamps the annotation
+    with (an xplane counts from its session's ``profile_start_time`` on
+    that clock) and the clock of :func:`compile_log`.
+
     Not for per-op loops: those keep the guard-first :func:`span`."""
     import jax
 
@@ -240,9 +335,107 @@ def boundary_span(name, step_num=None, **stats):
     else:
         stats["step_num"] = step_num
         annotation = jax.profiler.StepTraceAnnotation(name, **stats)
-    if not _state["running"]:
-        return annotation
-    return _BothClocks(annotation, scope(name, "boundary", stats or None))
+    if _state["running"]:
+        annotation = _BothClocks(annotation,
+                                 scope(name, "boundary", stats or None))
+    if keep or _thread.spans:
+        return _KeptSpan(name, stats, annotation)
+    return annotation
+
+
+def kept_spans():
+    """The kept boundary spans, oldest first: ``KeptSpan(name, start_ns,
+    end_ns, parent, thread, stats)``, the times ``time.time_ns()``,
+    ``parent`` the name of the innermost kept span open on the thread
+    when this one opened.  A span is recorded when it closes, so a child
+    precedes its parent.  At most ``KEPT_BOUND`` (:func:`kept_dropped`)."""
+    return _kept_spans.records()
+
+
+def compile_log():
+    """What jax reported of every program this process built, oldest
+    first: ``CompileRecord(kind, fun_name, start_ns, end_ns, thread, span,
+    retrieval_s)``.  ``kind`` is ``trace`` (python to jaxpr), ``lower``
+    (jaxpr to MLIR), ``compile`` (the backend compiled it) or
+    ``cache_load`` (the backend record of a program that the persistent
+    cache held; ``retrieval_s`` is what jax says reading it took, None
+    for the other kinds).  ``fun_name`` is jax's; ``span`` the innermost
+    kept span open on the thread, or None; the times are
+    ``time.time_ns()``'s.  A trace that ran inside another trace or inside
+    a lowering on its thread (the ``jit`` functions a step program calls,
+    thousands for a language model) leaves no record.
+    At most ``KEPT_BOUND`` (:func:`kept_dropped`)."""
+    return _compile_log.records()
+
+
+def kept_dropped():
+    """How many records each list has lost to its bound."""
+    return {"kept_spans": _kept_spans.dropped,
+            "compile_log": _compile_log.dropped}
+
+
+def clear_kept():
+    """Forget the kept spans, the compile log and their drop counts."""
+    _kept_spans.clear()
+    _compile_log.clear()
+
+
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_scalar(event, value, **_kw):
+    # jax reports the start of each of the three before it does the work
+    if event in _COMPILE_KINDS:
+        _thread.building.append(value)
+
+
+def _on_event(event, **_kw):
+    if event == _CACHE_HIT_EVENT:
+        _thread.hit = 0.0
+
+
+def _on_duration(event, seconds, **_kw):
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _thread.hit = seconds
+
+
+def _on_time_span(event, start, end, fun_name=None, **_kw):
+    kind = _COMPILE_KINDS.get(event)
+    if kind is None:
+        return
+    # what opened since this one's start was inside it and is reported
+    building = _thread.building
+    while building and building[-1] >= start:
+        building.pop()
+    if kind == "trace" and building:
+        # a jit function that a program calls, met while the program is
+        # traced or by one of its lowering rules: no program
+        return
+    retrieval_s = None
+    if kind == "compile" and _thread.hit is not None:
+        kind, retrieval_s, _thread.hit = "cache_load", _thread.hit, None
+    open_here = _thread.spans
+    _compile_log.add(CompileRecord(
+        kind, fun_name, int(start * 1e9), int(end * 1e9),
+        threading.get_ident(), open_here[-1] if open_here else None,
+        retrieval_s))
+
+
+def _listen_to_jax():
+    """Once, at import: jax reports to the compile log from here on.  The
+    listeners run only when a program is built."""
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_scalar)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_time_span_listener(_on_time_span)
 
 
 def counter(name, values, cat="framework"):
@@ -544,3 +737,4 @@ def _activate_from_env():
 
 
 _activate_from_env()
+_listen_to_jax()

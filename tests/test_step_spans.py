@@ -91,8 +91,15 @@ def test_three_steps_on_the_profilers_clock(tmp_path, zero, with_optimizer):
     assert [s[4]["step_num"] for s in steps] == [0, 1, 2]
     expected = [KEY] + [SCALARS] * with_optimizer + [LAUNCH]
     for n, (_, start, end, line, _) in enumerate(steps):
-        children = [s for s in spans
-                    if s[0] != STEP and start <= s[1] and s[2] <= end]
+        inside = [s for s in spans
+                  if s[0] != STEP and start <= s[1] and s[2] <= end]
+        # call 0 settles the orders the state is held in (PR 36's spans,
+        # tests/test_setup_spans.py); no later call does
+        settled = [s[0] for s in inside if s[0].startswith("mxtpu.setup.")]
+        assert settled == (n == 0) * (
+            ["mxtpu.setup.orders"] + ["mxtpu.setup.orders.learn"] * (not zero)
+            + ["mxtpu.setup.orders.relay"] * bool(step._relaid))
+        children = [s for s in inside if s[0].startswith(STEP + ".")]
         assert [c[0] for c in children] == \
             [PUT] * (n != 1) + expected
         # on the step's own line, one after the other
@@ -101,7 +108,8 @@ def test_three_steps_on_the_profilers_clock(tmp_path, zero, with_optimizer):
         launch = children[-1]
         assert launch[4] == {"leaves": step._leaves,
                              "relaid_leaves": step._relaid}
-    assert len(spans) == 3 * (1 + len(expected)) + 2
+    assert len([s for s in spans if s[0].startswith(STEP)]) \
+        == 3 * (1 + len(expected)) + 2
     # every array the launch flattens: parameters, optimizer state,
     # statistics, batch, labels, key and the optimizer's host scalars
     n_scalars = len(step._rule.slots)
