@@ -874,32 +874,121 @@ def moe_experts(data, expert_ids, expert_weights, gate_weight, up_weight,
 # ------------------------------------------------- head fused with its loss
 
 
+def _head_rows(h, weight, y):
+    """A chunk's float32 logits, their log-sum-exp and its loss rows (0
+    where the label is negative)."""
+    logits = _dot(h, weight, ((1,), (1,)))
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logits, jnp.maximum(y, 0)[:, None], axis=-1)[:, 0]
+    return logits, logz, jnp.where(y >= 0, logz - picked, 0.0)
+
+
+def _head_dlogits(h, weight, y, g=None):
+    """A chunk's loss rows and ``dlogits = g (softmax - onehot(y))``, 0
+    where the label is negative, ``g`` a row's cotangent (1 where it is
+    None), cast to the operands' dtype for the products that take it."""
+    logits, logz, loss = _head_rows(h, weight, y)
+    d = jnp.exp(logits - logz[:, None]) - (
+        lax.broadcasted_iota(jnp.int32, logits.shape, 1) == y[:, None])
+    if g is not None:
+        d = d * g[:, None]
+    return loss, jnp.where((y >= 0)[:, None], d, 0.0).astype(h.dtype)
+
+
+def _head_scan(data, weight, label, chunk, g=None):
+    """Over the chunks of rows, ``dW`` summed in the weight's dtype, as the
+    transpose of a loop over checkpointed chunks sums it (a float32 carry
+    made Mellum2's step hold 227 MB more temporaries: PERF.md, PR 38).
+    -> (loss rows, dh, dW): three products a chunk; with ``g``, dW alone:
+    two."""
+    rows, width = data.shape
+    n = rows // chunk
+    xs = (data.reshape(n, chunk, width), label.reshape(n, chunk))
+    if g is not None:
+        xs += (g.reshape(n, chunk),)
+
+    def body(dw, args):
+        h = args[0]
+        loss, d = _head_dlogits(h, weight, *args[1:])
+        dw = dw + _dot(d, h, ((0,), (0,))).astype(dw.dtype)
+        if g is not None:
+            return dw, ()
+        return dw, (loss, _dot(d, weight, ((1,), (0,))).astype(h.dtype))
+
+    dw, rest = lax.scan(body, jnp.zeros_like(weight), xs)
+    if g is not None:
+        return dw
+    loss, dh = rest
+    return loss.reshape(rows), dh.reshape(rows, width), dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _fused_head_loss(data, weight, label, chunk):
+    """Not differentiated: a chunk's logits and its loss rows, one product
+    a chunk, nothing kept."""
+    rows = data.shape[0]
+    with _xray.scope("lm_head"):
+        out = lax.map(lambda a: _head_rows(a[0], weight, a[1])[2],
+                      (data.reshape(rows // chunk, chunk, -1),
+                       label.reshape(rows // chunk, chunk)))
+    return out.reshape(rows)
+
+
+def _fused_head_loss_fwd(data, weight, label, chunk):
+    """The gradient for a cotangent of 1 on every row, formed while a
+    chunk's logits are in hand: three products a chunk, none left for the
+    backward pass."""
+    with _xray.scope("lm_head"):
+        loss, dh, dw = _head_scan(data, weight, label, chunk)
+    return loss, (dh, dw, data, weight, label)
+
+
+def _fused_head_loss_bwd(chunk, res, g):
+    """``dh`` is the kept one times a row's cotangent, whatever it is.
+    ``dW``: where ``g`` is one value over the labelled rows (what a mean of
+    the rows, or a row's sum times a constant, sends back), the kept one
+    times it, no product; else a chunk's logits again and the ``dW``
+    product, under ``lm_head.recompute``.  Decided on the device; exact
+    either way."""
+    dh_unit, dw_unit, data, weight, label = res
+    with _xray.scope("lm_head"):
+        g = g.astype(jnp.float32)
+        labelled = label >= 0
+        g0 = g[jnp.argmax(labelled)]
+        same = jnp.all(jnp.where(labelled, g == g0, True))
+        dh = (dh_unit * jnp.where(labelled, g, 0.0)[:, None]).astype(
+            data.dtype)
+
+        def again():
+            with _xray.scope("lm_head.recompute"):
+                return _head_scan(data, weight, label, chunk, g)
+
+        dw = lax.cond(same, lambda: (dw_unit * g0).astype(weight.dtype),
+                      again)
+    return dh, dw, None
+
+
+_fused_head_loss.defvjp(_fused_head_loss_fwd, _fused_head_loss_bwd)
+
+
 @register("_contrib_linear_cross_entropy",
           aliases=("linear_cross_entropy",))
 def linear_cross_entropy(data, weight, label, chunk=DEFAULT_LOSS_CHUNK, **_):
     """``-log softmax(data @ weight.T)[label]`` per row, the head's product
     fused with its loss over chunks of rows so that the float32 logits
-    never stand whole: a chunk's logits are recomputed in the backward
-    pass.  ``data`` (rows, in), ``weight`` (classes, in), ``label`` (rows,)
-    integer; a negative label gives 0 (the row is left out).  Fewer rows
-    than ``chunk`` take one chunk; otherwise the chunk is the largest
-    common divisor of the two.  -> (rows,) float32."""
+    never stand whole.  Differentiated, the forward pass forms the gradient
+    while a chunk's logits are in hand (``dlogits = softmax - onehot``,
+    three products a chunk); the backward pass scales it by the cotangent,
+    and recomputes a chunk's logits, for ``dW`` alone, only where that is
+    not one value over the labelled rows.  ``data`` (rows, in), ``weight``
+    (classes, in), ``label`` (rows,) integer; a negative label gives 0 (the
+    row is left out).  Fewer rows than ``chunk`` take one chunk; otherwise
+    the chunk is the largest common divisor of the two.  -> (rows,)
+    float32."""
     rows = data.shape[0]
     chunk = rows if rows <= int(chunk) else math.gcd(rows, int(chunk))
-    label = label.astype(jnp.int32)
-
-    @jax.checkpoint
-    def one(args):
-        h, y = args
-        logits = _dot(h, weight, ((1,), (1,)))
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, jnp.maximum(y, 0)[:, None], axis=-1)[:, 0]
-        return jnp.where(y >= 0, logz - picked, 0.0)
-
-    out = lax.map(one, (data.reshape(rows // chunk, chunk, -1),
-                        label.reshape(rows // chunk, chunk)))
-    return out.reshape(rows)
+    return _fused_head_loss(data, weight, label.astype(jnp.int32), chunk)
 
 
 OP_INPUT_NAMES.update({

@@ -3,6 +3,8 @@ flash-attention kernels with a value head size that differs from the
 scores' (interpret mode).  Docs: docs/LLM_OPS.md."""
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -982,3 +984,204 @@ def test_a_softmax_router_and_its_balancing_term():
                                  eps=0.0, scoring="softmax", balance=True)
     assert len(op) == 3
     assert float(op[2].asnumpy()) == pytest.approx(float(want_term), rel=1e-6)
+
+
+# ------------------------------- the head fused with its loss, PR 38
+
+
+def _recomputing_linear_ce(data, weight, label, chunk=llm.DEFAULT_LOSS_CHUNK):
+    """The op before PR 38, kept as a reference: a chunk's logits under
+    ``jax.checkpoint``, recomputed in the backward pass (four products a
+    chunk under ``value_and_grad``)."""
+    rows = data.shape[0]
+    chunk = rows if rows <= chunk else math.gcd(rows, chunk)
+
+    @jax.checkpoint
+    def one(args):
+        h, y = args
+        logits = jax.lax.dot_general(h, weight, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(y, 0)[:, None], axis=-1)[:, 0]
+        return jnp.where(y >= 0, logz - picked, 0.0)
+
+    return jax.lax.map(one, (data.reshape(rows // chunk, chunk, -1),
+                             label.reshape(rows // chunk, chunk))
+                       ).reshape(rows)
+
+
+def np_linear_ce_grads(h, w, label, g):
+    """``dh``, ``dW`` of ``sum(g * rows)``: ``dlogits = g (softmax -
+    onehot)``, 0 where the label is negative."""
+    logits = h @ w.T
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    d = p / p.sum(-1, keepdims=True)
+    d[np.arange(len(label)), np.maximum(label, 0)] -= 1.0
+    d *= np.where(label >= 0, g, 0.0)[:, None]
+    return d @ w, d.T @ h
+
+
+def _next_token_rows(batch, seq, classes, ahead=1, seed=3):
+    """``_head_loss``'s labels: token ``i + ahead`` labels position ``i``,
+    a row's last ``ahead`` positions -1; some more -1 besides."""
+    rs = _rs(seed)
+    tokens = rs.randint(0, classes, (batch, seq))
+    labels = np.concatenate([tokens[:, ahead:],
+                             np.full((batch, ahead), -1)], axis=1)
+    labels[rs.rand(batch, seq) < 0.2] = -1
+    return labels.reshape(-1)
+
+
+def _step_loss(op, h, w, label, batch, ahead=1, scale=1.0):
+    """What ``GluonTrainStep`` differentiates: the mean over rows of
+    ``_head_loss``'s per-row value, a row's sum times a constant."""
+    seq = h.shape[0] // batch
+    rows = op(h, w, jnp.asarray(label))
+    return jnp.mean(jnp.sum(rows.reshape(batch, seq), axis=1)
+                    * (scale / (seq - ahead)))
+
+
+# (batch, seq, chunk): 40 rows in chunks of 8 (16 does not divide them),
+# 48 in three of 16, 12 in one
+SHAPES = [(2, 20, 16), (3, 16, 16), (1, 12, 16)]
+
+
+@pytest.mark.parametrize("batch,seq,chunk", SHAPES)
+def test_the_gradient_under_the_steps_cotangent(batch, seq, chunk):
+    """float32, the step's own cotangent (one value on every row), labels
+    of -1 present, against the numpy form."""
+    rs = _rs(4)
+    h, w = rs.randn(batch * seq, 8), rs.randn(24, 8)
+    label = _next_token_rows(batch, seq, 24)
+    assert (label < 0).any() and (label >= 0).any()
+    op = functools.partial(llm.linear_cross_entropy, chunk=chunk)
+    value, (dh, dw) = jax.value_and_grad(
+        lambda h, w: _step_loss(op, h, w, label, batch),
+        argnums=(0, 1))(_f(h), _f(w))
+    g = np.full(batch * seq, 1.0 / (batch * (seq - 1)))
+    assert float(value) == pytest.approx(
+        float(np.sum(np_linear_ce(h, w, label) * g)), rel=1e-5)
+    want_dh, want_dw = np_linear_ce_grads(h, w, label, g)
+    np.testing.assert_allclose(dh, want_dh, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-7)
+    assert dh.dtype == dw.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("batch,seq,chunk", SHAPES)
+def test_the_gradient_in_bfloat16_is_the_recomputing_ops(batch, seq, chunk):
+    """bfloat16 operands: the gradient formed in the forward pass against
+    the parent's formulation, within bfloat16's rounding (the two round
+    ``dlogits`` and the products' results at other points)."""
+    rs = _rs(6)
+    h = jnp.asarray(rs.randn(batch * seq, 16), jnp.bfloat16)
+    w = jnp.asarray(0.5 * rs.randn(40, 16), jnp.bfloat16)
+    label = _next_token_rows(batch, seq, 40)
+
+    def grads(op):
+        return jax.value_and_grad(
+            lambda h, w: _step_loss(functools.partial(op, chunk=chunk),
+                                    h, w, label, batch, scale=0.3),
+            argnums=(0, 1))(h, w)
+
+    (value, got), (want_value, want) = (grads(llm.linear_cross_entropy),
+                                        grads(_recomputing_linear_ce))
+    assert float(value) == pytest.approx(float(want_value), rel=1e-6)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=2 ** -6,
+                                   atol=2 ** -7 * np.abs(b).max())
+
+
+def test_one_weight_in_two_calls_sums_their_gradients():
+    """``MultiTokenLoss`` applies one head to two streams, a tied head is
+    the embedding too: every path's gradient of the weight adds up."""
+    rs = _rs(8)
+    batch, seq, classes = 2, 12, 20
+    w, h = _f(rs.randn(classes, 8)), _f(rs.randn(batch * seq, 8))
+    tokens = jnp.asarray(rs.randint(0, classes, batch * seq))
+    main, ahead = (_next_token_rows(batch, seq, classes, a, seed=a)
+                   for a in (1, 2))
+
+    def loss(op, w, h):
+        embedded = w[tokens] + h                 # the tied embedding
+        return _step_loss(op, embedded, w, main, batch) \
+            + _step_loss(op, h, w, ahead, batch, ahead=2, scale=0.3)
+
+    for chunk in (8, 24):
+        got = jax.grad(lambda w, h: loss(functools.partial(
+            llm.linear_cross_entropy, chunk=chunk), w, h), (0, 1))(w, h)
+        want = jax.grad(lambda w, h: loss(functools.partial(
+            _recomputing_linear_ce, chunk=chunk), w, h), (0, 1))(w, h)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("cotangent", ["one_value", "zero",
+                                       "other_on_unlabelled_rows",
+                                       "a_value_a_row"])
+def test_every_cotangent_gives_the_numpy_gradient(cotangent):
+    """The backward pass scales the kept gradient where the cotangent is
+    one value over the labelled rows (whatever it is on the others) and
+    recomputes where it is not: exact either way."""
+    rs = _rs(10)
+    h, w = rs.randn(32, 8), rs.randn(12, 8)
+    label = _next_token_rows(4, 8, 12)
+    g = {"one_value": np.full(32, 0.37), "zero": np.zeros(32),
+         "other_on_unlabelled_rows": np.where(label >= 0, 0.37, 5.0),
+         "a_value_a_row": rs.randn(32)}[cotangent]
+    rows, vjp = jax.vjp(lambda h, w: llm.linear_cross_entropy(
+        h, w, jnp.asarray(label), chunk=8), _f(h), _f(w))
+    np.testing.assert_allclose(rows, np_linear_ce(h, w, label), rtol=2e-5,
+                               atol=2e-5)
+    dh, dw = vjp(_f(g))
+    want_dh, want_dw = np_linear_ce_grads(h, w, label, g)
+    np.testing.assert_allclose(dh, want_dh, rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(dw, want_dw, rtol=2e-5, atol=1e-6)
+
+
+def _products(text):
+    """The ``dot`` instructions of an optimized HLO module."""
+    return [line for line in text.splitlines()
+            if re.search(r"= \S+ dot\(", line)]
+
+
+def test_the_forward_alone_holds_one_product_a_chunk():
+    """Not differentiated (evaluation, the imperative op): a loop over the
+    chunks whose body holds one product, and nothing else kept."""
+    rs = _rs(12)
+    h, w = _f(rs.randn(40, 8)), _f(rs.randn(24, 8))
+    label = jnp.asarray(_next_token_rows(2, 20, 24))
+    lowered = jax.jit(lambda h, w: llm.linear_cross_entropy(
+        h, w, label, chunk=16)).lower(h, w)
+    text = lowered.as_text()
+    assert text.count("stablehlo.dot_general") == 1
+    assert text.count("stablehlo.while") == 1
+    assert len(_products(lowered.compile().as_text())) == 1
+
+
+def test_the_steps_gradient_compiles_without_the_recomputation():
+    """Under the step's cotangent the compiled gradient holds three
+    products (the logits, ``dh``, ``dW``), all under ``lm_head``, none
+    under ``lm_head.recompute``, and no conditional: XLA sees the
+    cotangent is one value.  Under a cotangent that differs between rows
+    the recomputation is there, for ``dW`` alone: the logits and one
+    product (``dh`` is the kept one times the cotangent, exact for any)."""
+    rs = _rs(14)
+    h, w = _f(rs.randn(40, 8)), _f(rs.randn(24, 8))
+    label = _next_token_rows(2, 20, 24)
+    op = functools.partial(llm.linear_cross_entropy, chunk=16)
+    step = jax.jit(jax.grad(lambda h, w: _step_loss(op, h, w, label, 2),
+                            argnums=(0, 1))).lower(h, w).compile().as_text()
+    products = _products(step)
+    assert len(products) == 3
+    assert all("lm_head" in p and "lm_head.recompute" not in p
+               for p in products)
+    assert "conditional(" not in step
+    other = jax.jit(jax.grad(lambda h, w, g: jnp.sum(op(h, w, jnp.asarray(
+        label)) * g), argnums=(0, 1))).lower(
+            h, w, _f(rs.randn(40))).compile().as_text()
+    assert "conditional(" in other
+    assert len([p for p in _products(other)
+                if "lm_head.recompute" in p]) == 2
