@@ -148,7 +148,8 @@ class MLAttention(HybridBlock):
 
 class RoutedExperts(HybridBlock):
     """Routed experts, with one shared expert unless ``shared`` is false;
-    see the module docstring.
+    see the module docstring.  ``shared``: true, a shared expert as wide as
+    a routed one (``hidden_size``); a number, one of that width.
 
     ``num_experts``: the router's width (the model's experts);
     ``held_experts`` ``(first, count)``: the consecutive expert ids held
@@ -231,8 +232,9 @@ class RoutedExperts(HybridBlock):
                 "held_pairs", shape=(1,), grad_req="null", init="zeros")
             self.max_load = self.params.get(
                 "max_load", shape=(1,), grad_req="null", init="zeros")
-            self.shared = GatedFFN(units, hidden_size, weight_std=weight_std,
-                                   prefix="shared_") if shared else None
+            self.shared = GatedFFN(
+                units, hidden_size if shared is True else int(shared),
+                weight_std=weight_std, prefix="shared_") if shared else None
 
     def hybrid_forward(self, F, x, router_weight, experts_gate_weight,
                        experts_up_weight, experts_down_weight, held_pairs,

@@ -245,7 +245,8 @@ def _install_contrib_ops(namespace):
                       "_contrib_rms_norm", "_contrib_rope",
                       "_contrib_gated_silu", "_contrib_mla_qkv",
                       "_contrib_mla_out", "_contrib_gqa_qkv",
-                      "_contrib_gqa_out", "_contrib_gated_short_conv",
+                      "_contrib_gqa_out", "_contrib_head_gate",
+                      "_contrib_gated_short_conv",
                       "_contrib_moe_route",
                       "_contrib_moe_experts",
                       "_contrib_linear_cross_entropy")]

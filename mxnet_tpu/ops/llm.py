@@ -3,7 +3,9 @@
 RMS norm, rotary position embedding (adjacent pairs or halves, its
 frequencies and amplitude data of the layer: ``rotary_frequencies`` has
 yarn's), the gated-SiLU feed-forward, the projections of latent attention
-and of grouped-query attention with a norm on every head, a gated short
+and of grouped-query attention with a norm on every head (the rotation on
+all of a head's lanes or its first ones) and the per-head output gate of
+gated attention, a gated short
 causal convolution over the sequence, the router (sigmoid scores with a
 selection bias, DeepSeek-V3, arXiv:2412.19437, or softmax scores, with the
 balancing term of Switch Transformer, arXiv:2101.03961) and the
@@ -40,8 +42,8 @@ from ..util import pallas_interpret
 from .registry import OP_INPUT_NAMES, register
 
 __all__ = ["rms_norm", "rope", "rotary_frequencies", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
-           "gqa_out", "gated_short_conv", "moe_route", "moe_experts",
-           "linear_cross_entropy", "expert_tiles"]
+           "gqa_out", "head_gate", "gated_short_conv", "moe_route",
+           "moe_experts", "linear_cross_entropy", "expert_tiles"]
 
 # rows of one expert tile: what an expert's rows are padded to.  A step of
 # the loops costs its expert's three weights read (twice in the backward
@@ -329,9 +331,19 @@ def mla_out(data, weight, **_):
 # ------------------------------------ grouped-query attention, head norms
 
 
+def _first_lanes_rotated(x, cos, sin):
+    """Lanes ``0 .. r - 1`` of ``x (..., S, d)`` rotated by halves (pairs
+    ``(i, i + r / 2)``) by tables of ``r`` lanes, the lanes from ``r`` on
+    passed through: transformers' partial rotary."""
+    r = cos.shape[-1]
+    return lax.dynamic_update_slice_in_dim(
+        x, _rotary(x[..., :r], cos, sin, 0, True), 0, axis=x.ndim - 1)
+
+
 @register("_contrib_gqa_qkv", num_outputs=3, aliases=("gqa_qkv",))
 def gqa_qkv(data, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
-            theta=10000.0, eps=1e-6, inv_freq=None, amplitude=1.0, **_):
+            theta=10000.0, eps=1e-6, inv_freq=None, amplitude=1.0,
+            rotary_dim=None, **_):
     """The projections of grouped-query attention with a norm on every
     head (Ainslie et al., arXiv:2305.13245; the head norms of Dehghani et
     al., arXiv:2302.05442), from the block's input ``(B, S, units)`` to
@@ -345,13 +357,23 @@ def gqa_qkv(data, q_weight, k_weight, v_weight, qnorm_weight, knorm_weight,
     2)``).  The head size is read from the norms' scales, the head counts
     from the weights.  Every product writes ``(B, heads, S, d)`` itself.
     ``inv_freq`` / ``amplitude``: the layer's rotary scaling in place of
-    ``theta``, as in :func:`rope`."""
+    ``theta``, as in :func:`rope`.  ``rotary_dim`` (``r < d``; a config's
+    ``partial_rotary_factor`` times ``d``): only the first ``r`` lanes of a
+    head are rotated, pairs ``(i, i + r / 2)``, by ``r / 2`` frequencies
+    and the amplitude; the other lanes pass through unscaled."""
     d = qnorm_weight.shape[0]
+    partial = rotary_dim is not None and int(rotary_dim) < d
     with _xray.scope("gqa.proj"):
-        cos, sin = _rotary_tables(data.shape[-2], d, theta, True, inv_freq,
-                                  float(amplitude))
+        cos, sin = _rotary_tables(data.shape[-2],
+                                  int(rotary_dim) if partial else d, theta,
+                                  True, inv_freq, float(amplitude))
         q, k, v = (_by_head(data, w.reshape(-1, d, w.shape[-1]))
                    for w in (q_weight, k_weight, v_weight))
+        if partial:
+            return (_first_lanes_rotated(rms_norm(q, qnorm_weight, eps=eps),
+                                         cos, sin),
+                    _first_lanes_rotated(rms_norm(k, knorm_weight, eps=eps),
+                                         cos, sin), v)
         q = _rotary(rms_norm(q, qnorm_weight, eps=eps), cos, sin, 0, True)
         k = _rotary(rms_norm(k, knorm_weight, eps=eps), cos, sin, 0, True)
         return q, k, v
@@ -363,6 +385,22 @@ def gqa_out(data, weight, **_):
     result (``_heads_out``), under the scope ``gqa.proj``."""
     with _xray.scope("gqa.proj"):
         return _heads_out(data, weight)
+
+
+@register("_contrib_head_gate", aliases=("head_gate",))
+def head_gate(data, gate_data, gate_weight, **_):
+    """The per-head output gate of gated attention (Qiu et al.,
+    arXiv:2505.06708, its headwise form): ``data (B, heads, S, d)``, the
+    attention kernel's own result, times ``sigmoid(gate_data W_g^T)``, one
+    scalar a head and position, ``gate_data (B, S, units)`` the block's
+    normed input and ``gate_weight (heads, units)``.  The product writes
+    ``(B, heads, S)`` itself; sigmoid and multiply in float32, the result in
+    ``data``'s dtype, under the scope ``gqa.gate``."""
+    with _xray.scope("gqa.gate"):
+        logits = jnp.einsum("bsu,hu->bhs", gate_data, gate_weight,
+                            preferred_element_type=jnp.float32)
+        return (data.astype(jnp.float32)
+                * jax.nn.sigmoid(logits)[..., None]).astype(data.dtype)
 
 
 # --------------------------------------------- gated short convolution
@@ -1002,6 +1040,7 @@ OP_INPUT_NAMES.update({
     "_contrib_gqa_qkv": ("data", "q_weight", "k_weight", "v_weight",
                          "qnorm_weight", "knorm_weight"),
     "_contrib_gqa_out": ("data", "weight"),
+    "_contrib_head_gate": ("data", "gate_data", "gate_weight"),
     "_contrib_gated_short_conv": ("data", "weight"),
     "_contrib_moe_route": ("data", "router_weight", "router_bias"),
     "_contrib_moe_experts": ("data", "expert_ids", "expert_weights",

@@ -22,7 +22,8 @@ _CONTRIB_OPS = [
     "_contrib_interleaved_matmul_selfatt_valatt",
     "_contrib_rms_norm", "_contrib_rope", "_contrib_gated_silu",
     "_contrib_mla_qkv", "_contrib_mla_out",
-    "_contrib_gqa_qkv", "_contrib_gqa_out", "_contrib_gated_short_conv",
+    "_contrib_gqa_qkv", "_contrib_gqa_out", "_contrib_head_gate",
+    "_contrib_gated_short_conv",
     "_contrib_moe_route", "_contrib_moe_experts",
     "_contrib_linear_cross_entropy",
 ]

@@ -550,6 +550,7 @@ ELSEWHERE = {
     "_contrib_mla_out": ("tests/test_mla_moe.py", "mla_out"),
     "_contrib_gqa_qkv": ("tests/test_llm_ops.py", "llm.gqa_qkv"),
     "_contrib_gqa_out": ("tests/test_llm_ops.py", "llm.gqa_out"),
+    "_contrib_head_gate": ("tests/test_laguna_layers.py", "llm.head_gate"),
     "_contrib_gated_short_conv": ("tests/test_llm_ops.py",
                                   "llm.gated_short_conv"),
     "_contrib_moe_route": ("tests/test_llm_ops.py", "llm.moe_route"),

@@ -762,6 +762,53 @@ def test_on_the_v5e_a_window_layers_kernels_run_the_band_only(v5e,
         == kinds.count("('dkv', 2)") == 2
 
 
+@pytest.mark.parametrize("dtype,heads,window", [
+    ("bfloat16", 72, 512), ("float32", 72, 512), ("float32", 48, None)],
+    ids=["window_group_9_bfloat16", "window_group_9_float32",
+         "full_group_6_float32"])
+def test_on_the_v5e_the_kernels_take_lagunas_groups_and_narrow_window(
+        v5e, monkeypatch, dtype, heads, window):
+    """Mosaic takes the three flash kernels at ``laguna_moe_train_seq4k``'s
+    shapes: 1 x 72 query heads on 8 key heads x 4,096 of 128 with a window
+    of 512 under blocks of 1,024 (both key blocks a query block runs are
+    cut into tiles: the diagonal's, and the one the window's edge
+    crosses), in bfloat16 as the step runs them and in float32 at
+    ``highest`` as the check does, and 48 on 8 over the whole row; the
+    benchmark's cost file finds each kernel by the layer's own head count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness import swa_attention_cost
+    from mxnet_tpu.ops import attention as att
+
+    monkeypatch.setattr(att, "pallas_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(n):
+        return jax.ShapeDtypeStruct((1, n, 4096, 128), jnp.dtype(dtype),
+                                    sharding=chip)
+
+    def forward_and_backward(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: att.flash_attention(
+            q, k, v, causal=True, window=window), q, k, v)
+        return (out,) + vjp(g)
+
+    assert att._block_choices(arg(heads), arg(8))[0] == (1024, 1024)
+    if window:
+        assert att._masked_offsets(1024, 1024, window) == [0, 1]
+    with jax.default_matmul_precision(
+            "highest" if dtype == "float32" else "default"):
+        text = jax.jit(forward_and_backward).lower(
+            arg(heads), arg(8), arg(8), arg(heads)).compile().as_text()
+    shapes = {"rows": 1, "seq": 4096, "heads": heads, "kv_heads": 8,
+              "d": 128}
+    size = jnp.dtype(dtype).itemsize
+    assert sorted(swa_attention_cost.kernel_kind(k, shapes)
+                  for k in _kernel_instructions(text)) == [
+        ("dkv", size), ("dq", size), ("forward", size)]
+
+
 @pytest.mark.parametrize("part", ["prefix", "sliding_attention",
                                   "full_attention"])
 def test_on_the_v5e_the_references_timed_rows_fit_beside_the_steps_state(

@@ -15,9 +15,14 @@ language cell's warm-up prints the routers' loads after every group),
 ``train_samples_per_s``, each group's time and the routed layers' counters;
 at the end the spread as the driver takes it (the distance between the first
 and third quartile of ``statistics.quantiles(values, n=4)`` over the
-median) and the mean drift from one group of a window to the next.
-``--warmup-groups`` overrides the traffic file's, to find the value to write
-there.  The lines also go to ``chiprun_out/cell_spread.jsonl``.
+median) and the mean drift from one group of a window to the next.  A seed's
+line also holds every dispatch's and every fetch's time in the window
+(``dispatch_s``, ``fetch_s``: a group that lost time shows whether the host
+held a dispatch or the device held the fetch) and the host's garbage
+collections while the window ran (``collections``: generation, seconds from
+the window's start, seconds taken).  ``--warmup-groups`` overrides the
+traffic file's, to find the value to write there.  The lines also go to
+``chiprun_out/cell_spread.jsonl``.
 """
 
 import argparse
@@ -41,6 +46,31 @@ def quartile_spread(values):
     return (q3 - q1) / statistics.median(values)
 
 
+class Collections:
+    """The host's garbage collections while it is entered: [generation,
+    start in seconds from the entry, seconds taken] each."""
+
+    def __init__(self):
+        self.seen, self._opened, self._began = [], None, None
+
+    def __call__(self, phase, info):
+        now = time.perf_counter()
+        if phase == "start":
+            self._began = now
+        elif self._began is not None:
+            self.seen.append([info["generation"], self._began - self._opened,
+                              now - self._began])
+            self._began = None
+
+    def __enter__(self):
+        self._opened = time.perf_counter()
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+
 def one_seed(cell, seed, devices, seconds):
     ctx = run.Context(cell, seed, devices)
     session = cell.entry().build(ctx)
@@ -55,7 +85,8 @@ def one_seed(cell, seed, devices, seconds):
 
     session.fetch = timed_fetch
     session.warm_up()
-    window = session.measure(seconds)
+    with Collections() as collections:
+        window = session.measure(seconds)
     n = session.steps_per_fetch
     line = {
         "cell": cell.name, "seed": seed, "ok": window["ok"],
@@ -63,6 +94,8 @@ def one_seed(cell, seed, devices, seconds):
         "steps": window["steps"],
         "group_s": [sum(window["dispatch_s"][i * n:(i + 1) * n]) + waited
                     for i, waited in enumerate(window["fetch_s"])],
+        "dispatch_s": window["dispatch_s"], "fetch_s": window["fetch_s"],
+        "collections": collections.seen,
         "counters": window.get("counters", {})}
     del session, window, fetch, timed_fetch
     gc.collect()

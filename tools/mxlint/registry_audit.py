@@ -121,6 +121,8 @@ def canonical_spec(name):
                               ((8, 8), f), ((4,), f), ((4,), f)],
                              {"theta": 1e4}),
         "_contrib_gqa_out": ([((2, 4, 5, 4), f), ((8, 16), f)], {}),
+        "_contrib_head_gate": ([((2, 4, 5, 4), f), ((2, 5, 8), f),
+                                ((4, 8), f)], {}),
         "_contrib_gated_short_conv": ([((2, 5, 24), f), ((8, 3), f)], {}),
         "_contrib_moe_route": ([((6, 8), f), ((12, 8), f), ((12,), f)],
                                {"k": 3, "scale": 2.5}),
