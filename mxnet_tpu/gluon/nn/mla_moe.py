@@ -41,7 +41,9 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
 
 Named scopes (``xray.scope``): ``mla.proj``, ``mla.attention``,
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
-``moe.shared``, ``moe.aux``, ``mtp``, ``lm_head``.  Counters: every
+``moe.shared``, ``moe.aux``, ``mtp``, ``lm_head``, and ``ffn.gated``
+around every :class:`GatedFFN` (``ops/llm.py::gated_silu``), dense or a
+shared expert (then inside ``moe.shared``).  Counters: every
 :class:`RoutedExperts` keeps ``held_pairs`` (pairs routed to its held
 experts in the last step) and ``max_load`` (the largest held expert's
 pairs over their mean), and one with a balancing loss ``balance_term``

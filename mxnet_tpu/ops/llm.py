@@ -240,12 +240,12 @@ def _dot(a, b, contract):
 def gated_silu(data, gate_weight, up_weight, down_weight, **_):
     """Gated-SiLU feed-forward ``W_down(silu(W_gate x) * W_up x)`` (Shazeer
     2020, arXiv:2002.05202), no biases; weights ``(out, in)`` like
-    ``FullyConnected``'s, float32 accumulation."""
-    last = (data.ndim - 1,)
-    g = _dot(data, gate_weight, (last, (1,)))
-    u = _dot(data, up_weight, (last, (1,)))
-    h = (jax.nn.silu(g) * u).astype(data.dtype)
-    return _dot(h, down_weight, (last, (1,))).astype(data.dtype)
+    ``FullyConnected``'s, float32 accumulation.  Forward and backward run
+    under the scope ``ffn.gated``; the backward pass is written by hand
+    (``_gated_silu_bwd``), its six products take their operands in the
+    data's type."""
+    with _xray.scope("ffn.gated"):
+        return _gated_silu(data, gate_weight, up_weight, down_weight)
 
 
 # --------------------------------------------------- latent attention
@@ -742,6 +742,50 @@ def _expert_forward(xt, wg, wu, wd):
     u = _dot(xt, wu, ((1,), (0,)))
     h = (jax.nn.silu(g) * u).astype(xt.dtype)
     return g, u, h, _dot(h, wd, ((1,), (0,)))
+
+
+@jax.custom_vjp
+def _gated_silu(x, wg, wu, wd):
+    return _gated_silu_fwd(x, wg, wu, wd)[0]
+
+
+def _gated_silu_fwd(x, wg, wu, wd):
+    last = (x.ndim - 1,)
+    g = _dot(x, wg, (last, (1,)))
+    u = _dot(x, wu, (last, (1,)))
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return _dot(h, wd, (last, (1,))).astype(x.dtype), (x, wg, wu, wd, g, u)
+
+
+def _gated_silu_bwd(res, dy):
+    """``dh = dy W_down`` in float32, then ``dg`` and ``du`` from it and
+    float32 ``g`` and ``u``, each rounded once to the data's type before
+    their products, as ``_routed_sum_bwd`` does: the products of ``dx``,
+    ``W_gate``'s and ``W_up``'s gradients take operands in that type and
+    sum in float32, like the forward pass's.  Left to autodiff, ``dg`` and
+    ``du`` stayed float32 and each of those four products' fusions formed
+    them again from ``g``, ``u`` and ``dh`` on every pass over its result
+    (a v5e ran the backward pass's six products at 50-59 % of its peak, the
+    forward's three at 91-95 %).  ``W_down``'s gradient reads ``h``, which
+    XLA forms inside that product from ``g`` and ``u``; barriers that kept
+    it a pass of its own cost a step's temporaries up to 1.9 GB (PERF.md,
+    Findings).  In float32 the roundings are none and the result is
+    autodiff's, up to the order of the sums."""
+    x, wg, wu, wd, g, u = res
+    last = (x.ndim - 1,)
+    rows = tuple(range(x.ndim - 1))
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    dh = _dot(dy, wd, (last, (0,)))
+    sig = jax.nn.sigmoid(g)
+    dg = (dh * u * sig * (1 + g * (1 - sig))).astype(x.dtype)
+    du = (dh * g * sig).astype(x.dtype)
+    dx = _dot(dg, wg, (last, (0,))) + _dot(du, wu, (last, (0,)))
+    return (dx.astype(x.dtype), _dot(dg, x, (rows, rows)).astype(wg.dtype),
+            _dot(du, x, (rows, rows)).astype(wu.dtype),
+            _dot(dy, h, (rows, rows)).astype(wd.dtype))
+
+
+_gated_silu.defvjp(_gated_silu_fwd, _gated_silu_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))

@@ -1185,3 +1185,116 @@ def test_the_steps_gradient_compiles_without_the_recomputation():
     assert "conditional(" in other
     assert len([p for p in _products(other)
                 if "lm_head.recompute" in p]) == 2
+
+
+# --------------------- the gated-SiLU feed-forward's backward pass
+
+
+def _plain_gated_silu(x, wg, wu, wd):
+    """The formula left to autodiff: what ``gated_silu`` was before its
+    backward pass was written by hand."""
+    last = (x.ndim - 1,)
+    g = llm._dot(x, wg, (last, (1,)))
+    u = llm._dot(x, wu, (last, (1,)))
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return llm._dot(h, wd, (last, (1,))).astype(x.dtype)
+
+
+def _gated_silu_args(rows, units=16, hidden=24, seed=15):
+    rs = _rs(seed)
+    return (rs.randn(*rows, units), rs.randn(hidden, units) * 0.3,
+            rs.randn(hidden, units) * 0.3, rs.randn(units, hidden) * 0.3,
+            rs.randn(*rows, units))
+
+
+@pytest.mark.parametrize("rows", [(2, 5), (7,)], ids=["batch", "no_batch"])
+def test_the_gated_silu_backward_in_float32_is_autodiffs(rows):
+    """In float32 the rounding to the data's type is none: the gradients of
+    ``x`` and of the three weights are autodiff's of the plain formula, up
+    to the order of the sums."""
+    x, wg, wu, wd, dy = (_f(a) for a in _gated_silu_args(rows))
+    with jax.default_matmul_precision("highest"):
+        got = jax.vjp(llm.gated_silu, x, wg, wu, wd)[1](dy)
+        want = jax.vjp(_plain_gated_silu, x, wg, wu, wd)[1](dy)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(a, w, rtol=1e-5, atol=1e-5)
+
+
+def _bf16(a):
+    """``a`` rounded to bfloat16, as float64."""
+    return np.asarray(jnp.asarray(np.asarray(a, np.float32), jnp.bfloat16),
+                      np.float64)
+
+
+@pytest.mark.parametrize("rows", [(3, 16), (40,)], ids=["batch", "no_batch"])
+def test_the_gated_silu_backward_in_bfloat16_rounds_dg_du_and_h(rows):
+    """bfloat16 in: every product takes operands in bfloat16 and sums in
+    float32, ``dh`` stays float32; ``dg``, ``du`` and ``h`` are each rounded
+    to bfloat16 once, before their products (numpy, float64 between the
+    roundings); the two halves of ``dx`` are summed before one rounding.
+    Results in bfloat16: at least 99 % of them the reference's to the
+    bit (left to autodiff, ``dg`` and ``du`` unrounded, about half), and
+    every one within two of its ulps of the largest value."""
+    x, wg, wu, wd, dy = (_bf16(a) for a in _gated_silu_args(
+        rows, units=32, hidden=48))
+    got = jax.vjp(llm.gated_silu, *(jnp.asarray(a, jnp.bfloat16)
+                                     for a in (x, wg, wu, wd)))[1](
+        jnp.asarray(dy, jnp.bfloat16))
+    g, u = x @ wg.T, x @ wu.T
+    sig = 1 / (1 + np.exp(-g))
+    h = _bf16(g * sig * u)
+    dh = dy @ wd
+    dg, du = _bf16(dh * u * sig * (1 + g * (1 - sig))), _bf16(dh * g * sig)
+    flat = (-1, x.shape[-1])
+    want = (dg @ wg + du @ wu, dg.reshape(-1, 48).T @ x.reshape(flat),
+            du.reshape(-1, 48).T @ x.reshape(flat),
+            dy.reshape(flat).T @ h.reshape(-1, 48))
+    for a, w in zip(got, want):
+        assert a.dtype == jnp.bfloat16 and a.shape == w.shape
+        a = np.asarray(a, np.float64)
+        assert np.mean(a == _bf16(w)) >= 0.99
+        assert np.abs(a - _bf16(w)).max() <= 2 * 2.0 ** -8 * np.abs(w).max()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_gated_ffn_recomputed_gives_the_gradients_it_gives_outside(dtype):
+    """``GatedFFN`` in a ``recomputed`` block (``jax.checkpoint``, as every
+    decoder block of the language cells is staged): its forward runs again
+    in the backward pass and the hand-written backward pass takes what
+    that run forms; the gradients of the input and of the three weights
+    are the ones the block gives outside the recomputation."""
+    from mxnet_tpu.gluon.block import recomputed, staged_call
+    from mxnet_tpu.gluon.nn import GatedFFN
+    from mxnet_tpu.ndarray import NDArray
+
+    ffn = GatedFFN(16, 24, weight_std=0.3, prefix="recomputed_ffn_")
+    ffn.initialize(ctx=mx.cpu())
+    params = list(ffn.collect_params().values())
+    rs = _rs(16)
+    cast = jnp.dtype(dtype)
+    x = jnp.asarray(rs.randn(2, 5, 16), cast)
+    dy = jnp.asarray(rs.randn(2, 5, 16), jnp.float32)
+    values = [p.data().data_jax.astype(cast) for p in params]
+
+    def loss(again, x, values):
+        def block(h):
+            return recomputed(ffn, h) if again else ffn(h)
+
+        out, _ = staged_call(block, {p: NDArray(v)
+                                     for p, v in zip(params, values)},
+                             None, [NDArray(x)])
+        return jnp.sum(out._data.astype(jnp.float32) * dy)
+
+    grads = {again: jax.jit(jax.grad(functools.partial(loss, again),
+                                     argnums=(0, 1)))(x, values)
+             for again in (False, True)}
+    assert " remat2[" in str(jax.make_jaxpr(
+        jax.grad(functools.partial(loss, True)))(x, values))
+    tol = 1e-6 if dtype == "float32" else 1e-2
+    for a, w in zip(jax.tree.leaves(grads[True]),
+                    jax.tree.leaves(grads[False])):
+        assert a.dtype == cast
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=tol, atol=tol)

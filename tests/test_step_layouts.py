@@ -809,6 +809,69 @@ def test_on_the_v5e_the_kernels_take_lagunas_groups_and_narrow_window(
         ("dkv", size), ("dq", size), ("forward", size)]
 
 
+def test_on_the_v5e_the_feed_forwards_gradients_read_dg_and_du_in_bfloat16(
+        v5e):
+    """One ``gated_silu`` at LFM2's widths (2 x 8,192 x 2,048 -> 7,168),
+    bfloat16, under ``jax.grad``, as the v5e's compiler emits it: eight
+    products under ``ffn.gated`` (the two of the forward pass that the
+    gradient needs and the six of the backward pass), and of them only
+    ``W_down``'s gradient reads float32 operands through its fusion, ``g``
+    and ``u``, from which it forms ``h`` (``tools/step_text.py``'s
+    ``float32_products``; left to autodiff, four more formed ``dg`` or
+    ``du`` from float32 ``g``, ``u`` and ``dh`` on every pass over their
+    results).  Of the layer's ``(rows, hidden)`` arrays ``g`` and ``u`` are
+    written once as float32, ``dg`` and ``du`` once as bfloat16, and
+    nothing else: ``dh`` is formed in the fusion that writes ``dg`` and
+    ``du``."""
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mxnet_tpu.ops import llm
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.harness import hlo_cost, scope_time
+    from tools.step_text import float32_products, unfused
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    rows, units, hidden = (2, 8192), 2048, 7168
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    text = jax.jit(jax.grad(
+        lambda x, wg, wu, wd, dy: jnp.sum(
+            llm.gated_silu(x, wg, wu, wd).astype(jnp.float32)
+            * dy.astype(jnp.float32)), argnums=(0, 1, 2, 3))).lower(
+        arg(*rows, units), arg(hidden, units), arg(hidden, units),
+        arg(units, hidden), arg(*rows, units)).compile().as_text()
+    products = float32_products(text, "ffn.gated")
+    assert len(products) == 8
+    module = hlo_cost.Module(text)
+    under = scope_time._under(("ffn.gated",))
+    written, results = [], {}
+    for comp in unfused(module).values():
+        for name, (opcode, (result, _, _)) in comp.items():
+            if opcode in ("parameter", "get-tuple-element", "tuple",
+                          "bitcast"):
+                continue
+            results[name] = [dims for _, dims, _ in
+                             hlo_cost.shape_dims(result)]
+            if under.search(module.instructions[name][1]):
+                written += [(dtype, dims) for dtype, dims, _ in
+                            hlo_cost.shape_dims(result)]
+    reading = {name: operands for name, operands in products.items()
+               if operands}
+    assert len(reading) == 1 and len(*reading.values()) == 2, products
+    assert results[next(iter(reading))] == [[units, hidden]]
+    wide = [dtype for dtype, dims in written if dims == [*rows, hidden]]
+    assert sorted(wide) == ["bf16"] * 2 + ["f32"] * 2, written
+
+
 @pytest.mark.parametrize("part", ["prefix", "sliding_attention",
                                   "full_attention"])
 def test_on_the_v5e_the_references_timed_rows_fit_beside_the_steps_state(

@@ -16,12 +16,14 @@ jaxpr, which holds the kernels' bodies, beside it.  ``--out`` keeps the
 texts, for a diff where two hashes differ.  ``--compile`` also compiles a
 language cell's step for that v5e (Mosaic and all: what the compiler
 refuses here costs no chip time) and prints the program's temporaries and
-state, the two parts of ``peak_hbm_gb``.
+state, the two parts of ``peak_hbm_gb``.  ``float32_products`` reads a
+compiled module's products under a named scope.
 """
 
 import argparse
 import hashlib
 import json
+import math
 import os
 import re
 import sys
@@ -29,6 +31,81 @@ import sys
 
 def digest(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def called(parts):
+    """The computations an instruction calls, in the order its attributes
+    name them (``hlo_cost``'s reading): a fusion's body first."""
+    from benchmark.harness import hlo_cost
+
+    return [name.lstrip("%") for group in hlo_cost._CALLED.findall(parts[2])
+            for name in re.split(r",\s*", group)]
+
+
+def unfused(module):
+    """{computation: {instruction: (opcode, parts)}} of a
+    ``hlo_cost.Module``'s computations that no fusion calls: the ones whose
+    instructions run as such (the entry, loop bodies, branches)."""
+    fused = {called(parts)[0] for comp in module._comps.values()
+             for opcode, parts in comp.values() if opcode == "fusion"}
+    return {name: comp for name, comp in module._comps.items()
+            if name not in fused}
+
+
+def float32_products(text, scope):
+    """{instruction: [float32 operands]} of the product instructions of an
+    optimized module outside fusion bodies (a fusion with a dot or a
+    convolution in it, or one such instruction) whose ``op_name`` lies
+    under the named scope ``scope``.  A float32 operand is a fusion's
+    parameter, or a plain product's operand, of as many elements as the
+    product's operand it reaches through the fusion's elementwise
+    arithmetic: the fusion forms that operand from it again on every pass
+    over its result.  A row's scale broadcast over the row (a norm's) or
+    a value that only meets the product's result is not one.  Empty where
+    there is none."""
+    from benchmark.harness import hlo_cost, scope_time
+
+    def elements(result):
+        typed = hlo_cost.shape_dims(result)
+        return (typed[0][0], math.prod(typed[0][1])) if typed else ("", 0)
+
+    def operands(parts):
+        return re.findall(r"%([\w.\-]+)", parts[1])
+
+    module = hlo_cost.Module(text)
+    under = scope_time._under((scope,))
+    found = {}
+    for comp in unfused(module).values():
+        for name, (opcode, parts) in comp.items():
+            flops, op_name = module.instructions.get(name, (0, ""))
+            if flops <= 0 or not under.search(op_name):
+                continue
+            body = module._comps[called(parts)[0]] \
+                if opcode == "fusion" else {name: (opcode, parts)}
+            float32 = set()
+            for o, p in body.values():
+                if o not in ("dot", "convolution"):
+                    continue
+                for operand in operands(p):
+                    result = (body.get(operand) or comp.get(operand)
+                              or ("", ("", "", "")))[1][0]
+                    size = elements(result)[1]
+                    stack, seen = [operand], set()
+                    while stack:
+                        at = stack.pop()
+                        if at in seen:
+                            continue
+                        seen.add(at)
+                        if at in body and body[at][0] != "parameter" \
+                                and at != name:
+                            stack += operands(body[at][1])
+                            continue
+                        source = (body.get(at) or comp.get(at)
+                                  or ("", ("", "", "")))[1][0]
+                        if elements(source) == ("f32", size):
+                            float32.add(at)
+            found[name] = sorted(float32)
+    return found
 
 
 def cnn_step_text(root, cell, compile_it=False):
