@@ -18,6 +18,11 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   the router keeps the model's width, selection and normalisation run over
   all experts, and the layer computes its own experts' part of the sum:
   one chip's share of an expert-parallel layer, without the exchange.
+- :class:`HyperConnection`: the coefficients of one sublayer's
+  manifold-constrained hyper-connection (mHC, DeepSeek-AI 2025, on
+  Hyper-Connections, arXiv:2409.19606) over a residual stream widened to
+  n streams: ``h_pre``, ``h_post`` and the Sinkhorn-normalised mixing
+  matrix ``H_res``.
 - :class:`DecoderBlock`: ``h += mixer(norm(h)); h += ffn(norm(h))`` over
   the token mixer and the feed-forward it is given, its forward recomputed
   in the backward pass while a step is staged.  The one block class of
@@ -28,9 +33,11 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   ``(h, term)`` through its recomputation, the model sums the blocks'
   terms and returns ``(hidden, side)``, and the loss block adds ``side``
   to every row's loss: a value of the program from the block to the loss,
-  with its gradient.
+  with its gradient.  With ``hc_mult`` n > 1 the block carries n streams
+  and wraps each sublayer in a :class:`HyperConnection`.
 - :class:`DecoderLM`: embedding, blocks, final norm and a head that may be
-  tied to the embedding; it returns the normed hidden states (with the
+  tied to the embedding (with n streams: the embedding copied into each,
+  their sum normed); it returns the normed hidden states (with the
   blocks' side term where they give one), ``net.head`` makes logits of
   them, and :class:`NextTokenLoss` fuses the head with the loss over token
   chunks.
@@ -38,12 +45,15 @@ Pallas flash attention of ``ops/attention.py``.  Docs: docs/LLM_OPS.md.
   one multi-token prediction module that shares embedding and head.  It
   returns the two streams' normed hidden states;
   :class:`MultiTokenLoss` fuses the head with the loss of both terms.
+  Without the module (``num_nextn_predict_layers`` 0) it returns one, as
+  :class:`DecoderLM` does; only then may its residual be widened.
 
 Named scopes (``xray.scope``): ``mla.proj``, ``mla.attention``,
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
 ``moe.shared``, ``moe.aux``, ``mtp``, ``lm_head``, and ``ffn.gated``
 around every :class:`GatedFFN` (``ops/llm.py::gated_silu``), dense or a
-shared expert (then inside ``moe.shared``).  Counters: every
+shared expert (then inside ``moe.shared``); ``hc.coef`` and ``hc.mix``
+around a hyper-connection's coefficients and its two mixes.  Counters: every
 :class:`RoutedExperts` keeps ``held_pairs`` (pairs routed to its held
 experts in the last step) and ``max_load`` (the largest held expert's
 pairs over their mean), and one with a balancing loss ``balance_term``
@@ -55,14 +65,17 @@ state.
 
 from __future__ import annotations
 
+import math
+
 from ... import initializer as _init
 from ... import xray as _xray
+from ...ops.llm import rotary_frequencies
 from ..block import Block, HybridBlock, recomputed, update_aux_state
 from .basic_layers import Dense, Embedding
 
 __all__ = ["RMSNorm", "GatedFFN", "MLAttention", "RoutedExperts",
-           "DecoderBlock", "DecoderLM", "MLAMoELM", "NextTokenLoss",
-           "MultiTokenLoss"]
+           "HyperConnection", "DecoderBlock", "DecoderLM", "MLAMoELM",
+           "NextTokenLoss", "MultiTokenLoss"]
 
 # at a quarter of the scores' spread (0.05) the bias alone made the busiest
 # expert 4-7 times the mean at random weights (PERF.md, PR 28)
@@ -101,6 +114,36 @@ class GatedFFN(HybridBlock):
         return F.contrib.gated_silu(x, gate_weight, up_weight, down_weight)
 
 
+def deepseek_yarn(dim, rope_theta, rope_scaling):
+    """A DeepSeek ``config.json``'s ``rope_scaling`` (``type`` ``"yarn"``,
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``, ``mscale``, ``mscale_all_dim``) for ``dim`` rotary lanes
+    -> ``(inv_freq, amplitude, softmax_factor)``: yarn's frequencies
+    (``ops.llm.rotary_frequencies``), the tables' amplitude ``m(mscale) /
+    m(mscale_all_dim)`` and what the softmax scale is multiplied by,
+    ``m(mscale_all_dim)^2`` where ``mscale_all_dim`` is given, with ``m(a)
+    = 0.1 a ln(factor) + 1`` (1 for a factor of 1 or less): DeepSeek-V3's
+    ``yarn_get_mscale``."""
+    how = dict(rope_scaling)
+    kind = how.get("type", how.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError("deepseek_yarn: rope_scaling type %r is not 'yarn'"
+                         % (kind,))
+    factor = float(how["factor"])
+
+    def m(a):
+        return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    every = how.get("mscale_all_dim", 0)
+    inv_freq, amplitude = rotary_frequencies(
+        dim, rope_theta=rope_theta, rope_type="yarn", factor=factor,
+        original_max_position_embeddings=how[
+            "original_max_position_embeddings"],
+        beta_fast=how.get("beta_fast", 32), beta_slow=how.get("beta_slow", 1),
+        attention_factor=m(how.get("mscale", 1)) / m(every))
+    return inv_freq, amplitude, m(every) ** 2 if every else 1.0
+
+
 class MLAttention(HybridBlock):
     """Multi-head latent attention, causal, in its training form:
     ``mla_qkv`` (``ops/llm.py``), the flash kernels, ``mla_out``.  The
@@ -112,12 +155,18 @@ class MLAttention(HybridBlock):
     def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                  rope_theta=10000.0, epsilon=1e-6, weight_std=0.02,
-                 **kwargs):
+                 rope_scaling=None, **kwargs):
         super().__init__(**kwargs)
         self._heads = num_heads
         self._theta, self._epsilon = rope_theta, epsilon
         qk = qk_nope_head_dim + qk_rope_head_dim
         self._sm_scale = qk ** -0.5
+        self._rotary = {}
+        if rope_scaling:
+            inv_freq, amplitude, softmax = deepseek_yarn(
+                qk_rope_head_dim, rope_theta, rope_scaling)
+            self._rotary = {"inv_freq": inv_freq, "amplitude": amplitude}
+            self._sm_scale *= softmax
         init = _init.Normal(weight_std)
         shapes = {
             "qa_weight": (q_lora_rank, units),
@@ -141,7 +190,7 @@ class MLAttention(HybridBlock):
         q, k, v = F.contrib.mla_qkv(
             x, qa_weight, qb_weight, kva_weight, kvb_weight, qnorm_weight,
             kvnorm_weight, num_heads=self._heads, theta=self._theta,
-            eps=self._epsilon)
+            eps=self._epsilon, **self._rotary)
         with _xray.scope("mla.attention"):
             o = F.contrib.flash_attention(q, k, v, causal=True,
                                           sm_scale=self._sm_scale)
@@ -280,6 +329,70 @@ class RoutedExperts(HybridBlock):
         return y, self._balance * term
 
 
+# the hyper-connections' scalars alpha_pre, alpha_post, alpha_res as drawn
+# (mHC's small start: the coefficients begin near their biases)
+HC_ALPHA = 0.01
+# what the biases of the hyper-connections are drawn about, and how widely:
+# h_pre's about logit(1 / n) (u starts near the streams' mean), h_post's
+# about 0 (2 sigmoid: each stream takes the sublayer's result once), the
+# mixing matrix's logits about HC_DIAGONAL on the diagonal and 0 off it, so
+# that Sinkhorn's H_res starts near the identity but not at it
+HC_DIAGONAL = 2.0
+HC_BIAS_STD = 0.3
+
+
+class HyperConnectionBias(_init.Initializer):
+    """The bias ``[pre (n) | post (n) | res (n x n)]`` of a
+    :class:`HyperConnection`: ``logit(1 / n)``, 0 and ``diagonal`` times
+    the identity, each plus N(0, ``sigma``) (``HC_DIAGONAL``,
+    ``HC_BIAS_STD``)."""
+
+    def __init__(self, streams, diagonal=HC_DIAGONAL, sigma=HC_BIAS_STD):
+        super().__init__(streams=streams, diagonal=diagonal, sigma=sigma)
+        self._n, self._diagonal, self._sigma = streams, diagonal, sigma
+
+    def _init_weight(self, _, arr):
+        import numpy as np
+
+        from ...ndarray import array
+        from ...ndarray import random as ndr
+
+        n = self._n
+        centre = np.concatenate([
+            np.full(n, -math.log(n - 1.0)), np.zeros(n),
+            self._diagonal * np.eye(n).reshape(-1)]).astype(np.float32)
+        arr[:] = array(centre) + ndr.normal(0.0, self._sigma,
+                                            shape=arr.shape)
+
+
+class HyperConnection(HybridBlock):
+    """The coefficients of one sublayer's manifold-constrained
+    hyper-connection over ``streams`` streams of ``units``
+    (``ops/llm.py::hc_coefficients``): ``phi (streams x units, 2 streams +
+    streams^2)`` drawn N(0, ``weight_std``), ``bias`` by
+    :class:`HyperConnectionBias`, ``alpha`` (pre, post, res) ``HC_ALPHA``.
+    Called on the widened stream ``(B, S, streams x units)`` it returns
+    ``(h_pre, h_post, H_res)``, the token axes last."""
+
+    def __init__(self, units, streams, sinkhorn_iters=20, epsilon=1e-6,
+                 clamp=(-30.0, 30.0), weight_std=0.02, **kwargs):
+        super().__init__(**kwargs)
+        self._how = dict(streams=streams, iters=sinkhorn_iters, eps=epsilon,
+                         clamp_min=clamp[0], clamp_max=clamp[1])
+        width = 2 * streams + streams * streams
+        with self.name_scope():
+            self.phi = self.params.get(
+                "phi", shape=(streams * units, width),
+                init=_init.Normal(weight_std))
+            self.bias = self.params.get(
+                "bias", shape=(width,), init=HyperConnectionBias(streams))
+            self.alpha = self.params.get(
+                "alpha", shape=(3,), init=_init.Constant(HC_ALPHA))
+
+    def hybrid_forward(self, F, x, phi, bias, alpha):
+        return F.contrib.hc_coefficients(x, phi, bias, alpha, **self._how)
+
+
 class DecoderBlock(HybridBlock):
     """One decoder block: ``h += mixer(norm(h)); h += ffn(norm(h))``.
     ``mixer`` and ``ffn`` are called without arguments inside the block's
@@ -290,13 +403,30 @@ class DecoderBlock(HybridBlock):
     block's input and its attention kernel's results are kept for the
     backward pass, which runs the rest of the forward again
     (``gluon.block.recomputed``): a block's activations are thirty times
-    its input, and a model of this kind fills a chip with its state."""
+    its input, and a model of this kind fills a chip with its state.
 
-    def __init__(self, units, mixer, ffn, epsilon=1e-6, **kwargs):
+    ``hc_mult`` n > 1: the block carries a residual stream widened to n
+    streams, ``(B, S, n x units)``, and wraps each sublayer ``f`` in a
+    hyper-connection (:class:`HyperConnection`, ``hc_attn`` and ``hc_ffn``;
+    ``hc``: its keyword arguments): ``u = sum_i h_pre[i] X_i``, ``y =
+    f(norm(u))``, ``X'_i = sum_j H_res[i, j] X_j + h_post[i] y``
+    (``ops/llm.py``'s ``hc_pre_mix`` / ``hc_post_mix``), recomputed like
+    the rest."""
+
+    def __init__(self, units, mixer, ffn, epsilon=1e-6, hc_mult=1, hc=None,
+                 **kwargs):
         super().__init__(**kwargs)
+        self._streams = int(hc_mult)
         with self.name_scope():
+            if self._streams > 1:
+                self.hc_attn = HyperConnection(units, self._streams,
+                                               prefix="hc_attn_",
+                                               **(hc or {}))
             self.ln1 = RMSNorm(units, epsilon, prefix="ln1_")
             self.mixer = mixer()
+            if self._streams > 1:
+                self.hc_ffn = HyperConnection(units, self._streams,
+                                              prefix="hc_ffn_", **(hc or {}))
             self.ln2 = RMSNorm(units, epsilon, prefix="ln2_")
             self.ffn = ffn()
 
@@ -307,7 +437,21 @@ class DecoderBlock(HybridBlock):
             return h + y[0], y[1]
         return h + y
 
+    def _widened_body(self, F, x):
+        h_pre, h_post, h_res = self.hc_attn(x)
+        y = self.mixer(self.ln1(F.contrib.hc_pre_mix(x, h_pre)))
+        x = F.contrib.hc_post_mix(x, h_res, h_post, y)
+        h_pre, h_post, h_res = self.hc_ffn(x)
+        y = self.ffn(self.ln2(F.contrib.hc_pre_mix(x, h_pre)))
+        side = None
+        if isinstance(y, tuple):        # with a side term for the loss
+            y, side = y
+        x = F.contrib.hc_post_mix(x, h_res, h_post, y)
+        return x if side is None else (x, side)
+
     def hybrid_forward(self, F, h):
+        if self._streams > 1:
+            return recomputed(lambda x: self._widened_body(F, x), h)
         return recomputed(self._body, h)
 
 
@@ -342,12 +486,20 @@ class DecoderLM(HybridBlock):
     the residual stream (``TO_THE_STREAM``) N(0, ``weight_std / sqrt(2 x
     stream_blocks)``) as in GPT-2 and Megatron-LM: unscaled, attention's
     running mean of the values dominates the stream and the tokens of a
-    row route alike."""
+    row route alike.
+
+    ``hc_mult`` n > 1: the residual stream is widened to n streams
+    (:class:`DecoderBlock`'s ``hc_mult``, ``hc`` its hyper-connections'
+    keyword arguments): the embedding is copied into every stream
+    (``expand``) and the streams are summed before the final norm
+    (``collapse``), Hyper-Connections' ends."""
 
     def __init__(self, vocab_size, hidden_size, blocks, epsilon=1e-6,
-                 weight_std=0.02, tie_embedding=False, **kwargs):
+                 weight_std=0.02, tie_embedding=False, hc_mult=1, hc=None,
+                 **kwargs):
         super().__init__(**kwargs)
         self._weight_std = weight_std
+        self._streams = int(hc_mult)
         init = _init.Normal(weight_std)
         with self.name_scope():
             self.embed = Embedding(vocab_size, hidden_size, prefix="embed_",
@@ -355,6 +507,8 @@ class DecoderLM(HybridBlock):
             self.blocks = []
             for i, (mixer, ffn) in enumerate(blocks):
                 blk = DecoderBlock(hidden_size, mixer, ffn, epsilon=epsilon,
+                                   hc_mult=hc_mult, hc=dict(
+                                       hc or {}, weight_std=weight_std),
                                    prefix="l%d_" % i)
                 self.register_child(blk, "l%d" % i)
                 self.blocks.append(blk)
@@ -373,14 +527,30 @@ class DecoderLM(HybridBlock):
             if name.endswith(TO_THE_STREAM):
                 param.init = to_the_stream
 
+    def expand(self, F, h):
+        """The embedding's ``(B, S, C)`` copied into every stream: ``(B, S,
+        n x C)``; ``h`` itself with one stream."""
+        if self._streams == 1:
+            return h
+        return F.concat(*([h] * self._streams), dim=-1)
+
+    def collapse(self, F, x):
+        """The streams' sum, ``(B, S, C)``; ``x`` itself with one stream."""
+        if self._streams == 1:
+            return x
+        c = x.shape[-1] // self._streams
+        return F.add_n(*[F.slice_axis(x, axis=-1, begin=i * c,
+                                      end=(i + 1) * c)
+                         for i in range(self._streams)])
+
     def hybrid_forward(self, F, tokens):
-        h, side = self.embed(tokens), None
+        h, side = self.expand(F, self.embed(tokens)), None
         for blk in self.blocks:
             h = blk(h)
             if isinstance(h, tuple):
                 h, term = h
                 side = term if side is None else side + term
-        hidden = self.norm(h)
+        hidden = self.norm(self.collapse(F, h))
         return hidden if side is None else (hidden, side)
 
 
@@ -400,7 +570,17 @@ class MLAMoELM(DecoderLM):
 
     The keyword arguments carry the names of the model's ``config.json``.
     ``held_experts`` ``(first, count)`` gives this chip's share of every
-    routed layer."""
+    routed layer.  ``rope_scaling``: the config's (DeepSeek's yarn, with
+    ``mscale`` and ``mscale_all_dim``: :func:`deepseek_yarn`).
+    ``bias_update_rate``: :class:`RoutedExperts`' (the selection bias
+    trained by its balancing rule).  ``num_nextn_predict_layers`` 0: no
+    MTP module, and the model returns the one normed stream
+    (:class:`NextTokenLoss`'s input), as :class:`DecoderLM` does.
+    ``hc_mult`` n > 1, ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` / ``_max``: a residual stream of n streams
+    with a hyper-connection around every sublayer (:class:`DecoderLM`'s
+    ``hc_mult``); it takes no MTP module, since where that module would
+    read the widened stream is not given."""
 
     def __init__(self, vocab_size, hidden_size, num_hidden_layers,
                  first_k_dense_replace, intermediate_size,
@@ -409,7 +589,20 @@ class MLAMoELM(DecoderLM):
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                  held_experts=None, routed_scaling_factor=1.0,
                  rope_theta=10000.0, rms_norm_eps=1e-6, weight_std=0.02,
-                 **kwargs):
+                 rope_scaling=None, bias_update_rate=0.0,
+                 num_nextn_predict_layers=1, hc_mult=1, hc_sinkhorn_iters=20,
+                 hc_eps=1e-6, mhc_h_res_clamp_min=-30.0,
+                 mhc_h_res_clamp_max=30.0, **kwargs):
+        if num_nextn_predict_layers not in (0, 1):
+            raise ValueError("MLAMoELM: %r multi-token prediction modules; "
+                             "0 or 1" % (num_nextn_predict_layers,))
+        if hc_mult > 1 and num_nextn_predict_layers:
+            raise ValueError(
+                "MLAMoELM: hc_mult %d with a multi-token prediction module: "
+                "where that module reads the widened stream is not given"
+                % hc_mult)
+        self._mtp = bool(num_nextn_predict_layers)
+
         def attention():
             return MLAttention(
                 hidden_size, num_heads=num_attention_heads,
@@ -417,20 +610,27 @@ class MLAMoELM(DecoderLM):
                 qk_nope_head_dim=qk_nope_head_dim,
                 qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
                 rope_theta=rope_theta, epsilon=rms_norm_eps,
-                weight_std=weight_std, prefix="attn_")
+                weight_std=weight_std, rope_scaling=rope_scaling,
+                prefix="attn_")
 
         routed = feed_forward(hidden_size, weight_std=weight_std, moe=dict(
             hidden_size=moe_intermediate_size, num_experts=router_outputs,
             experts_per_token=num_experts_per_tok,
             held_experts=held_experts and tuple(held_experts),
-            routed_scaling_factor=routed_scaling_factor))
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_rate=bias_update_rate))
         dense = feed_forward(hidden_size, intermediate_size,
                              weight_std=weight_std)
         super().__init__(
             vocab_size, hidden_size,
             [(attention, dense if i < first_k_dense_replace else routed)
              for i in range(num_hidden_layers)],
-            epsilon=rms_norm_eps, weight_std=weight_std, **kwargs)
+            epsilon=rms_norm_eps, weight_std=weight_std, hc_mult=hc_mult,
+            hc=dict(sinkhorn_iters=hc_sinkhorn_iters, epsilon=hc_eps,
+                    clamp=(mhc_h_res_clamp_min, mhc_h_res_clamp_max)),
+            **kwargs)
+        if not self._mtp:
+            return
         with self.name_scope():
             self.mtp_enorm = RMSNorm(hidden_size, rms_norm_eps,
                                      prefix="mtp_enorm_")
@@ -448,6 +648,8 @@ class MLAMoELM(DecoderLM):
         self.scale_stream_writers(num_hidden_layers + 1)    # the MTP's too
 
     def hybrid_forward(self, F, tokens):
+        if not self._mtp:
+            return super().hybrid_forward(F, tokens)
         h = self.embed(tokens)
         for blk in self.blocks:
             h = blk(h)

@@ -249,7 +249,9 @@ def _install_contrib_ops(namespace):
                       "_contrib_gated_short_conv",
                       "_contrib_moe_route",
                       "_contrib_moe_experts",
-                      "_contrib_linear_cross_entropy")]
+                      "_contrib_linear_cross_entropy",
+                      "_contrib_hc_coefficients", "_contrib_hc_pre_mix",
+                      "_contrib_hc_post_mix")]
     _register.populate(namespace, names)
     return namespace
 

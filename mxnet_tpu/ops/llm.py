@@ -9,8 +9,9 @@ gated attention, a gated short
 causal convolution over the sequence, the router (sigmoid scores with a
 selection bias, DeepSeek-V3, arXiv:2412.19437, or softmax scores, with the
 balancing term of Switch Transformer, arXiv:2101.03961) and the
-held-experts layer of a mixture of experts, and a linear head fused with
-its cross-entropy over token chunks.  The reference
+held-experts layer of a mixture of experts, a linear head fused with
+its cross-entropy over token chunks, and the coefficients and mixes of
+hyper-connections over a residual stream of several streams.  The reference
 framework has none of them (its transformer helpers are
 ``src/operator/contrib/transformer.cc``).
 
@@ -43,7 +44,8 @@ from .registry import OP_INPUT_NAMES, register
 
 __all__ = ["rms_norm", "rope", "rotary_frequencies", "gated_silu", "mla_qkv", "mla_out", "gqa_qkv",
            "gqa_out", "head_gate", "gated_short_conv", "moe_route",
-           "moe_experts", "linear_cross_entropy", "expert_tiles"]
+           "moe_experts", "linear_cross_entropy", "expert_tiles",
+           "hc_coefficients", "hc_pre_mix", "hc_post_mix"]
 
 # rows of one expert tile: what an expert's rows are padded to.  A step of
 # the loops costs its expert's three weights read (twice in the backward
@@ -273,7 +275,7 @@ def _by_head(x, weight):
 @register("_contrib_mla_qkv", num_outputs=3, aliases=("mla_qkv",))
 def mla_qkv(data, qa_weight, qb_weight, kva_weight, kvb_weight,
             qnorm_weight, kvnorm_weight, num_heads=1, theta=10000.0,
-            eps=1e-6, **_):
+            eps=1e-6, inv_freq=None, amplitude=1.0, **_):
     """The projections of multi-head latent attention in its training
     form (DeepSeek-V2, arXiv:2405.04434), from the block's input ``(B, S,
     units)`` to what ``flash_attention`` reads: ``q``, ``k`` ``(B, heads,
@@ -289,13 +291,15 @@ def mla_qkv(data, qa_weight, qb_weight, kva_weight, kvb_weight,
     lanes are rotated in place of the product's result, and one pass
     writes ``k`` from its ``nope`` part and the one rotary key that all
     heads share (the pass that reads ``dk`` sums that key's gradient over
-    the heads)."""
+    the heads).  ``inv_freq`` / ``amplitude``: the layer's rotary scaling
+    in place of ``theta``, as in :func:`rope`."""
     heads = int(num_heads)
     rank = kvnorm_weight.shape[0]
     rot = kva_weight.shape[0] - rank
     nope = qb_weight.shape[0] // heads - rot
     with _xray.scope("mla.proj"):
-        cos, sin = _rotary_tables(data.shape[-2], rot, theta)
+        cos, sin = _rotary_tables(data.shape[-2], rot, theta, False,
+                                  inv_freq, float(amplitude))
         c_q = rms_norm(jnp.matmul(data, qa_weight.T), qnorm_weight, eps=eps)
         q = _by_head(c_q, qb_weight.reshape(heads, nope + rot, -1))
         q = _rotary(q, cos, sin, nope, False)
@@ -1073,6 +1077,164 @@ def linear_cross_entropy(data, weight, label, chunk=DEFAULT_LOSS_CHUNK, **_):
     return _fused_head_loss(data, weight, label.astype(jnp.int32), chunk)
 
 
+# --------------------------------------------------- hyper-connections
+# The residual stream widened to n streams (Hyper-Connections, Zhu et al.,
+# arXiv:2409.19606) whose mixing matrix is kept doubly stochastic (mHC,
+# manifold-constrained hyper-connections, DeepSeek-AI 2025).  The stream of
+# a token is held as one row of ``n x C`` lanes, stream ``i`` at lanes ``i C
+# .. (i + 1) C - 1``: ``(B, S, n C)``, whose lanes a v5e tiles without
+# padding and whose streams are lane-aligned slices (held ``(B, S, n, C)``,
+# the compiler copies the stream into another layout on the way into the
+# mixes).  The coefficients hold the token axes last, ``(n, B, S)`` and
+# ``(n, n, B, S)``: the tokens lie on the lanes, and Sinkhorn's sums over
+# the n x n matrix are sums of whole arrays.
+
+
+def _sinkhorn(m, iters, eps):
+    """``m (n, n, ...)`` positive, rows (axis 1 summed) then columns (axis
+    0 summed) divided by their sums plus ``eps``, ``iters`` times: a loop
+    of a fixed trip count, which autodiff turns into a scan."""
+    def one(_, m):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+
+    return lax.fori_loop(0, int(iters), one, m)
+
+
+@register("_contrib_hc_coefficients", num_outputs=3,
+          aliases=("hc_coefficients",))
+def hc_coefficients(data, phi, bias, alpha, streams=4, iters=20, eps=1e-6,
+                    clamp_min=-30.0, clamp_max=30.0, **_):
+    """The coefficients of one sublayer's hyper-connection, from the widened
+    stream ``data (B, S, n C)``: with ``x`` a token's ``n C`` lanes, ``r = x
+    / sqrt(mean(x^2) + eps)``, ``[p | q | R] = [alpha_0 (r phi)_pre |
+    alpha_1 (r phi)_post | alpha_2 (r phi)_res] + bias`` (``phi (n C, 2n +
+    n^2)``, ``bias (2n + n^2,)``, ``alpha (3,)``), ``h_pre = sigmoid(p)``,
+    ``h_post = 2 sigmoid(q)`` and ``H_res = Sinkhorn_iters(exp(clamp(R)))``,
+    ``R`` read row-major as ``n x n`` (``H_res[i, j]`` carries stream ``j``
+    into stream ``i``), rows normalised before columns, ``eps`` in every
+    denominator.  -> ``h_pre``, ``h_post (n, B, S)``, ``H_res (n, n, B,
+    S)``, float32, the token axes last.  ``r phi`` is ``(x phi) / sqrt(...)``:
+    the product takes ``x`` and ``phi`` in the stream's dtype and
+    accumulates in float32; the norm's scale, the sigmoids and Sinkhorn (a
+    loop of a fixed trip count) are float32.  Differentiated by autodiff.
+    Under the scope ``hc.coef``."""
+    n, f32 = int(streams), jnp.float32
+    with _xray.scope("hc.coef"):
+        proj = lax.dot_general(data, phi.astype(data.dtype),
+                               (((data.ndim - 1,), (0,)), ((), ())),
+                               preferred_element_type=f32)
+        x = data.astype(f32)
+        inv = lax.rsqrt(jnp.mean(x * x, axis=-1) + eps)
+        a = alpha.astype(f32)
+        scale = jnp.concatenate([jnp.broadcast_to(a[0], (n,)),
+                                 jnp.broadcast_to(a[1], (n,)),
+                                 jnp.broadcast_to(a[2], (n * n,))])
+        z = jnp.moveaxis(proj, -1, 0) * inv
+        z = z * scale.reshape((-1,) + (1,) * inv.ndim) \
+            + bias.astype(f32).reshape((-1,) + (1,) * inv.ndim)
+        h_pre = jax.nn.sigmoid(z[:n])
+        h_post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+        res = jnp.exp(jnp.clip(z[2 * n:], clamp_min, clamp_max))
+        h_res = _sinkhorn(res.reshape((n, n) + inv.shape), iters, eps)
+        return h_pre, h_post, h_res
+
+
+def _streams(data, n):
+    """The ``n`` lane-aligned streams of ``data (..., n C)``."""
+    c = data.shape[-1] // n
+    return [data[..., i * c:(i + 1) * c] for i in range(n)]
+
+
+def _sums_over_lanes(a, b):
+    """``sum_c a[..., c] b[..., c]`` in float32: ``(...)``."""
+    return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32), axis=-1)
+
+
+@jax.custom_vjp
+def _pre_mix(data, h_pre):
+    total = sum(w[..., None] * x.astype(jnp.float32) for w, x in
+                zip(h_pre, _streams(data, h_pre.shape[0])))
+    return total.astype(data.dtype)
+
+
+def _pre_mix_fwd(data, h_pre):
+    return _pre_mix(data, h_pre), (data, h_pre)
+
+
+def _pre_mix_bwd(res, g):
+    data, h_pre = res
+    g32 = g.astype(jnp.float32)
+    d_data = jnp.concatenate([w[..., None] * g32 for w in h_pre], axis=-1)
+    d_h = jnp.stack([_sums_over_lanes(g, x)
+                     for x in _streams(data, h_pre.shape[0])])
+    return d_data.astype(data.dtype), d_h
+
+
+_pre_mix.defvjp(_pre_mix_fwd, _pre_mix_bwd)
+
+
+@register("_contrib_hc_pre_mix", aliases=("hc_pre_mix",))
+def hc_pre_mix(data, h_pre, **_):
+    """A sublayer's input from the widened stream: ``u = sum_i h_pre[i]
+    X_i``, ``data (B, S, n C)``, ``h_pre (n, B, S)`` -> ``(B, S, C)`` in
+    ``data``'s dtype, float32 arithmetic.  The backward pass is written by
+    hand: it keeps ``data`` as it came and forms the float32 lanes inside
+    the passes that read them, so no float32 copy of the stream is saved
+    or written.  Under the scope ``hc.mix``."""
+    with _xray.scope("hc.mix"):
+        return _pre_mix(data, h_pre)
+
+
+@jax.custom_vjp
+def _post_mix(data, h_res, h_post, update):
+    n = h_post.shape[0]
+    xs = [x.astype(jnp.float32) for x in _streams(data, n)]
+    y = update.astype(jnp.float32)
+    out = [sum(h_res[i, j][..., None] * xs[j] for j in range(n))
+           + h_post[i][..., None] * y for i in range(n)]
+    return jnp.concatenate(out, axis=-1).astype(data.dtype)
+
+
+def _post_mix_fwd(data, h_res, h_post, update):
+    return _post_mix(data, h_res, h_post, update), \
+        (data, h_res, h_post, update)
+
+
+def _post_mix_bwd(res, g):
+    data, h_res, h_post, update = res
+    n = h_post.shape[0]
+    gs, xs = _streams(g, n), _streams(data, n)
+    g32 = [a.astype(jnp.float32) for a in gs]
+    d_data = jnp.concatenate(
+        [sum(h_res[i, j][..., None] * g32[i] for i in range(n))
+         for j in range(n)], axis=-1)
+    d_update = sum(h_post[i][..., None] * g32[i] for i in range(n))
+    d_res = jnp.stack([jnp.stack([_sums_over_lanes(gs[i], xs[j])
+                                  for j in range(n)]) for i in range(n)])
+    d_post = jnp.stack([_sums_over_lanes(a, update) for a in gs])
+    return (d_data.astype(data.dtype), d_res, d_post,
+            d_update.astype(update.dtype))
+
+
+_post_mix.defvjp(_post_mix_fwd, _post_mix_bwd)
+
+
+@register("_contrib_hc_post_mix", aliases=("hc_post_mix",))
+def hc_post_mix(data, h_res, h_post, update, **_):
+    """The widened stream after a sublayer: ``X'_i = sum_j H_res[i, j] X_j
+    + h_post[i] y``, ``data (B, S, n C)``, ``h_res (n, n, B, S)``, ``h_post
+    (n, B, S)``, the sublayer's result ``update (B, S, C)`` -> ``(B, S, n
+    C)`` in ``data``'s dtype, float32 arithmetic.  The backward pass is
+    written by hand, as ``hc_pre_mix``'s: the cotangent and the stream are
+    read in their dtype and widened inside each pass (``dX_j = sum_i
+    H_res[i, j] dX'_i``, ``dy = sum_i h_post[i] dX'_i``, ``dH_res[i, j] =
+    dX'_i . X_j``, ``dh_post[i] = dX'_i . y``).  Under the scope
+    ``hc.mix``."""
+    with _xray.scope("hc.mix"):
+        return _post_mix(data, h_res, h_post, update)
+
+
 OP_INPUT_NAMES.update({
     "_contrib_rms_norm": ("data", "gamma"),
     "_contrib_rope": ("data",),
@@ -1090,4 +1252,7 @@ OP_INPUT_NAMES.update({
     "_contrib_moe_experts": ("data", "expert_ids", "expert_weights",
                              "gate_weight", "up_weight", "down_weight"),
     "_contrib_linear_cross_entropy": ("data", "weight", "label"),
+    "_contrib_hc_coefficients": ("data", "phi", "bias", "alpha"),
+    "_contrib_hc_pre_mix": ("data", "h_pre"),
+    "_contrib_hc_post_mix": ("data", "h_res", "h_post", "update"),
 })

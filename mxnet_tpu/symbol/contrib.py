@@ -26,6 +26,7 @@ _CONTRIB_OPS = [
     "_contrib_gated_short_conv",
     "_contrib_moe_route", "_contrib_moe_experts",
     "_contrib_linear_cross_entropy",
+    "_contrib_hc_coefficients", "_contrib_hc_pre_mix", "_contrib_hc_post_mix",
 ]
 
 _populate(globals(), names=[n for n in _CONTRIB_OPS if n in _reg.list_ops()])

@@ -557,6 +557,12 @@ ELSEWHERE = {
     "_contrib_moe_experts": ("tests/test_llm_ops.py", "llm.moe_experts"),
     "_contrib_linear_cross_entropy": ("tests/test_llm_ops.py",
                                       "llm.linear_cross_entropy"),
+    "_contrib_hc_coefficients": ("tests/test_hyper_connections.py",
+                                 "llm.hc_coefficients"),
+    "_contrib_hc_pre_mix": ("tests/test_hyper_connections.py",
+                            "llm.hc_pre_mix"),
+    "_contrib_hc_post_mix": ("tests/test_hyper_connections.py",
+                             "llm.hc_post_mix"),
     "_contrib_flash_attention": ("tests/test_attention.py",
                                  "flash_attention"),
     "_contrib_interleaved_matmul_selfatt_qk": (

@@ -872,6 +872,65 @@ def test_on_the_v5e_the_feed_forwards_gradients_read_dg_and_du_in_bfloat16(
     assert sorted(wide) == ["bf16"] * 2 + ["f32"] * 2, written
 
 
+def test_on_the_v5e_the_widened_stream_is_held_flat_and_never_relaid(v5e):
+    """One sublayer's hyper-connection at ``xing4_0_29b_a4b_ep8``'s widths
+    (1 x 4,096 tokens, 4 streams of 3,584, bfloat16), coefficients and both
+    mixes around a stand-in sublayer, under ``jax.grad``, as the v5e's
+    compiler emits it: every array of the stream's size is ``(1, 4096,
+    14336)`` tiled (8, 128) with no padding, no ``(1, 4096, 4, 3584)`` form
+    and no copy of the stream stands anywhere (held four streams deep, as
+    ``(1, 4096, 4, 3584)``, the compiler copies it into the mixes' layout
+    and back); instructions lie under ``hc.coef`` and ``hc.mix``, and
+    Sinkhorn's 20 iterations are one loop forward and one backward."""
+    import os
+    import re
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mxnet_tpu.ops import llm
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark.harness import hlo_cost, scope_time
+    from tools.step_text import unfused
+
+    chip = SingleDeviceSharding(v5e.devices[0])
+    n, units, seq = 4, 3584, 4096
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(x, phi, bias, alpha, g):
+        h_pre, h_post, h_res = llm.hc_coefficients(x, phi, bias, alpha)
+        u = llm.hc_pre_mix(x, h_pre)
+        out = llm.hc_post_mix(x, h_res, h_post, jnp.tanh(u))
+        return jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        arg(1, seq, n * units), arg(n * units, 24),
+        arg(24, dtype=jnp.float32), arg(3, dtype=jnp.float32),
+        arg(1, seq, n * units)).compile().as_text()
+    assert "4,3584]" not in text
+    stream = re.findall(r"(bf16|f32)\[1,4096,14336\]\{([^}]*)\}", text)
+    assert stream and all(layout.startswith("2,1,0:T(8,128)")
+                          for _, layout in stream), set(stream)
+    module = hlo_cost.Module(text)
+    scopes = {"hc.coef": 0, "hc.mix": 0}
+    for comp in unfused(module).values():
+        for name, (opcode, (result, _, _)) in comp.items():
+            if opcode in ("copy", "transpose"):
+                assert "4096,14336" not in result, (name, result)
+            for scope in scopes:
+                if scope_time._under((scope,)).search(
+                        module.instructions.get(name, (0, ""))[1]):
+                    scopes[scope] += 1
+    assert min(scopes.values()) > 0, scopes
+    assert len(re.findall(r" while\(", text)) == 2
+
+
 @pytest.mark.parametrize("part", ["prefix", "sliding_attention",
                                   "full_attention"])
 def test_on_the_v5e_the_references_timed_rows_fit_beside_the_steps_state(

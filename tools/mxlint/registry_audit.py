@@ -132,6 +132,12 @@ def canonical_spec(name):
                                  {"first_expert": 4, "tile": 4}),
         "_contrib_linear_cross_entropy": ([((8, 6), f), ((11, 6), f),
                                            ((8,), i32)], {"chunk": 4}),
+        "_contrib_hc_coefficients": ([((2, 5, 16), f), ((16, 24), f),
+                                      ((24,), f), ((3,), f)],
+                                     {"streams": 4, "iters": 3}),
+        "_contrib_hc_pre_mix": ([((2, 5, 16), f), ((4, 2, 5), f)], {}),
+        "_contrib_hc_post_mix": ([((2, 5, 16), f), ((4, 4, 2, 5), f),
+                                  ((4, 2, 5), f), ((2, 5, 4), f)], {}),
         "_contrib_quantize": ([((2, 3), f), ((1,), f), ((1,), f)], {}),
         "_contrib_quantize_v2": ([((2, 3), f)],
                                  {"min_calib_range": -1.0,
